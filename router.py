@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import shlex
 import sys
 import time
@@ -177,7 +178,7 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     import tempfile
 
-    from shallowspeed_tpu import chaos
+    from shallowspeed_tpu import chaos, runtime
     from shallowspeed_tpu.metrics import MetricsLogger
     from shallowspeed_tpu.serving.router import (FleetOverloaded,
                                                  ReplicaProc, Router)
@@ -212,6 +213,41 @@ def main(argv=None) -> int:
                       "url": fleet_srv.url("/status.json")}),
           flush=True)
     collector.start(poll=0.5)
+
+    # One chip per replica process. A chip belongs to one process at a
+    # time, so replicas that inherit this environment unchanged would
+    # each open every chip and all but the first would fail. The count
+    # comes from a throwaway child: this parent never touches a backend.
+    n_chips = None
+    if args.platform != "cpu":
+        dev = runtime.probe_device_stamp()
+        print(json.dumps({"event": "device", **dev}), flush=True)
+        if dev["platform"] == "tpu":
+            n_chips = dev["count"]
+            want = max(args.replicas,
+                       args.max_replicas if args.autoscale else 0)
+            if want > n_chips:
+                raise SystemExit(
+                    f"router: {want} replicas need {want} chips (one "
+                    f"process per chip) but this host has {n_chips} "
+                    f"{dev['kind']}; lower --replicas/--max-replicas "
+                    f"or pass --platform cpu")
+    chip_of: dict[str, int] = {}
+    router_box: list = []         # the Router, once constructed
+
+    def replica_env(name: str):
+        if n_chips is None:
+            return None           # inherit: no chips to divide
+        live = (set(router_box[0].replica_names()) if router_box
+                else set(chip_of))
+        used = {c for n, c in chip_of.items() if n in live}
+        free = [c for c in range(n_chips) if c not in used]
+        if not free:
+            raise RuntimeError(
+                f"no free chip for replica {name}: {n_chips} chip(s), "
+                f"held by {sorted(n for n in chip_of if n in live)}")
+        chip_of[name] = free[0]
+        return {**os.environ, **runtime.one_chip_env(free[0])}
 
     serve_py = str(Path(__file__).resolve().parent / "serve.py")
     model_args = ["--vocab", str(args.vocab),
@@ -263,7 +299,8 @@ def main(argv=None) -> int:
                            hang_timeout=args.hang_timeout,
                            term_grace=args.term_grace,
                            stdout_path=str(run_dir
-                                           / f"replica_{name}.out"))
+                                           / f"replica_{name}.out"),
+                           env=replica_env(name))
 
     router = Router(
         spawn, n_replicas=args.replicas, collector=collector,
@@ -280,6 +317,7 @@ def main(argv=None) -> int:
         scale_cooldown_s=args.scale_cooldown,
         sticky=(args.prefix_cache == "on"),
         sticky_block=args.block_size)
+    router_box.append(router)
 
     t0 = time.time()
     i = 0

@@ -206,13 +206,17 @@ def load_requests(path: str, vocab: int) -> list[dict]:
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    if args.platform:
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
     import jax
-    import numpy as np
 
+    if args.platform:
+        jax.config.update("jax_platforms", args.platform)
+    from shallowspeed_tpu import runtime
+
+    runtime.enable_compile_cache()
+    # what the run actually got: with no accelerator JAX falls back to
+    # the CPU (kernels interpreted) with only a warning
+    print(json.dumps({"event": "device", **runtime.device_stamp()}),
+          flush=True)
     from shallowspeed_tpu.elastic import install_sigterm_exit
     from shallowspeed_tpu.metrics import MetricsLogger
     from shallowspeed_tpu.models import transformer as T
@@ -452,6 +456,9 @@ def main(argv=None) -> int:
             "executables": eng.executable_counts(),
             "blocks_free_at_drain":
                 f"{eng.alloc.n_free}/{eng.alloc.n_usable}",
+            # with the prefix cache on, finished requests donate their
+            # blocks to the cold list: free + cold == usable at drain
+            "blocks_cold_at_drain": eng.alloc.n_cold,
         })
         print(json.dumps({"event": "summary", **summary}), flush=True)
         if plane is not None:

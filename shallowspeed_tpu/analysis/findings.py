@@ -2,7 +2,7 @@
 
 A `Finding` is one fact the static pass proved about a compiled train
 step: which rule fired, how bad it is, which target/entrypoint it lives
-in, and the jaxpr provenance (the chain of enclosing sub-jaxprs — pjit /
+in, and the jaxpr provenance (the chain of enclosing sub-jaxprs — jit /
 shard_map / scan / cond / remat — down to the offending equation).
 
 Suppressions are the inline escape hatch: a module that does something

@@ -3,7 +3,7 @@
 `walker.iter_eqns` answers "what equations exist"; this module answers
 "what does each VALUE carry" — the per-value provenance the quantized-
 training rules need. One forward pass over an entrypoint's jaxpr
-(recursing through pjit / shard_map / scan / while / cond / remat /
+(recursing through jit / shard_map / scan / while / cond / remat /
 custom-vjp sub-jaxprs with explicit environment mapping) assigns every
 intermediate a `VInfo`:
 
@@ -58,8 +58,10 @@ from dataclasses import dataclass, field, replace
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
-from shallowspeed_tpu.analysis.walker import _as_jaxpr
+from shallowspeed_tpu.analysis.walker import (REDUCE_COLLECTIVES, _as_jaxpr,
+                                              collective_axes)
 
 # quantized-storage dtypes (same set the dequant-fusion rule uses)
 QUANT_DTYPES = {"int8", "uint8", "float8_e4m3fn", "float8_e5m2"}
@@ -260,7 +262,7 @@ class _Flow:
     # -- environment helpers ------------------------------------------
 
     def info_of(self, env, atom) -> VInfo:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             val = atom.val
             itv = None
             if np.ndim(val) == 0 and _is_float(_dt(atom)):
@@ -358,7 +360,7 @@ class _Flow:
                 env[v] = _join_infos(infos, _dt(v))
             return True
 
-        # pjit / closed_call / remat2 / custom_jvp_call /
+        # jit / closed_call / remat2 / custom_jvp_call /
         # custom_vjp_call(_jaxpr) / shard_map / ...: 1:1 when mappable
         new_axes = dict(axis_env)
         if name == "shard_map":
@@ -404,7 +406,7 @@ class _Flow:
         for eqn in body.eqns:
             d: set = set()
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     d |= deps.get(id(v), set())
             for v in eqn.outvars:
                 deps[id(v)] = d
@@ -534,13 +536,10 @@ class _Flow:
             put(VInfo(itv=ins[0].itv, maxof=tag,
                       round_m=ins[0].round_m))
             return
-        if name in ("psum", "psum_scatter", "reduce_scatter"):
-            axes = eqn.params.get("axes") or eqn.params.get("axis_name")
-            if not isinstance(axes, (tuple, list)):
-                axes = (axes,)
+        if name in REDUCE_COLLECTIVES:
             n = 1
-            for ax in axes:
-                n *= axis_env.get(ax, 1) if isinstance(ax, str) else 1
+            for ax in collective_axes(eqn):
+                n *= axis_env.get(ax, 1)
             put(VInfo(itv=_itv_mul(ins[0].itv, (n, n))
                       if ins[0].itv else None,
                       round_m=ins[0].round_m))
@@ -675,7 +674,7 @@ class _Flow:
         """Attach a fresh scale identity to a divisor var AND its
         shape/convert ancestors, so any later value derived from the
         same scale (the dequant multiply's operand) carries the sid."""
-        while depth and not isinstance(var, jax.core.Literal):
+        while depth and not isinstance(var, Literal):
             info = env.get(var) or VInfo(dtype=_dt(var))
             env[var] = replace(info, sids=info.sids | {sid},
                                scale_like=True)

@@ -43,21 +43,17 @@ from typing import Callable
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from shallowspeed_tpu.analysis.findings import (Finding, Severity,
                                                 apply_suppressions)
-from shallowspeed_tpu.analysis.walker import aval_bytes, peak_bytes
+from shallowspeed_tpu.analysis.walker import (COLLECTIVES,
+                                              REDUCE_COLLECTIVES,
+                                              aval_bytes, collective_axes,
+                                              peak_bytes)
 
 RULES: dict[str, Callable] = {}
 
-# collectives whose eqn params name mesh axes, with the param key
-_COLLECTIVES = {
-    "psum": "axes", "pmin": "axes", "pmax": "axes",
-    "ppermute": "axis_name", "pbroadcast": "axis_name",
-    "all_gather": "axis_name", "reduce_scatter": "axis_name",
-    "psum_scatter": "axis_name", "all_to_all": "axis_name",
-    "axis_index": "axis_name", "pgather": "axes",
-}
 
 
 def rule(name: str):
@@ -91,16 +87,6 @@ def run_rules(probe, only: tuple = ()) -> list:
     apply_suppressions(deduped)
     deduped.sort(key=lambda f: (-int(f.severity), f.rule, f.site))
     return deduped
-
-
-def _axis_names(axes) -> tuple:
-    """Normalize an eqn's axis param to a tuple of names (drops
-    positional ints, which cannot mismatch a mesh)."""
-    if axes is None:
-        return ()
-    if not isinstance(axes, (tuple, list)):
-        axes = (axes,)
-    return tuple(a for a in axes if isinstance(a, str))
 
 
 # ------------------------------------------------------- dtype promotion
@@ -151,7 +137,7 @@ def _f32_origin(var, made_by, bf16, f32, budget: int = 128) -> str:
             has_cast = True
             continue
         for iv in eqn.invars:
-            if (not isinstance(iv, jax.core.Literal)
+            if (not isinstance(iv, Literal)
                     and getattr(iv.aval, "dtype", None) is not None
                     and np.dtype(iv.aval.dtype) == f32):
                 frontier.append(iv)
@@ -284,14 +270,14 @@ def donation(probe) -> list:
     for ep in probe.entrypoints:
         if not ep.donate:
             continue
-        pjit_eqn = probe.top_pjit(ep)
-        if pjit_eqn is None:
+        jit_eqn = probe.top_jit(ep)
+        if jit_eqn is None:
             out.append(Finding(
                 "donation", Severity.HIGH, probe.name, ep.name,
                 (), "step-like entrypoint is not jitted — every call "
                     "pays Python dispatch and nothing can be donated"))
             continue
-        donated = pjit_eqn.params.get("donated_invars", ())
+        donated = jit_eqn.params.get("donated_invars", ())
         # flat invars are the flattened args in order; map each arg
         # index to its leaf range
         sizes = [len(jax.tree_util.tree_leaves(a)) for a in ep.args]
@@ -304,7 +290,7 @@ def donation(probe) -> list:
                            [i for i in range(lo, hi) if not donated[i]])
                 out.append(Finding(
                     "donation", Severity.HIGH, probe.name, ep.name,
-                    ("pjit",),
+                    ("jit",),
                     f"argument {argi} ({ep.arg_names[argi]}) is not "
                     f"donated ({len(missing) or hi - lo} of "
                     f"{hi - lo} leaves un-aliased) — the step keeps a "
@@ -355,10 +341,9 @@ def collective(probe) -> list:
                         f"whose constructing mesh has "
                         f"{tuple(probe.mesh.axis_names)}"))
                 continue
-            key = _COLLECTIVES.get(name)
-            if key is None:
+            if name not in COLLECTIVES and name != "axis_index":
                 continue
-            axes = _axis_names(eqn.params.get(key))
+            axes = collective_axes(eqn)
             unbound = [a for a in axes if a not in env]
             if unbound:
                 out.append(Finding(
@@ -457,39 +442,42 @@ def overlap_bucket(probe) -> list:
         if info is None:
             continue
         axis = info["axis"]
-        expected = Counter(info["buckets"])
+        # a bucket's psum binds one eqn per member leaf, so the match
+        # is leaf by leaf against the registered buckets' members
+        expected = Counter(leaf for bucket in info["buckets"]
+                           for leaf in bucket)
         seen: Counter = Counter()
         for eqn, path, env in probe.walk(ep):
             name = eqn.primitive.name
-            if name not in OV.REDUCE_PRIMS:
+            if name not in REDUCE_COLLECTIVES:
                 continue
-            if axis not in OV.eqn_axes(eqn):
+            if axis not in collective_axes(eqn):
                 continue
-            operands = [v for v in eqn.invars
-                        if not isinstance(v, jax.core.Literal)]
-            nbytes = sum(aval_bytes(v.aval) for v in operands)
-            sig = OV.bucket_signature([v.aval for v in operands])
-            if seen[sig] < expected[sig]:
-                seen[sig] += 1
-            elif nbytes < 1024:
-                continue  # unmatched scalar statistics (health pack,
-                #           loss means), not gradient payload
-            else:
-                out.append(Finding(
-                    "overlap-bucket", Severity.HIGH, probe.name,
-                    ep.name, path,
-                    f"{name} over '{axis}' ({nbytes} B, "
-                    f"{len(operands)} operand(s)) is not a registered "
-                    f"reduction bucket — this gradient bypasses the "
-                    f"bucketed overlapped reduction"))
+            for v in eqn.invars:
+                if isinstance(v, Literal):
+                    continue
+                (sig,) = OV.bucket_signature([v.aval])
+                nbytes = aval_bytes(v.aval)
+                if seen[sig] < expected[sig]:
+                    seen[sig] += 1
+                elif nbytes >= 1024:
+                    # (smaller unmatched operands are scalar statistics
+                    # — health pack, loss means — not gradient payload)
+                    out.append(Finding(
+                        "overlap-bucket", Severity.HIGH, probe.name,
+                        ep.name, path,
+                        f"{name} over '{axis}' ({nbytes} B) is not a "
+                        f"member of a registered reduction bucket — "
+                        f"this gradient bypasses the bucketed "
+                        f"overlapped reduction"))
         missing = expected - seen
         if missing:
             out.append(Finding(
                 "overlap-bucket", Severity.MEDIUM, probe.name, ep.name,
                 (),
-                f"{sum(missing.values())} registered bucket(s) never "
-                f"appeared in the traced program — the bucket plan and "
-                f"the compiled reduction drifted"))
+                f"{sum(missing.values())} registered bucket member(s) "
+                f"never appeared in the traced program — the bucket "
+                f"plan and the compiled reduction drifted"))
         expo = OV.collective_exposure(probe.jaxpr_of(ep), axes=(axis,))
         if expo["n_collectives"] and not expo["n_overlapped"]:
             out.append(Finding(
@@ -550,7 +538,7 @@ def dequant_fusion(probe) -> list:
                 for v in eqn.outvars:
                     made_by[v] = eqn
                 for v in eqn.invars:
-                    if not isinstance(v, jax.core.Literal):
+                    if not isinstance(v, Literal):
                         consumers.setdefault(v, []).append(eqn)
 
             def root_of(var):
@@ -593,7 +581,7 @@ def dequant_fusion(probe) -> list:
                 if eqn.primitive.name != "convert_element_type":
                     continue
                 src = root_of(eqn.invars[0])
-                if isinstance(src, jax.core.Literal) \
+                if isinstance(src, Literal) \
                         or not _is_quant(src):
                     continue
                 o = eqn.outvars[0]
@@ -722,11 +710,6 @@ def accumulation_dtype(probe) -> list:
     return out
 
 
-# collectives that REDUCE (sum) across devices — the precision-
-# sensitive subset of _COLLECTIVES (gather/permute move bits verbatim)
-_REDUCE_COLLECTIVES = ("psum", "psum_scatter", "reduce_scatter")
-
-
 @rule("reduction-precision")
 def reduction_precision(probe) -> list:
     """Grad-sized cross-device reductions must run in f32: a bf16/fp8
@@ -740,10 +723,10 @@ def reduction_precision(probe) -> list:
     for ep in probe.entrypoints:
         for eqn, path, env in probe.walk(ep):
             name = eqn.primitive.name
-            if name not in _REDUCE_COLLECTIVES:
+            if name not in REDUCE_COLLECTIVES:
                 continue
             for v in eqn.invars:
-                if isinstance(v, jax.core.Literal):
+                if isinstance(v, Literal):
                     continue
                 dt = getattr(v.aval, "dtype", None)
                 if dt is None or not jax.numpy.issubdtype(
@@ -754,8 +737,7 @@ def reduction_precision(probe) -> list:
                 nbytes = aval_bytes(v.aval)
                 if nbytes < 1024:
                     continue  # scalar statistics, not gradient payload
-                key = _COLLECTIVES.get(name)
-                axes = _axis_names(eqn.params.get(key)) if key else ()
+                axes = collective_axes(eqn)
                 out.append(Finding(
                     "reduction-precision", Severity.HIGH, probe.name,
                     ep.name, path,

@@ -103,12 +103,12 @@ class TargetProbe:
             self._flows[ep.name] = flow_entrypoint(self, ep)
         return self._flows[ep.name]
 
-    def top_pjit(self, ep: EntryPoint):
-        """The outermost pjit eqn (donation lives there), or None."""
+    def top_jit(self, ep: EntryPoint):
+        """The outermost jit eqn (donation lives there), or None."""
         jaxpr = self.jaxpr_of(ep)
         if jaxpr is not None:
             for eqn in jaxpr.jaxpr.eqns:
-                if eqn.primitive.name == "pjit":
+                if eqn.primitive.name == "jit":
                     return eqn
         return None
 

@@ -1,7 +1,7 @@
 """Generic jaxpr walker — the traversal every rule shares.
 
 `iter_eqns` yields every equation of a (closed) jaxpr depth-first,
-recursing through EVERY higher-order primitive's sub-jaxprs — pjit,
+recursing through EVERY higher-order primitive's sub-jaxprs — jit,
 shard_map, scan, while, cond (all branches), remat2/checkpoint,
 custom_vjp/jvp calls — without a per-primitive table: any eqn param that
 IS (or contains) a Jaxpr/ClosedJaxpr is a sub-jaxpr. Each yield carries
@@ -22,9 +22,31 @@ from typing import Iterator
 
 import jax
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
-ClosedJaxpr = jax.core.ClosedJaxpr
-Jaxpr = jax.core.Jaxpr
+# Data-moving collective primitives, by the names jax emits under
+# shard_map's varying-axes typing (`lax.psum` binds `psum_invariant`,
+# `lax.psum_scatter` binds `reduce_scatter`). The one table the static
+# rules, the traffic accountant and the overlap exposure model match.
+COLLECTIVES = frozenset({
+    "psum_invariant", "pmin", "pmax", "ppermute", "pbroadcast",
+    "all_gather", "all_gather_invariant", "reduce_scatter", "all_to_all",
+    "pgather"})
+# the subset that SUMS across devices (gather/permute move bits verbatim)
+REDUCE_COLLECTIVES = frozenset({"psum_invariant", "reduce_scatter"})
+
+
+def collective_axes(eqn) -> tuple:
+    """The mesh-axis names a collective (or `axis_index`) eqn runs over
+    — jax names the param `axes` or `axis_name` depending on the
+    primitive. Positional ints are dropped: they cannot mismatch a
+    mesh."""
+    axes = eqn.params.get("axes", eqn.params.get("axis_name"))
+    if axes is None:
+        return ()
+    if not isinstance(axes, (tuple, list)):
+        axes = (axes,)
+    return tuple(a for a in axes if isinstance(a, str))
 
 
 def _as_jaxpr(obj):
@@ -38,7 +60,7 @@ def _as_jaxpr(obj):
 
 def sub_jaxprs(eqn) -> list:
     """Every sub-jaxpr in this equation's params (cond's `branches`
-    tuple, scan/pjit/shard_map's `jaxpr`, while's cond/body, ...)."""
+    tuple, scan/jit/shard_map's `jaxpr`, while's cond/body, ...)."""
     out = []
     for v in eqn.params.values():
         items = v if isinstance(v, (tuple, list)) else (v,)
@@ -106,7 +128,7 @@ def eqn_bytes(eqn) -> int:
     lower, so pricing this at the HBM roofline over-explains, never
     under-explains, a measured step)."""
     ins = sum(aval_bytes(v.aval) for v in eqn.invars
-              if not isinstance(v, jax.core.Literal))
+              if not isinstance(v, Literal))
     outs = sum(aval_bytes(v.aval) for v in eqn.outvars)
     return ins + outs
 
@@ -132,7 +154,7 @@ def _inner_extra(eqn) -> int | None:
     operands the caller already holds live (max over branches — only
     one cond branch runs; scan iterations reuse one body's
     transients). Subtracting the sub-jaxpr's own inputs is what keeps
-    nesting from re-counting the same buffers at every level (pjit ->
+    nesting from re-counting the same buffers at every level (jit ->
     shard_map -> scan would otherwise multiply params+opt_state by the
     nesting depth). None when the eqn has no sub-jaxprs."""
     subs = sub_jaxprs(eqn)
@@ -163,10 +185,10 @@ def peak_bytes(jaxpr) -> int:
     last_use: dict = {}
     for i, eqn in enumerate(j.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 last_use[v] = i
     for v in j.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, Literal):
             last_use[v] = len(j.eqns)
 
     live = sum(aval_bytes(v.aval) for v in (*j.invars, *j.constvars))
@@ -181,14 +203,14 @@ def peak_bytes(jaxpr) -> int:
             # one iteration's body transients are live — additive
             peak = max(peak, live + out_b + extra)
         else:
-            # call-like (pjit/shard_map/cond/remat): the call's outputs
+            # call-like (jit/shard_map/cond/remat): the call's outputs
             # materialize INSIDE the sub-jaxpr, already in its peak
             peak = max(peak, live + max(out_b, extra))
         live += out_b
         # a var dies at its last textual use; outvars never read again
         # (incl. DropVars) die immediately — default their last use to i
         for v in set(v for v in (*eqn.invars, *eqn.outvars)
-                     if not isinstance(v, jax.core.Literal)):
+                     if not isinstance(v, Literal)):
             if last_use.get(v, i) == i:
                 live -= aval_bytes(v.aval)
     return peak

@@ -47,9 +47,8 @@ def initialize(coordinator_address: str | None = None,
     the standard env vars (`JAX_COORDINATOR_ADDRESS`, `JAX_NUM_PROCESSES`,
     `JAX_PROCESS_ID`). Strictly opt-in: without an explicit coordinator
     address (argument or env var) this is a no-op, even on hardware whose
-    metadata advertises a pod — single-host TPU images often do (this one
-    sets `TPU_WORKER_HOSTNAMES=localhost`), and an unwanted init attempt
-    after backend startup is a hard error. Returns True if a multi-process
+    metadata advertises a pod — single-host TPU images often do, and an
+    unwanted init attempt after backend startup is a hard error. Returns True if a multi-process
     runtime was set up, False for the single-process no-op or when already
     initialized (idempotent).
     """
@@ -57,22 +56,16 @@ def initialize(coordinator_address: str | None = None,
                            or os.environ.get("JAX_COORDINATOR_ADDRESS"))
     if coordinator_address is None:
         return False  # single-process run
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=(num_processes
-                           if num_processes is not None
-                           else _env_int("JAX_NUM_PROCESSES")),
-            process_id=(process_id if process_id is not None
-                        else _env_int("JAX_PROCESS_ID")))
-        return True
-    except RuntimeError as e:  # already initialized — idempotent
-        # jax has used both wordings across versions: "already
-        # initialized" and "initialize should only be called once"
-        msg = str(e).lower()
-        if "already initialized" in msg or "called once" in msg:
-            return False
-        raise
+    if jax.distributed.is_initialized():
+        return False  # idempotent
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=(num_processes
+                       if num_processes is not None
+                       else _env_int("JAX_NUM_PROCESSES")),
+        process_id=(process_id if process_id is not None
+                    else _env_int("JAX_PROCESS_ID")))
+    return True
 
 
 def _env_int(name: str) -> int | None:

@@ -33,11 +33,9 @@ from typing import Any
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# version-compat shard_map (utils.py): VMA jax as-is; pre-VMA jax
-# with the legacy replication rewriter disabled
-from shallowspeed_tpu.utils import shard_map
 
 from shallowspeed_tpu.models.mlp import MLPStage, accumulate_grads, zero_grads_like
 from shallowspeed_tpu.utils import pvary_over as _pvary

@@ -939,9 +939,11 @@ ring_flash_attention.defvjp(_ring_fwd_rule, _ring_bwd_rule)
 # scale planes stream into VMEM as stored, K's per-position scale
 # multiplies the score row and V's folds into the probability row —
 # the same outside-the-dot placement as `masked_attention`, so HBM
-# reads stay 1 byte/element and the reference parity is fp-reorder
-# noise only (pinned <= 1e-4 in tests/test_serving.py; compiled-mode
-# envelope recorded in bench.py's kernel_numerics_rel_err block).
+# reads stay 1 byte/element. Interpreted, the reference parity is
+# fp-reorder noise only (pinned <= 1e-4 in tests/test_serving.py).
+# Compiled, Mosaic runs the f32 dots below as one bf16 pass, so the
+# envelope is bf16 operand rounding: 0.002-0.004 relmax against the
+# reference on a v5e (chip run, PR 21; `bench.paged_decode_pass`).
 
 
 def _paged_decode_kernel(bt_ref, pos_ref, *refs, scale, bs, w, window,
@@ -1029,8 +1031,9 @@ def paged_flash_decode(q, pool_blk, bt, pos, *, window: int = 0,
 
     Matches `masked_attention(q, gather_table(pool, bt), valid)` — the
     XLA reference that stays in `serving/cache.py` — to fp-reorder
-    noise (<= 1e-4 pinned): same f32 score/softmax path, same
-    outside-the-dot int8 scale placement, no gathered copy. GQA is
+    noise interpreted (<= 1e-4 pinned) and to bf16 operand rounding
+    compiled (see the block comment above): same score/softmax path,
+    same outside-the-dot int8 scale placement, no gathered copy. GQA is
     native (H = G * Hkv query heads fold into the program's row axis).
     """
     if interpret is None:

@@ -73,16 +73,8 @@ SCHEDULES = {"constant": constant, "linear": warmup_linear,
 def _varying_axes(x, axes: tuple) -> tuple:
     """The subset of `axes` the value actually varies over (shard_map VMA
     typing). A leaf invariant over an axis is already fully reduced there
-    — psumming it would count it axis-size times. Refuses to guess when
-    VMA introspection is unavailable: a silent wrong norm (replicated
-    leaves counted axis-size times) is worse than an error."""
-    try:
-        vma = jax.typeof(x).vma
-    except Exception as e:
-        raise RuntimeError(
-            "global_norm with mesh axes needs shard_map VMA introspection "
-            "(jax.typeof(...).vma) to tell sharded gradient leaves from "
-            "replicated ones; this jax version does not expose it") from e
+    — psumming it would count it axis-size times."""
+    vma = jax.typeof(x).vma
     return tuple(a for a in axes if a in vma)
 
 

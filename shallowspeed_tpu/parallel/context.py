@@ -27,11 +27,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# version-compat shard_map (utils.py): VMA jax as-is; pre-VMA jax
-# with the legacy replication rewriter disabled
-from shallowspeed_tpu.utils import shard_map
 
 from shallowspeed_tpu.models import transformer as T
 from shallowspeed_tpu.ops.attention import (attention, ring_attention,
@@ -248,25 +246,30 @@ class ContextParallelEngine:
                 OV.bucket_signature([_p_leaves[i] for i in bk])
                 for bk in ov_plan]
 
-            def tagged_loss_and_gsum(params_v, tokens, targets, key,
-                                     tag):
+            def tagged_loss_and_gsum(params, tokens, targets, key,
+                                     tag, tag_in=lambda p: p):
                 """tile_loss_and_gsum with the reduction tags applied
                 to the (peeled) last microbatch's params: returns
-                (pmean'd loss, REDUCED grad sum, scale)."""
+                (pmean'd loss, REDUCED grad sum, scale). `params`
+                arrive invariant; `tag_in` types them as the tag's
+                primal must be (the tag's cotangent has that type)."""
                 if accum == 1:
                     lloc, gsum = jax.value_and_grad(
                         lambda p: local_loss(tag(p, None), tokens,
-                                             targets, key))(params_v)
+                                             targets, key))(
+                                                 tag_in(params))
                     return (jax.lax.pmean(lloc, ("dp", "sp")), gsum,
                             1.0 / n_tiles)
                 tok_r, tgt_r = mu_split(tokens, targets)
                 loss_head, acc = partial_grad_sum(
-                    params_v, tok_r[:-1], tgt_r[:-1], key)
+                    pvary_over(params, ("dp", "sp")), tok_r[:-1],
+                    tgt_r[:-1], key)
                 k_last = (None if key is None
                           else jax.random.fold_in(key, accum - 1))
                 l_last, gsum = jax.value_and_grad(
                     lambda p: local_loss(tag(p, acc), tok_r[-1],
-                                         tgt_r[-1], k_last))(params_v)
+                                         tgt_r[-1], k_last))(
+                                             tag_in(params))
                 return (jax.lax.pmean((loss_head + l_last) / accum,
                                       ("dp", "sp")),
                         gsum, 1.0 / (n_tiles * accum))
@@ -277,8 +280,7 @@ class ContextParallelEngine:
                         p, ("dp", "sp"), ov_plan, acc=acc)
 
                 loss, gsum, scale = tagged_loss_and_gsum(
-                    pvary_over(params, ("dp", "sp")), tokens, targets,
-                    train_key(step), tag)
+                    params, tokens, targets, train_key(step), tag)
                 return loss, tree_map(lambda g: g * scale, gsum)
 
             lag = loss_and_grads_ov
@@ -338,7 +340,8 @@ class ContextParallelEngine:
                     # the peeled-scan accumulator folded in; same wire
                     # bytes, reduction interleaved with the backward
                     from shallowspeed_tpu.parallel.overlap import (
-                        scatter_grads_on_backward, take_local_shard)
+                        scatter_grads_on_backward, scatter_tag_input,
+                        take_local_shard)
 
                     def tag(p, acc):
                         return scatter_grads_on_backward(
@@ -346,8 +349,9 @@ class ContextParallelEngine:
                             extra_axes=("sp",))
 
                     loss, grads, gscale = tagged_loss_and_gsum(
-                        pvary_over(params, ("dp", "sp")), tokens,
-                        targets, key, tag)
+                        params, tokens, targets, key, tag,
+                        partial(scatter_tag_input, axis="dp",
+                                dims=gdims))
                     leaves, tdef = jax.tree_util.tree_flatten(grads)
                     grads = jax.tree_util.tree_unflatten(tdef, [
                         take_local_shard(g, dim, "dp") * gscale
@@ -443,9 +447,8 @@ class ContextParallelEngine:
             # Run fusion: a whole multi-step run as ONE XLA dispatch
             # (`lax.scan` over optimizer steps, batches HBM-resident) —
             # the transformer-family counterpart of the MLP engine's
-            # `train_run` (engine.py), and the honest way to measure
-            # steady-state throughput when per-dispatch latency (e.g. a
-            # tunneled backend) would otherwise pollute step timing.
+            # `train_run` (engine.py): steady-state throughput with
+            # per-dispatch host latency out of the step timing.
             @partial(jax.jit, donate_argnums=(0, 1))
             @partial(shard_map, mesh=mesh,
                      in_specs=(P(), P(), P(None, "dp", "sp"),

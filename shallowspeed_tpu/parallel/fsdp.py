@@ -113,7 +113,7 @@ class FSDPEngine(GSPMDEngine):
 
         from shallowspeed_tpu.optim import Adafactor
         from shallowspeed_tpu.parallel import overlap as OV
-        from shallowspeed_tpu.utils import shard_map
+        from jax import shard_map
 
         if isinstance(optimizer, Adafactor):
             raise ValueError(

@@ -15,9 +15,10 @@ every engine family:
 
 - **Bucket plans** (`plan_buckets`): partition the grad pytree's leaves,
   in backward-finalization order, into size-targeted buckets
-  (`--bucket-mb`). One bucket = ONE collective bind (a multi-operand
-  `psum`), so the wire sees few right-sized collectives instead of one
-  late bulk reduction or dozens of latency-bound per-leaf ones.
+  (`--bucket-mb`). One bucket = one `psum` call over its member
+  leaves, issued where the bucket's last gradient is final (jax binds
+  one reduction per member; XLA's all-reduce combiner merges
+  neighbours), instead of one late bulk reduction.
 - **Reduce-on-backward tags** (`reduce_grads_on_backward`): a custom-VJP
   identity whose backward psums a bucket's cotangents over the data
   axes *at the point the bucket's last leaf gradient is produced* —
@@ -63,8 +64,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import Literal
 
-from shallowspeed_tpu.analysis.walker import _as_jaxpr, aval_bytes, sub_jaxprs
+from shallowspeed_tpu.analysis.walker import (COLLECTIVES,
+                                              _as_jaxpr, aval_bytes,
+                                              collective_axes,
+                                              sub_jaxprs)
+from shallowspeed_tpu.utils import pvary_over
 
 tree_map = jax.tree_util.tree_map
 
@@ -148,32 +154,32 @@ def plan_param_buckets(params, bucket_bytes: int):
 
 # ------------------------------------------- reduce-on-backward tags
 
-# Identity forward, per-bucket psum backward: applied to the params a
-# loss is differentiated against, the transpose runs when ALL the
-# bucket's cotangents are final — for a bucket of layer-i leaves,
+# A `pcast(..., to="varying")` whose transpose is ours to place: the
+# forward casts params that are INVARIANT over `axes` to varying (what
+# shard_map would insert implicitly where they meet per-device data),
+# the backward psums each bucket's cotangents back to invariant — the
+# type a custom VJP must return for an invariant primal. Applied to the
+# params a loss is differentiated against, the transpose runs when ALL
+# the bucket's cotangents are final — for a bucket of layer-i leaves,
 # right after layer i's backward matmuls, dataflow-independent of the
 # backward of layers < i. `acc` (unreduced grads of earlier
 # microbatches, from a peeled accumulation scan) is folded in BEFORE
-# the psum so wire bytes equal the bulk path's. On pre-VMA jax this is
-# the tree/bucket generalization of `utils.tp_region_enter`; on VMA
-# jax variance typing transposes the same way (the psum re-types the
-# varying cotangents invariant, which is what the callers' out_specs
-# declare).
+# the psum so wire bytes equal the bulk path's.
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _reduce_tag(axes, leaves, acc):
-    return leaves
+    return pvary_over(leaves, axes)
 
 
 def _reduce_tag_fwd(axes, leaves, acc):
-    return leaves, acc
+    return pvary_over(leaves, axes), acc
 
 
 def _reduce_tag_bwd(axes, acc, g):
     if acc is not None:
         g = tuple(jnp.add(a, b) for a, b in zip(g, acc))
-    g = jax.lax.psum(g, axes)  # ONE multi-operand bind = one collective
+    g = jax.lax.psum(g, axes)
     zeros = None if acc is None else tuple(jnp.zeros_like(a) for a in acc)
     return (g, zeros)
 
@@ -182,10 +188,11 @@ _reduce_tag.defvjp(_reduce_tag_fwd, _reduce_tag_bwd)
 
 
 def reduce_grads_on_backward(params, axes, plan, acc=None):
-    """Tag `params` so differentiating through the tagged tree reduces
-    each bucket's cotangents over `axes` inside the backward. `plan`
-    indexes the tree's flatten order (`plan_param_buckets`); leaves not
-    covered by any bucket pass through untagged (caller reduces them)."""
+    """Tag `params` (invariant over `axes`) so differentiating through
+    the tagged tree reduces each bucket's cotangents over `axes` inside
+    the backward. `plan` indexes the tree's flatten order
+    (`plan_param_buckets`); leaves not covered by any bucket pass
+    through untagged (shard_map's own typing reduces them)."""
     leaves, tdef = jax.tree_util.tree_flatten(params)
     acc_leaves = (None if acc is None
                   else jax.tree_util.tree_flatten(acc)[0])
@@ -205,11 +212,11 @@ def reduce_grads_on_backward(params, axes, plan, acc=None):
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _scatter_tag(axis, extra_axes, dims, leaves, acc):
-    return leaves
+    return pvary_over(leaves, (axis,) + tuple(extra_axes))
 
 
 def _scatter_tag_fwd(axis, extra_axes, dims, leaves, acc):
-    return leaves, acc
+    return pvary_over(leaves, (axis,) + tuple(extra_axes)), acc
 
 
 def _scatter_tag_bwd(axis, extra_axes, dims, acc, g):
@@ -250,7 +257,10 @@ def scatter_grads_on_backward(params, axis, dims, plan, acc=None,
     leaf's scatter dimension, None = plain psum), after an optional
     full psum over `extra_axes`. The cotangents come back full-shaped
     with the reduced shard embedded at this device's slot — slice with
-    `take_local_shard` after `value_and_grad`."""
+    `take_local_shard` after `value_and_grad`. Those embedded shards
+    differ per device, so a scattered leaf's cotangent is typed varying
+    over `axis` and its primal must be too: differentiate against
+    `scatter_tag_input(params, axis, dims)`."""
     leaves, tdef = jax.tree_util.tree_flatten(params)
     acc_leaves = (None if acc is None
                   else jax.tree_util.tree_flatten(acc)[0])
@@ -265,6 +275,17 @@ def scatter_grads_on_backward(params, axis, dims, plan, acc=None,
         for slot, i in enumerate(bucket):
             out[i] = tagged[slot]
     return jax.tree_util.tree_unflatten(tdef, out)
+
+
+def scatter_tag_input(params, axis, dims):
+    """`params` typed as `scatter_grads_on_backward` must receive them:
+    leaves with a scatter dim varying over `axis` (their cotangent is
+    this device's embedded shard), the rest left invariant (plain
+    psum)."""
+    leaves, tdef = jax.tree_util.tree_flatten(params)
+    return jax.tree_util.tree_unflatten(tdef, [
+        l if dim is None else pvary_over(l, (axis,))
+        for l, dim in zip(leaves, dims)])
 
 
 def take_local_shard(leaf, dim, axis):
@@ -410,35 +431,19 @@ def bucket_signature(leaves) -> tuple:
 
 # -------------------------------------------- exposure accounting
 
-# The reduction/collective primitive sets (psum_scatter traces as
-# either name depending on the path; ppermute is the pipeline/ring
-# hop; all_gather is FSDP's param prefetch).
-REDUCE_PRIMS = {"psum", "psum_scatter", "reduce_scatter"}
-COMM_PRIMS = REDUCE_PRIMS | {"ppermute", "all_gather", "all_to_all",
-                             "pbroadcast", "pgather"}
-
-_AXIS_PARAM = {"psum": "axes", "pgather": "axes", "pbroadcast":
-               "axis_name", "ppermute": "axis_name", "all_gather":
-               "axis_name", "reduce_scatter": "axis_name",
-               "psum_scatter": "axis_name", "all_to_all": "axis_name"}
-
-
-def eqn_axes(eqn) -> tuple:
-    axes = eqn.params.get(_AXIS_PARAM.get(eqn.primitive.name, "axes"))
-    if axes is None:
-        return ()
-    if not isinstance(axes, (tuple, list)):
-        axes = (axes,)
-    return tuple(a for a in axes if isinstance(a, str))
+# The collectives priced as communication (ppermute is the pipeline/
+# ring hop; all_gather is FSDP's param prefetch; pmin/pmax carry only
+# scalar statistics and are left out).
+COMM_PRIMS = COLLECTIVES - {"pmin", "pmax"}
 
 
 def _operand_bytes(eqn) -> int:
     return sum(aval_bytes(v.aval) for v in eqn.invars
-               if not isinstance(v, jax.core.Literal))
+               if not isinstance(v, Literal))
 
 
 def _eqn_is_heavy(eqn, cache: dict) -> bool:
-    """MXU-heavy: a dot_general/conv, or a sub-jaxpr (scan, pjit,
+    """MXU-heavy: a dot_general/conv, or a sub-jaxpr (scan, jit,
     remat, ...) containing one — the compute a collective can hide
     under."""
     name = eqn.primitive.name
@@ -473,7 +478,7 @@ def _scope_overlap(jaxpr, trips: int, acc: dict, cache: dict,
     for i, eqn in enumerate(eqns):
         m = 0
         for v in eqn.invars:
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, Literal):
                 continue
             jdx = prod.get(id(v))
             if jdx is not None:
@@ -485,7 +490,7 @@ def _scope_overlap(jaxpr, trips: int, acc: dict, cache: dict,
         name = eqn.primitive.name
         if name in COMM_PRIMS:
             if axes_filter is not None and not (
-                    set(eqn_axes(eqn)) & set(axes_filter)):
+                    set(collective_axes(eqn)) & set(axes_filter)):
                 continue
             nbytes = _operand_bytes(eqn) * trips
             overlappable = any(

@@ -94,11 +94,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# version-compat shard_map (utils.py): VMA jax as-is; pre-VMA jax
-# with the legacy replication rewriter disabled
-from shallowspeed_tpu.utils import shard_map
 
 from shallowspeed_tpu.models import transformer as T
 from shallowspeed_tpu.ops.attention import attention
@@ -413,28 +411,15 @@ class PipelineLMEngine:
         kv_local = cfg.kv_heads // self.tp
         hd = cfg.head_dim
 
+        # Megatron placement: a psum over 'tp' after each row-parallel
+        # matmul. shard_map's variance typing transposes it correctly
+        # (the replicated residual stream entering column-parallel
+        # compute needs no marker of its own).
         if self.has_tp:
-            # Megatron conjugate pair (utils.py): psum_tp after the
-            # row-parallel matmuls, enter_tp where the replicated
-            # residual stream feeds column-parallel compute. On VMA jax
-            # enter_tp is identity and psum_tp a plain lax.psum; on
-            # pre-VMA jax both carry explicit custom VJPs — autodiff
-            # straight through a bare psum there double-counted the
-            # sharded-weight grads tp x and left the replicated-param
-            # cotangents shard-partial (caught by the health pack's
-            # oracle parity, round 7).
-            from shallowspeed_tpu.utils import tp_allreduce, tp_region_enter
-
             def psum_tp(x):
-                return tp_allreduce(x, "tp")
-
-            def enter_tp(x):
-                return tp_region_enter(x, "tp")
+                return jax.lax.psum(x, "tp")
         else:
             def psum_tp(x):
-                return x
-
-            def enter_tp(x):
                 return x
 
         w = cfg.attn_window  # windows compose with every substrate
@@ -491,7 +476,7 @@ class PipelineLMEngine:
             k_attn = k_ffn = None
             if key is not None and cfg.dropout > 0.0:
                 k_attn, k_ffn = jax.random.split(key)
-            h = enter_tp(T._norm(blk["ln1"], x, cfg))
+            h = T._norm(blk["ln1"], x, cfg)
             if cfg.gqa:  # split projections; each shard owns whole groups
                 q = (h @ blk["q"]["W"] + blk["q"]["b"]).reshape(
                     b, t, heads_local, hd)
@@ -514,7 +499,7 @@ class PipelineLMEngine:
             x = x + T._dropout(
                 psum_tp(a @ blk["proj"]["W"]) + blk["proj"]["b"],
                 cfg.dropout, k_attn)
-            h = enter_tp(T._norm(blk["ln2"], x, cfg))
+            h = T._norm(blk["ln2"], x, cfg)
             aux = jnp.float32(0.0)
             if cfg.n_experts > 0:
                 from shallowspeed_tpu.ops.moe import moe_ffn, moe_ffn_ep
@@ -799,13 +784,7 @@ class PipelineLMEngine:
                 return jax.lax.pmean(loss, "dp"), grads
             # pvary the params and reduce each leaf EXPLICITLY over the
             # axes it is invariant on (reduce_plain — the same per-leaf
-            # contract the 1F1B/zb/vpp paths use). Round 7: this branch
-            # used to lean on variance-typed autodiff for the grad
-            # reductions, which pre-VMA jax (check_rep=False shim)
-            # simply does not have — head/ln_f grads came back as one
-            # device's zero partial (never trained) and dp>1 grads
-            # stayed per-tile partials; caught by the health pack's
-            # oracle parity, invisible to the loss-only parity tests.
+            # contract the 1F1B/zb/vpp paths use).
             (loss, _), grads = jax.value_and_grad(
                 local_loss, has_aux=True)(
                     _pvary(params, vary_axes), tokens, targets, key)

@@ -41,11 +41,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# version-compat shard_map (utils.py): VMA jax as-is; pre-VMA jax
-# with the legacy replication rewriter disabled
-from shallowspeed_tpu.utils import shard_map
 
 from shallowspeed_tpu.models.mlp import init_linear_np, stage_layer_sizes
 from shallowspeed_tpu.utils import pvary_over as _pvary

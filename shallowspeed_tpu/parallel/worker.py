@@ -40,11 +40,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# version-compat shard_map (utils.py): VMA jax as-is; pre-VMA jax
-# with the legacy replication rewriter disabled
-from shallowspeed_tpu.utils import shard_map
 
 from shallowspeed_tpu.models.mlp import MLPStage
 from shallowspeed_tpu.parallel.instructions import (

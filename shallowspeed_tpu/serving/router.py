@@ -488,9 +488,14 @@ class ReplicaProc:
                  hang_timeout: float | None = None,
                  startup_timeout: float | None = None,
                  term_grace: float = 5.0, timeout: float = 5.0,
-                 stdout_path: str | None = None, clock=time.time):
+                 stdout_path: str | None = None, clock=time.time,
+                 env: dict | None = None):
         self.name = name
         self.argv = list(argv)
+        # the child's whole environment (None = inherit): on a chip
+        # host the caller narrows it to ONE chip per replica
+        # (runtime.one_chip_env), kept across respawns
+        self.env = env
         self.collector = collector
         self.heartbeat_file = heartbeat_file
         self.hang_timeout = hang_timeout
@@ -533,7 +538,7 @@ class ReplicaProc:
         try:
             self.proc = subprocess.Popen(
                 self.argv, stdout=out, stderr=out,
-                stdin=subprocess.DEVNULL)
+                stdin=subprocess.DEVNULL, env=self.env)
         finally:
             if out is not None:
                 out.close()           # the child holds its own fd

@@ -151,9 +151,9 @@ def roofline_of_jaxpr(closed) -> dict:
     size. Collectives are skipped here — their bytes are wire traffic
     (`collectives.traffic_of_jaxpr`), not HBM work.
     """
-    from shallowspeed_tpu.analysis.walker import (_as_jaxpr, dot_flops,
+    from shallowspeed_tpu.analysis.walker import (COLLECTIVES,
+                                                  _as_jaxpr, dot_flops,
                                                   eqn_bytes, sub_jaxprs)
-    from shallowspeed_tpu.telemetry.collectives import _COLLECTIVES
 
     acc = {"flops_shard": 0, "flops_global": 0,
            "flops_fp8_shard": 0, "flops_fp8_global": 0,
@@ -176,7 +176,7 @@ def roofline_of_jaxpr(closed) -> dict:
         j = _as_jaxpr(jaxpr)
         for eqn in j.eqns:
             name = eqn.primitive.name
-            if name in _COLLECTIVES:
+            if name in COLLECTIVES:
                 continue
             subs = sub_jaxprs(eqn)
             if subs:

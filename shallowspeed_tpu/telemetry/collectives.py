@@ -25,33 +25,17 @@ branches differ.
 from __future__ import annotations
 
 import jax
+from jax.extend.core import Literal
 
-from shallowspeed_tpu.analysis.walker import (_as_jaxpr, aval_bytes,
+from shallowspeed_tpu.analysis.walker import (COLLECTIVES,
+                                              _as_jaxpr, aval_bytes,
+                                              collective_axes,
                                               sub_jaxprs)
-
-# collective primitive -> the eqn param naming its mesh axes
-# (mirrors analysis.rules._COLLECTIVES, minus axis_index which moves
-# no data)
-_COLLECTIVES = {
-    "psum": "axes", "pmin": "axes", "pmax": "axes",
-    "ppermute": "axis_name", "pbroadcast": "axis_name",
-    "all_gather": "axis_name", "reduce_scatter": "axis_name",
-    "psum_scatter": "axis_name", "all_to_all": "axis_name",
-    "pgather": "axes",
-}
-
-
-def _axis_names(axes) -> tuple:
-    if axes is None:
-        return ()
-    if not isinstance(axes, (tuple, list)):
-        axes = (axes,)
-    return tuple(a for a in axes if isinstance(a, str))
 
 
 def _operand_bytes(eqn) -> int:
     return sum(aval_bytes(v.aval) for v in eqn.invars
-               if not isinstance(v, jax.core.Literal))
+               if not isinstance(v, Literal))
 
 
 def _scan_length(eqn) -> int | None:
@@ -88,10 +72,9 @@ def traffic_of_jaxpr(closed) -> dict:
         j = _as_jaxpr(jaxpr)
         for eqn in j.eqns:
             name = eqn.primitive.name
-            key = _COLLECTIVES.get(name)
-            if key is not None:
+            if name in COLLECTIVES:
                 nbytes = _operand_bytes(eqn)
-                axes = _axis_names(eqn.params.get(key)) or ("?",)
+                axes = collective_axes(eqn) or ("?",)
                 for ax in axes:
                     add(acc_axis, ax, nbytes, trips)
                 add(acc_prim, name, nbytes, trips)

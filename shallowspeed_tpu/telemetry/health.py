@@ -16,9 +16,8 @@ entrypoints and zero recompiles** (the step executable simply grows a
 few scalar outputs; pinned by `tests/test_health.py`'s compile-count
 tests, the same counter the analysis retrace rule reads).
 
-Reductions are correct on every mesh, through ONE rule that holds on
-both jax generations (VMA and pre-VMA shard_map alike, unlike VMA
-introspection): the pack is computed on the engine's fully REDUCED
+Reductions are correct on every mesh, through ONE rule: the pack is
+computed on the engine's fully REDUCED
 gradients, and each per-leaf statistic is `psum`'d over exactly the
 mesh axes that leaf's PartitionSpec *shards* — the one piece of truth
 every engine already owns. Concretely:

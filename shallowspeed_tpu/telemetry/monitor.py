@@ -956,7 +956,7 @@ class StatusServer:
     """stdlib status endpoint: GET /status.json and /metrics on
     127.0.0.1:`port` (port 0 picks a free one — read `.port`). Runs on
     a daemon thread; `close()` shuts it down. No auth, loopback bind —
-    an operator tunnel (ssh -L) is the expected transport, same as
+    an operator's ssh port forward is the expected transport, same as
     jax's profiler server.
 
     Unknown paths answer 404 with a JSON error body (round 17) — a

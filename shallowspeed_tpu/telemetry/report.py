@@ -595,6 +595,12 @@ class RunTelemetry:
             "engine": type(self.engine).__name__,
             "static": static,
             "hbm_live_mib": round(live["max_device_bytes"] / MiB, 2),
+            # every device's share, not only the fullest one: live
+            # array shards per device, and the allocator's own view
+            # where the backend has one — what shows that a mesh of N
+            # chips really holds state on all N
+            "hbm_live_per_device": live["per_device"],
+            "device_stats": memory.device_memory_stats(),
             "compile_counts": counts,
             "bubble": self._bubble or None,
             # the engine's last on-device health pack (grad/param

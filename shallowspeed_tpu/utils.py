@@ -59,137 +59,16 @@ def assert_replicas_in_sync(params: Any) -> None:
                     f"{key}: {sorted(hashes)}")
 
 
-# Does this jax generation type shard_map values by varying-manual-axes
-# (VMA)? Gates BOTH compat shims below: on VMA jax, `pvary_over` does the
-# carry/branch typing and shard_map's default checking IS that typing; on
-# pre-VMA jax, pvary has nothing to do and the old rewrite-based
-# replication checker (which predates several primitives these engines
-# trace) must be disabled instead.
-_HAS_VMA = hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
-
-
-def shard_map(f=None, **kw):
-    """`jax.shard_map` across API generations (drop-in for the engines'
-    `partial(shard_map, mesh=..., in_specs=..., out_specs=...)` idiom).
-    On pre-VMA jax, passes `check_rep=False`: the engines' programs are
-    variance-typed for VMA shard_map, and the legacy replication
-    rewriter rejects primitives they rely on (scan-carried ppermute
-    chains and friends) with "No replication rule". The collective
-    structure itself is unchanged — `analysis`'s collective rule and the
-    cross-engine parity tests check it, not the legacy rewriter."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm
-    if not _HAS_VMA:
-        kw.setdefault("check_rep", False)
-    if f is None:
-        return lambda g: _sm(g, **kw)
-    return _sm(f, **kw)
-
-
-def _pvary_leaf(leaf, ax: str):
-    """One leaf to 'varying' over `ax`, across jax API generations:
-    `lax.pcast(..., to="varying")` (newest), `lax.pvary` (the rename it
-    shipped under first), or identity on pre-VMA jax — there shard_map
-    has no varying-manual-axes types, so the cast has nothing to do."""
-    lax = jax.lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(leaf, (ax,), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(leaf, (ax,))
-    return leaf
-
-
 def pvary_over(tree: Any, axes: tuple[str, ...]) -> Any:
     """Cast a pytree to 'varying' over the given shard_map mesh axes (VMA).
 
     Inside `shard_map`, axis-invariant constants (e.g. a zeros scan-carry
     init) and axis-varying data (e.g. outputs of `ppermute`) have different
-    types; this casts the former so carries typecheck. Skips axes a leaf
-    already varies over (pcast rejects those).
+    types; this casts the former so carries typecheck. Axes a leaf already
+    varies over are left alone (pcast rejects those).
     """
     def cast(leaf):
-        for ax in axes:
-            try:
-                leaf = _pvary_leaf(leaf, ax)
-            except ValueError:
-                pass  # already varying over this axis
-        return leaf
+        missing = tuple(ax for ax in axes if ax not in jax.typeof(leaf).vma)
+        return jax.lax.pcast(leaf, missing, to="varying") if missing else leaf
 
     return jax.tree_util.tree_map(cast, tree)
-
-
-# --------------------------- Megatron conjugate collectives (pre-VMA)
-#
-# Differentiating THROUGH an in-block `lax.psum` is only correct when
-# shard_map's variance typing (VMA) is there to transpose it: on pre-VMA
-# jax with `check_rep=False` the legacy rule transposes psum to psum, so
-# a replicated cotangent gets summed tp times (tensor-sharded weight
-# grads come out exactly tp x too large), and nothing inserts the psum
-# a tp-PARTIAL cotangent needs on the way back to replicated params
-# (layernorm/embedding grads come out shard-partial). Caught at runtime
-# by the health pack's oracle parity (telemetry/health.py, round 7) —
-# every pp x tp config trained with corrupted gradients on pre-VMA jax
-# while loss-only parity tests stayed green.
-#
-# The fix is Megatron-LM's conjugate operator pair, as explicit
-# custom-VJP ops gated on the jax generation (on VMA jax both are
-# trivial — variance typing already transposes correctly):
-#   tp_allreduce ("g"): psum forward, identity backward — placed after
-#     row-parallel matmuls, where the forward needs the cross-shard sum
-#     and the backward cotangent is already replicated.
-#   tp_region_enter ("f"): identity forward, psum backward — placed
-#     where the replicated residual stream enters column-parallel
-#     compute, so the shard-partial cotangents are summed exactly once.
-
-from functools import partial as _partial
-
-
-@_partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _psum_fwd_identity_bwd(axis, x):
-    return jax.lax.psum(x, axis)
-
-
-def _pfib_fwd(axis, x):
-    return jax.lax.psum(x, axis), None
-
-
-def _pfib_bwd(axis, _res, g):
-    return (g,)
-
-
-_psum_fwd_identity_bwd.defvjp(_pfib_fwd, _pfib_bwd)
-
-
-@_partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _identity_fwd_psum_bwd(axis, x):
-    return x
-
-
-def _ifpb_fwd(axis, x):
-    return x, None
-
-
-def _ifpb_bwd(axis, _res, g):
-    return (jax.lax.psum(g, axis),)
-
-
-_identity_fwd_psum_bwd.defvjp(_ifpb_fwd, _ifpb_bwd)
-
-
-def tp_allreduce(x, axis: str = "tp"):
-    """All-reduce a row-parallel partial sum over `axis` with the
-    backward a tensor-parallel program needs (see block comment)."""
-    if _HAS_VMA:
-        return jax.lax.psum(x, axis)
-    return _psum_fwd_identity_bwd(axis, x)
-
-
-def tp_region_enter(x, axis: str = "tp"):
-    """Mark a replicated activation's entry into column-parallel
-    compute: identity forward, cotangent psum over `axis` on pre-VMA
-    jax (see block comment)."""
-    if _HAS_VMA:
-        return x
-    return _identity_fwd_psum_bwd(axis, x)

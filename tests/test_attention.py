@@ -11,13 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# the version-compat wrapper (check_rep=False on pre-VMA jax): the
-# legacy replication rewriter has no rule for pallas_call, so the
-# flash-substrate cases below would otherwise raise NotImplementedError
-# — the engines run all their shard_maps through this same wrapper
-from shallowspeed_tpu.utils import shard_map
 
 from shallowspeed_tpu.ops.attention import (attention, ring_attention,
                                             ulysses_attention)

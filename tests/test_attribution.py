@@ -57,7 +57,7 @@ def test_roofline_scan_multiplies_trips_and_skips_collectives():
 
 
 def test_roofline_shard_map_lands_in_per_device_bucket():
-    from shallowspeed_tpu.utils import shard_map as smap
+    from jax import shard_map as smap
 
     mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("dp",))
     from jax.sharding import PartitionSpec as P

@@ -220,11 +220,8 @@ def test_streaming_fwd_matches_resident(monkeypatch):
 def _shmap_ring(fn, sp, axis="sp"):
     from functools import partial
 
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-
-    # compat wrapper (utils.py): pre-VMA jax's replication rewriter has
-    # no rule for pallas_call — the engines use this same wrapper
-    from shallowspeed_tpu.utils import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:sp]).reshape(sp), (axis,))
     return jax.jit(partial(
@@ -278,7 +275,7 @@ def test_ring_flash_matches_oracle(sp, kvh, window):
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from shallowspeed_tpu.utils import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:sp]).reshape(sp), ("sp",))
     spec = P(None, "sp")
@@ -286,9 +283,7 @@ def test_ring_flash_matches_oracle(sp, kvh, window):
     # the differentiated function): run SPMD, every device seeds its own
     # partial with 1 and the ring VJP's reverse hops deliver the
     # cross-device cotangents, so the per-device grad outputs ARE the
-    # global-loss grads. Differentiating THROUGH a psum is only correct
-    # under VMA variance typing, which the check_rep=False compat
-    # shard_map (pre-VMA jax) does not have.
+    # global-loss grads.
     ring_grad = jax.jit(partial(shard_map(
         lambda a, b_, c: jax.grad(
             lambda x, y, z: (ring_flash_attention(
@@ -335,7 +330,7 @@ def test_ring_flash_streaming_chunks(monkeypatch):
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from shallowspeed_tpu.utils import shard_map
+    from jax import shard_map
 
     g_ref = jax.grad(lambda *a: (attention(*a, causal=True) ** 2).sum(),
                      argnums=(0, 1, 2))(q, k, v)

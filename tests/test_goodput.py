@@ -5,12 +5,11 @@ The two acceptance pins live here:
 - a supervisor kill/restart run whose ledger accounts for >= 95% of
   wall clock, with restart downtime itemized and cross-checked against
   the child processes' own JSONL wall stamps;
-- the `--regress` gate passing on the committed BENCH_r01-r05
-  trajectory and demonstrably failing on a synthetic regression.
+- the `--regress` gate passing on a steady trajectory and
+  demonstrably failing on a synthetic regression.
 """
 
 import json
-import shutil
 import subprocess
 import sys
 import textwrap
@@ -210,28 +209,31 @@ def test_supervisor_autodetects_child_log_file(tmp_path):
 # ------------------------------------------------- bench --regress gate
 
 
-def test_regress_gate_passes_on_committed_trajectory(capsys):
+def _write_trajectory(dirpath, mfus):
+    """One BENCH_rNN.json per entry of `mfus`, in the driver's record
+    layout (round number `n`, headline metrics under `parsed`)."""
+    for n, mfu in enumerate(mfus, start=1):
+        (dirpath / f"BENCH_r{n:02d}.json").write_text(json.dumps(
+            {"n": n, "parsed": {"value": 3.6e6 + 1e4 * n,
+                                "vs_baseline": 90.0 + n,
+                                "transformer_mfu": mfu}}))
+
+
+def test_regress_gate_passes_on_steady_trajectory(tmp_path, capsys):
     from shallowspeed_tpu.telemetry.regress import main as rmain
 
-    assert rmain([str(ROOT)]) == 0
+    _write_trajectory(tmp_path, [0.552, 0.561, 0.575, 0.582, 0.566])
+    assert rmain([str(tmp_path)]) == 0
     out = capsys.readouterr().out
-    assert "regress gate: OK" in out
+    assert "regress gate: OK" in out and "5 round(s)" in out
 
 
 def test_regress_gate_fails_on_synthetic_regression(tmp_path, capsys):
     from shallowspeed_tpu.telemetry.regress import main as rmain
 
-    rounds = []
-    for f in sorted(ROOT.glob("BENCH_r*.json")):
-        shutil.copy(f, tmp_path / f.name)
-        rounds.append(int(json.loads(f.read_text()).get("n", 0)))
-    bad = json.loads((ROOT / "BENCH_r05.json").read_text())
-    # the synthetic regression must be the NEWEST round — the gate
-    # only judges the last entry, so pin past the committed trajectory
-    bad["n"] = max(rounds) + 1
-    bad["parsed"]["transformer_mfu"] = 0.40   # ~29% below the median
-    (tmp_path / f"BENCH_r{bad['n']:02d}.json").write_text(
-        json.dumps(bad))
+    # the gate only judges the NEWEST round: ~29% below the median
+    _write_trajectory(tmp_path, [0.552, 0.561, 0.575, 0.582, 0.566,
+                                 0.40])
     assert rmain([str(tmp_path)]) == 1
     out = capsys.readouterr().out
     assert "REGRESSION" in out and "transformer_mfu" in out
@@ -259,7 +261,7 @@ def test_regress_band_widens_with_recorded_spread():
 def test_regress_vacuous_on_short_trajectory(tmp_path):
     from shallowspeed_tpu.telemetry.regress import main as rmain
 
-    shutil.copy(ROOT / "BENCH_r01.json", tmp_path / "BENCH_r01.json")
+    _write_trajectory(tmp_path, [0.552])
     assert rmain([str(tmp_path)]) == 0
 
 

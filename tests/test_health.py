@@ -152,9 +152,9 @@ def test_fsdp_health_matches_single_device_oracle():
 
 
 def test_pipeline_tp_health_matches_oracle():
-    """pp x tp: block stats psum over BOTH sharded axes in-program.
-    (This parity is what caught the pre-VMA pp x tp gradient corruption
-    — round 7; keep it tight.)"""
+    """pp x tp: block stats psum over BOTH sharded axes in-program
+    (keep this parity tight: it is the check that catches a wrongly
+    transposed tp reduction)."""
     from shallowspeed_tpu.parallel.pipeline_lm import PipelineLMEngine
 
     tok, tgt = lm_batch(0)

@@ -187,7 +187,8 @@ def test_fused_dp_exposure_strictly_lower_with_overlap():
     # equal wire bytes: bucketing moves the reduction, it does not
     # duplicate it
     assert on["total_bytes"] == off["total_bytes"]
-    assert on["n_collectives"] < off["n_collectives"]  # per-bucket binds
+    # psum binds one eqn per operand: bucketing regroups, never adds
+    assert on["n_collectives"] == off["n_collectives"]
 
 
 def test_fused_dp_overlap_registered():
@@ -340,9 +341,9 @@ def test_context_zero2_overlap_keeps_grad_sharding():
 # ------------------------------------------------------ fsdp engine
 
 
-def fsdp_pair(health="off"):
+def fsdp_pair(health="off", opt=Adam):
     def build(ov):
-        return FSDPEngine(CFG, Adam(1e-3),
+        return FSDPEngine(CFG, opt(1e-3),
                           Mesh(np.array(jax.devices()[:4]), ("dp",)),
                           health=health, overlap=ov)
 
@@ -350,7 +351,10 @@ def fsdp_pair(health="off"):
 
 
 def test_fsdp_overlap_matches_gspmd_oracle():
-    e_off, e_on = fsdp_pair()
+    # params compared under SGD: the k-bias slice of the fused qkv bias
+    # has a TRUE gradient of ~0 (softmax shift-invariance), and Adam
+    # normalizes its reduction-order fp noise into O(lr) drift
+    e_off, e_on = fsdp_pair(opt=SGD)
     for s in range(3):
         tok, tgt = lm_batch(s)
         l_off = e_off.train_batch(tok, tgt)

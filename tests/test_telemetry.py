@@ -318,7 +318,7 @@ def test_collective_traffic_counts_scan_trips():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from shallowspeed_tpu.telemetry.collectives import collective_traffic
-    from shallowspeed_tpu.utils import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
 
