@@ -313,6 +313,7 @@ def main(argv=None) -> int:
     # anomaly verdicts arm bounded high-rate capture windows
     # (profcap_<step>.json next to the flight-recorder dumps)
     from shallowspeed_tpu.telemetry import profiler as profiler_mod
+    from shallowspeed_tpu.telemetry.trace import tracer
 
     plane = profiler_mod.from_args(args, metrics)
     if plane is not None:
@@ -320,7 +321,6 @@ def main(argv=None) -> int:
         if mon is not None:
             mon.profiler = plane
             mon.alert_listeners.append(plane.on_alert)
-    phase_tag = profiler_mod.tag     # no-op context when plane is off
     if args.fleet_register:
         # announce this replica to a fleet collector (best effort —
         # the fleet may come up after us and poll-register instead)
@@ -388,7 +388,7 @@ def main(argv=None) -> int:
                         {"event": "error", "id": r["id"],
                          "error": f"{type(e).__name__}: {e}"}))
             if gateway is not None:
-                with phase_tag("gateway"):
+                with tracer().span("gateway"):
                     gateway.pump(eng)
             if eng.pending():
                 eng.step()
@@ -398,7 +398,7 @@ def main(argv=None) -> int:
                     and not gateway.drain_requested:
                 time.sleep(0.02)        # idle replica: await HTTP work
             if gateway is not None:
-                with phase_tag("gateway"):
+                with tracer().span("gateway"):
                     gateway.publish(eng)
             for rec in eng.request_records[len(reported):]:
                 reported.add(rec["id"])

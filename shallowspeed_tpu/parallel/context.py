@@ -580,9 +580,12 @@ class ContextParallelEngine:
                             self.params, grads, self.opt_state)
                     u.fence(self.opt_state)
             else:
-                out = self._step_fn(
-                    self.params, self.opt_state,
-                    self._place(tokens), self._place(targets), step)
+                with tracer().span("place"):
+                    tokens_d = self._place(tokens)
+                    targets_d = self._place(targets)
+                with tracer().span("dispatch"):
+                    out = self._step_fn(self.params, self.opt_state,
+                                        tokens_d, targets_d, step)
                 self.params, self.opt_state, loss = out[:3]
                 if monitored:
                     _note_step(self, out[3])

@@ -36,6 +36,9 @@ def enable_compile_cache() -> str:
     replica and smoke phase started from this checkout."""
     import jax
 
+    from shallowspeed_tpu.telemetry import trace
+
+    trace.watch_compiles()      # count this process's compiles from here
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     # cache every program, not only those that took over a second to

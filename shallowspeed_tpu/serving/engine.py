@@ -102,7 +102,6 @@ import numpy as np
 from shallowspeed_tpu import chaos
 from shallowspeed_tpu.models import generate as G
 from shallowspeed_tpu.ops.flash_attention import paged_flash_decode
-from shallowspeed_tpu.telemetry.profiler import tag as phase_tag
 from shallowspeed_tpu.telemetry.trace import tracer
 from shallowspeed_tpu.telemetry.tracing import new_span_id, new_trace_id
 from shallowspeed_tpu.models import transformer as T
@@ -620,23 +619,28 @@ class ServingEngine:
         chunked-prefill no-stall contract."""
         plan = self.chaos_plan if self.chaos_plan is not None \
             else chaos.active()
-        if plan is not None:
-            # tick-indexed faults: a serving drill reuses the training
-            # hooks — stall sleeps here (and must surface as replica
-            # skew the fleet's straggler detector names — AND, tagged,
-            # as the profiler capture's dominant host bucket), kill/
-            # nan poison the params like a training step would
-            with phase_tag("data-load"):
-                plan.on_data_load(self.counters["ticks"])
-                plan.on_step(self.counters["ticks"], engine=self)
-        # phase tags (round 17): name the scheduler's host buckets for
-        # the sampling profiler; phase_tag is a shared no-op unless a
-        # profiler is running
-        with phase_tag("block-alloc"):
-            did = self._admit()
-        with phase_tag("prefill-chunk"):
+        # the scheduler's host phases as spans (telemetry/trace.py):
+        # always in the tracer's ring, in the profiler's trace as
+        # `ss:<name>` while a session is live, and the sampling
+        # profiler's phase names while it runs. `*.fetch` spans block
+        # on the device; every other one is the host's own time.
+        tr = tracer()
+        with tr.span("engine.step", tick=self.counters["ticks"]):
+            if plan is not None:
+                # tick-indexed faults: a serving drill reuses the
+                # training hooks — stall sleeps here (and must surface
+                # as replica skew the fleet's straggler detector names
+                # — AND as the profiler capture's dominant host
+                # phase), kill/nan poison the params like a training
+                # step would
+                with tr.span("chaos"):
+                    plan.on_data_load(self.counters["ticks"])
+                    plan.on_step(self.counters["ticks"], engine=self)
+            with tr.span("admit") as sp:
+                before = self._admit_counter
+                did = self._admit()
+                sp.set(n_admitted=self._admit_counter - before)
             did = self._prefill_step() or did
-        with phase_tag("decode-tick"):
             did = self._decode_step() or did
         return did
 
@@ -901,6 +905,13 @@ class ServingEngine:
         if not pre:
             return False
         req = min(pre, key=lambda r: r.admit_seq)     # FIFO
+        tr = tracer()
+        with tr.span("prefill", rid=req.rid,
+                     chunk=req.written // self.prefill_chunk):
+            self._prefill_chunk_of(req, tr)
+        return True
+
+    def _prefill_chunk_of(self, req, tr) -> None:
         c = self.prefill_chunk
         n_tok = min(c, len(req.ctx) - req.written)
         self._lifecycle(req, "prefill", chunk=req.written // c,
@@ -914,10 +925,11 @@ class ServingEngine:
         # self-copy when there is nothing to copy) — zero executables
         cow = req.cow if req.cow is not None \
             else (SCRATCH_BLOCK, SCRATCH_BLOCK)
-        logits, self.pools = _prefill_chunk(
-            self.params, self.pools, tokens, np.int32(req.written),
-            np.int32(n_tok), bt, np.int32(cow[0]), np.int32(cow[1]),
-            cfg=self.cfg)
+        with tr.span("prefill.dispatch"):
+            logits, self.pools = _prefill_chunk(
+                self.params, self.pools, tokens, np.int32(req.written),
+                np.int32(n_tok), bt, np.int32(cow[0]), np.int32(cow[1]),
+                cfg=self.cfg)
         if req.cow is not None:
             # the copy landed: drop the reference that kept the shared
             # source block alive for it
@@ -931,18 +943,45 @@ class ServingEngine:
             # continuation index after a preemption) from the last
             # true position's logits, exactly like generate()'s
             # post-prefill sample
-            with phase_tag("sampling"):
+            with tr.span("prefill.sample"):
                 tok = _sample_jit(
                     logits, np.asarray([req.temp], np.float32),
                     np.asarray([req.seed], np.uint32),
                     np.asarray([len(req.generated)], np.int32),
                     top_k=self.top_k, top_p=self.top_p)
+            with tr.span("prefill.fetch"):
+                tok = int(np.asarray(tok)[0])
             req.phase = "decode"
             self._lifecycle(req, "decoding")
-            self._append_token(req, int(np.asarray(tok)[0]))
-        return True
+            self._append_token(req, tok)
 
     def _decode_step(self) -> bool:
+        if not any(r is not None and r.phase == "decode"
+                   for r in self.slots):
+            return False
+        tr = tracer()
+        with tr.span("decode") as sp:
+            with tr.span("decode.prep"):
+                prep = self._decode_prep()
+            if prep is None:       # every decoder was evicted for blocks
+                return False
+            actives, drafts, rows = prep
+            sp.set(n_active=len(actives), width=rows[2].shape[1])
+            with tr.span("decode.dispatch"):
+                nxt, self.pools = _decode_tick(
+                    self.params, self.pools, *rows, cfg=self.cfg,
+                    top_k=self.top_k, top_p=self.top_p,
+                    attn=self.attn_impl)
+            with tr.span("decode.fetch"):
+                nxt = np.asarray(nxt)
+            with tr.span("decode.emit"):
+                self._decode_emit(actives, drafts, nxt)
+        return True
+
+    def _decode_prep(self):
+        """The tick's host-side inputs: (decoding requests, their
+        speculative drafts, `_decode_tick`'s per-row arrays in its own
+        order), or None when no request is left to decode."""
         for req in [r for r in self.slots
                     if r is not None and r.phase == "decode"]:
             if req.slot is not None:          # not evicted meanwhile
@@ -950,9 +989,8 @@ class ServingEngine:
         actives = [r for r in self.slots
                    if r is not None and r.phase == "decode"]
         if not actives:
-            return False
+            return None
         s = self.max_slots
-        bs = self.block_size
         # speculative drafts claim the tick's FREE rows (empty slots
         # and prefilling requests' idle rows) — occupancy is data, so
         # drafting costs zero executables and zero extra tick time
@@ -1011,11 +1049,12 @@ class ServingEngine:
                                  tick=self.counters["ticks"])
             self._tick_widths.add(w)
         self._last_width = w
-        nxt, self.pools = _decode_tick(
-            self.params, self.pools, tok, pos, bt, temp, seeds, idx,
-            cfg=self.cfg, top_k=self.top_k, top_p=self.top_p,
-            attn=self.attn_impl)
-        nxt = np.asarray(nxt)
+        return actives, drafts, (tok, pos, bt, temp, seeds, idx)
+
+    def _decode_emit(self, actives, drafts, nxt) -> None:
+        """Book the tick's tokens: counters, appends (which finish
+        requests), the windowed tick line."""
+        bs = self.block_size
         self.counters["ticks"] += 1
         self._last_touched = sum(
             blocks_for(r.written + 1
@@ -1050,9 +1089,7 @@ class ServingEngine:
                 self._append_token(r, tok_next)
                 emitted += 1
         self._win_tokens += emitted
-        with phase_tag("logging"):
-            self._maybe_log()
-        return True
+        self._maybe_log()
 
     # ------------------------------------------------- spec decoding
 
@@ -1135,7 +1172,7 @@ class ServingEngine:
         `req` itself). Returns whether `req` is still running."""
         while req.written // self.block_size >= len(req.table):
             try:
-                with phase_tag("block-alloc"):
+                with tracer().span("alloc"):
                     req.table.extend(self.alloc.alloc(1, rid=req.rid))
             except OutOfBlocks as e:
                 self._note_oom(e)

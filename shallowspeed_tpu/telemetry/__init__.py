@@ -5,10 +5,13 @@ The reference's observability is bare `print` (SURVEY §5,
 machine-comparable but only at end-of-window granularity. This package
 makes the *inside* of a step visible without xprof:
 
-- `trace`        low-overhead span API (`tracer().span("fwd", step=s)`)
-                 with host wall-clock and, at the `spans` level, device
-                 time via `block_until_ready` fences at phase
-                 boundaries; exports JSONL and Chrome-trace/Perfetto.
+- `trace`        the one span recorder (`tracer().span("fwd", step=s)`):
+                 a bounded in-memory ring at every level, `ss:<name>`
+                 annotations in a live `jax.profiler` trace, JAX's
+                 compiles as spans; host wall-clock and, at the `spans`
+                 level, device time via `block_until_ready` fences at
+                 phase boundaries; exports JSONL and
+                 Chrome-trace/Perfetto.
 - `bubble`       pipeline bubble accounting: executed schedule traces
                  (or a two-point step-time calibration for the fused
                  engines) replayed against `parallel/verify.py`'s
@@ -51,10 +54,12 @@ makes the *inside* of a step visible without xprof:
                  (pre-commit hook); `--live f.jsonl [--once]` renders
                  the live status view of a growing metrics file.
 
-Levels: `off` (no-ops — no fences, no buffers), `steps` (host
-timestamps only; the async dispatch pipeline is preserved), `spans`
-(device fences at span exits: accurate attributed time, serialized
-dispatch — the documented measurement mode).
+Levels: `off` (every span reaches the tracer's bounded ring, and the
+profiler's trace as `ss:<name>` while a session is live; no file, no
+fences), `steps` (spans also stream to spans.jsonl; host timestamps
+only, the async dispatch pipeline is preserved), `spans` (device
+fences at span exits: accurate attributed time, serialized dispatch —
+the documented measurement mode).
 """
 
 # trace has no jax/numpy imports at module level; the heavier modules
@@ -111,7 +116,7 @@ _LAZY = {
     # the single jax.profiler entry point, flamegraph reduction
     "SamplingProfiler": "profiler", "ProfilerPlane": "profiler",
     "CaptureWindow": "profiler", "device_trace_ctx": "profiler",
-    "profiler_tag": "profiler", "merge_profiles": "profiler",
+    "merge_profiles": "profiler",
     "flame_tree": "profiler", "profile_main": "profiler",
 }
 

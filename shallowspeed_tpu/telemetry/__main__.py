@@ -13,6 +13,7 @@ run's goodput ledger, or watch a run live.
         --out stitched.json
     python -m shallowspeed_tpu.telemetry --profile run/metrics.jsonl \
         --out flame.json
+    python -m shallowspeed_tpu.telemetry --gaps run/profile_dir
     python -m shallowspeed_tpu.telemetry --live run/metrics.jsonl
     python -m shallowspeed_tpu.telemetry --live f.jsonl --once
     python -m shallowspeed_tpu.telemetry --fleet http://127.0.0.1:9100 \
@@ -31,7 +32,11 @@ router log + N replica logs (schema v11 trace context) on one
 skew-corrected timeline and writes a Perfetto-loadable Chrome trace
 (--out) with per-replica tracks and a per-request journey track —
 queue-wait -> dispatch -> prefill -> decode -> failover gap ->
-re-prefill -> decode -> finish (telemetry/tracing.py). --live tails a
+re-prefill -> decode -> finish (telemetry/tracing.py). --gaps reduces
+a jax.profiler trace (a driver's --profile-dir, a capture window) to
+each device's busy and idle seconds and the idle seconds by the
+program span (`ss:<name>`) open at the time: why the chip waited
+(telemetry/profiler.py). --live tails a
 GROWING metrics JSONL and renders the same view the --monitor-port
 /status.json endpoint serves (streaming sketch quantiles, goodput so
 far, health, SLO burn rates with --slo) — live monitoring for runs
@@ -85,6 +90,10 @@ def main(argv=None) -> int:
                         "files/stanzas merge replica-prefixed) to a "
                         "flamegraph JSON (--out) + a printed "
                         "top-frames/phases summary")
+    g.add_argument("--gaps", metavar="TRACE",
+                   help="a jax.profiler trace directory or "
+                        ".xplane.pb: device busy/idle seconds and the "
+                        "idle seconds by innermost program span")
     g.add_argument("--live", metavar="JSONL",
                    help="tail a growing metrics JSONL and render the "
                         "live status view (the /status.json surface "
@@ -125,6 +134,11 @@ def main(argv=None) -> int:
         from shallowspeed_tpu.telemetry.profiler import profile_main
 
         return profile_main(args.profile, out=args.out)
+
+    if args.gaps:
+        from shallowspeed_tpu.telemetry.profiler import gaps_main
+
+        return gaps_main(args.gaps)
 
     if args.trace_stitch:
         from shallowspeed_tpu.telemetry.tracing import stitch_main

@@ -16,16 +16,17 @@ nothing says *where* it went. Four parts close that:
   story and events MERGE across replicas by summing counts. Reduce to
   a d3-flamegraph-shaped JSON with ``python -m shallowspeed_tpu
   .telemetry --profile <log> --out flame.json``.
-- **Span-tagged attribution** (`tag` + the tracer phase hook): every
-  sample is labelled with the innermost active phase — tracer spans
-  (step/grads/update) auto-push via `trace.PHASE_HOOKS`; the serving
-  engine brackets its scheduler phases (data-load, block-alloc,
-  prefill-chunk, sampling, decode-tick, logging, gateway) with
-  `tag(...)`, which costs one module-global check when no profiler
-  runs (the `_NULL_SPAN` pattern). `phases` decomposes the host blob
-  into named buckets; `step_samples` (stack contains a step/batch
-  span) is the sampler's own estimate of in-step time, cross-checked
-  against the waterfall's `attrib_host_frac` in tests.
+- **Span-tagged attribution** (the tracer phase hook): every sample
+  is labelled with the innermost open tracer span — `Tracer` spans
+  are real at every level and push their names through
+  `trace.PHASE_HOOKS` while a profiler runs: the train engines'
+  step/grads/update, the serving scheduler's admit, prefill,
+  decode.prep, decode.dispatch, decode.fetch, decode.emit, chaos,
+  gateway (one module-global check a span when no profiler runs).
+  `phases` decomposes the host blob into named buckets; `step_samples`
+  (stack contains a step/batch span) is the sampler's own estimate of
+  in-step time, cross-checked against the waterfall's
+  `attrib_host_frac` in tests.
 - **Trigger-driven capture windows** (`CaptureWindow`): a critical SLO
   burn, an anomaly verdict, a chaos fault, or a fleet straggler
   verdict arms ONE bounded high-rate window (~200 Hz for ~0.5 s) via
@@ -54,7 +55,9 @@ gap and the test suite asserts it stays bounded.
 from __future__ import annotations
 
 import contextlib
+import gzip
 import json
+import re
 import sys
 import threading
 import time
@@ -83,63 +86,7 @@ STEP_TAGS = ("step", "batch")
 # mislabelled sample, never a crash).
 
 _TAGS: dict[int, list] = {}
-_ACTIVE = 0     # number of running SamplingProfilers; tag() gates on it
-
-
-class _NullTag:
-    """Shared no-op: the `tag()` fast path when no profiler runs."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_TAG = _NullTag()
-
-
-class _Tag:
-    __slots__ = ("name", "_ident")
-
-    def __init__(self, name: str):
-        self.name = name
-        self._ident = None
-
-    def __enter__(self):
-        self._ident = threading.get_ident()
-        _TAGS.setdefault(self._ident, []).append(self.name)
-        return self
-
-    def __exit__(self, *exc):
-        stack = _TAGS.get(self._ident)
-        if stack:
-            if stack and stack[-1] == self.name:
-                stack.pop()
-            else:
-                # a profiler started/stopped mid-span can leave the
-                # stack misaligned once — recover instead of corrupting
-                try:
-                    stack.remove(self.name)
-                except ValueError:
-                    pass
-        return False
-
-
-def tag(name: str):
-    """Phase-tag context manager for host-attribution buckets. Returns
-    a shared no-op unless a profiler is running, so engine hot loops
-    may call it unconditionally."""
-    if not _ACTIVE:
-        return _NULL_TAG
-    return _Tag(name)
-
-
-# package-level re-export alias (`telemetry.profiler_tag`): `tag` is
-# too generic a name to surface at the package root unqualified
-profiler_tag = tag
+_ACTIVE = 0     # number of running SamplingProfilers (hook refcount)
 
 
 def _push_phase(name: str) -> None:
@@ -727,4 +674,110 @@ def profile_main(paths, out=None, top: int = 10, echo=print) -> int:
         tree["samples"] = samples
         Path(out).write_text(json.dumps(tree))
         echo(f"flamegraph JSON -> {out}")
+    return 0
+
+
+# ------------------------------------------------ device idle, by span
+#
+# The operator's reduction of a profiler trace (`--profile-dir`, a
+# capture window, a benchmark's traced run): how long each device was
+# busy and idle over the traced extent, and which of the program's
+# spans (`ss:<name>`, telemetry/trace.py) was open when it idled. The
+# spans and the device's operations sit in one trace on one clock.
+
+_DEVICE_PLANE = re.compile(r"/device:[A-Za-z]+:\d+")
+_HOST_PLANE = "/host:CPU"
+_OPS_LINE = "XLA Ops"
+
+
+def load_device_trace(path) -> dict:
+    """`{plane: {line: [(name, start_s, duration_s), ...]}}` from an
+    `.xplane.pb`, from the newest one under a trace directory, or from
+    a `.json` / `.json.gz` that already holds that shape (a trace cut
+    down to be kept with the tests)."""
+    path = Path(path)
+    if path.is_dir():
+        found = sorted(path.glob("**/*.xplane.pb"))
+        if not found:
+            raise FileNotFoundError(f"no *.xplane.pb under {path}")
+        path = found[-1]
+    if path.suffix == ".pb":
+        import jax
+
+        out: dict = {}
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+            lines = out.setdefault(plane.name, {})
+            for line in plane.lines:
+                lines.setdefault(line.name, []).extend(
+                    (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+                    for e in line.events)
+        return out
+    opener = gzip.open if path.suffix == ".gz" else open
+    with opener(path, "rt") as f:
+        return json.load(f)
+
+
+def device_gaps(trace: dict) -> list[dict]:
+    """Per device plane: seconds traced, busy (the union of its
+    operations' intervals) and idle, and the idle seconds by the
+    innermost program span open at the middle of each gap (`none`
+    where no span was). The traced extent is that of all devices'
+    operations."""
+    from shallowspeed_tpu.telemetry.trace import ANNOTATION_PREFIX
+
+    planes = sorted((p for p in trace if _DEVICE_PLANE.fullmatch(p)),
+                    key=lambda p: int(p.rsplit(":", 1)[1]))
+    ops = {p: sorted((s, s + d) for _, s, d
+                     in trace[p].get(_OPS_LINE, ())) for p in planes}
+    if not any(ops.values()):
+        return []
+    t0 = min(o[0][0] for o in ops.values() if o)
+    t1 = max(max(b for _, b in o) for o in ops.values() if o)
+    spans = sorted(
+        (s, -d, name[len(ANNOTATION_PREFIX):])
+        for line in trace.get(_HOST_PLANE, {}).values()
+        for name, s, d in line if name.startswith(ANNOTATION_PREFIX))
+    out = []
+    for p in planes:
+        gaps, at = [], t0
+        for a, b in ops[p]:
+            if a > at:
+                gaps.append((at, a))
+            at = max(at, b)
+        if at < t1:
+            gaps.append((at, t1))
+        idle: Counter = Counter()
+        open_spans: list = []       # (end, name), innermost last
+        k = 0
+        for a, b in gaps:           # in time order, like the spans
+            mid = (a + b) / 2
+            while k < len(spans) and spans[k][0] <= mid:
+                s, neg_d, name = spans[k]
+                open_spans.append((s - neg_d, name))
+                k += 1
+            while open_spans and open_spans[-1][0] <= mid:
+                open_spans.pop()
+            idle[open_spans[-1][1] if open_spans else "none"] += b - a
+        idle_s = sum(idle.values())
+        out.append({"device": p, "traced_s": t1 - t0,
+                    "busy_s": t1 - t0 - idle_s, "idle_s": idle_s,
+                    "idle_by_span": dict(idle.most_common())})
+    return out
+
+
+def gaps_main(path, echo=print) -> int:
+    """``python -m shallowspeed_tpu.telemetry --gaps <trace dir or
+    .xplane.pb>``: why the chip waited. Exit 1 when the trace holds no
+    device operations (a CPU trace has no device plane)."""
+    devices = device_gaps(load_device_trace(path))
+    if not devices:
+        echo(f"--gaps: no device operations in {path}")
+        return 1
+    for d in devices:
+        echo(f"{d['device']}: traced {d['traced_s']:.3f} s, busy "
+             f"{d['busy_s']:.3f} s, idle {d['idle_s']:.3f} s "
+             f"({d['idle_s'] / d['traced_s']:.2%})")
+        for name, s in d["idle_by_span"].items():
+            echo(f"  idle in {name:<18} {s:9.4f} s  "
+                 f"{s / max(d['idle_s'], 1e-12):6.1%}")
     return 0

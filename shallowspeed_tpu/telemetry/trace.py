@@ -1,32 +1,61 @@
-"""The span tracer — low-overhead runtime tracing for the engines.
+"""The span recorder — the program's one tracing mechanism.
 
-Design constraints, in order:
+Every `tracer().span(name, **attrs)` is a real span at every level.
+What a level adds:
 
-1. `off` must cost nothing: engines call `tracer().span(...)` on every
-   step (the VM on every instruction), so the disabled path is one
-   module-global read returning a shared no-op span. No buffers, no
-   timestamps, and — critically — **no device fences**: the async
-   dispatch pipeline the engines are built around is untouched
-   (pinned by tests/test_telemetry.py's no-fence test).
-2. `steps` records host wall-clock only. Spans are real (buffered,
-   exported) but `Span.fence()` is a no-op, so queued device work is
-   never drained — timestamps measure *dispatch*, and only log-point
-   spans (which the drivers already synchronize) measure compute.
+1. `off` (the default) records each closed span into a bounded
+   in-memory **ring** — one tuple `(seq, parent_seq, name, t0, t1,
+   attrs, depth)` on `time.perf_counter` — and, while a profiler
+   session is live, enters a `jax.profiler.TraceAnnotation
+   ("ss:<name>")`, which puts the span into the profiler's own
+   `/host:CPU` plane beside the device's `XLA Ops`, on one clock (with
+   no session it asks `TraceMe.is_enabled()`, 0.04 us, and makes no
+   annotation). Nothing else: no dict, no file, no subscriber and —
+   critically — **no device fence**: the async dispatch pipeline the
+   engines are built around is untouched (pinned by
+   tests/test_telemetry.py's no-fence test). About 2 us a span in a
+   tight loop on this sandbox's CPU and 5-8 us between real work (cold
+   caches); engines open a dozen a step (the VM one per instruction).
+2. `steps` also streams every span as a dict to `spans.jsonl` and to
+   the subscribers. `Span.fence()` is still a no-op, so queued device
+   work is never drained — timestamps measure *dispatch*, and only
+   log-point spans (which the drivers already synchronize) measure
+   compute.
 3. `spans` adds a `jax.block_until_ready` on the arrays handed to
    `Span.fence()` at span exit, so a span's duration brackets the
    DEVICE time of the work dispatched inside it. This serializes
    dispatch at every phase boundary — the honest cost of attributable
    time; the README documents it as the measurement mode.
 
+`seq` numbers spans in the order they OPEN, `parent_seq` is the
+innermost span open on the same thread at that moment (None at the
+top), and the ring is ordered by CLOSE, so a parent follows its
+children. `depth` is the nesting depth, the span's Chrome track.
+
+JAX's compiles land in the same ring: `watch_compiles()` registers
+`jax.monitoring` listeners once a process has JAX, and every backend
+compile becomes a `compile` span under whatever span was open (which
+step recompiled), every program the persistent cache did not hold a
+zero-length `cache_miss` entry beside it.
+
 Export: one `spans.jsonl` line per closed span (append-streamed, so a
 killed run keeps its trace) and a Chrome-trace `trace.json`
 (`ph: "X"` complete events, microsecond timebase) written by `close()`
 — loadable in Perfetto / chrome://tracing with zero TPU tooling.
+Instant events, counter samples, track names and the request
+lifecycle's `complete()` phases stream to the file and the subscribers
+only; the ring holds the spans that code opened, and JAX's compiles.
+
+This module imports nothing but the standard library: a process that
+never imports JAX (the `--validate` pre-commit hook, a launch-only
+router) annotates nothing and watches no compiles.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
+import sys
 import threading
 import time
 from collections import deque
@@ -34,43 +63,56 @@ from pathlib import Path
 
 LEVELS = ("off", "steps", "spans")
 
-# in-memory event buffer cap: the VM emits one span per pipeline
-# instruction, so a long spans-level run would otherwise grow the
-# buffer without bound — spans.jsonl streams EVERY event to disk and
-# is the source of truth for trace.json; the buffer only serves
-# same-process consumers (the bubble replay reads the last batch via
-# `events_since`, far below this cap)
-_BUF_CAP = 200_000
+# ring bound: the VM emits one span per pipeline instruction, so a long
+# run would otherwise grow without limit. The longest same-process
+# reader is the benchmark's: set-up's compiles and every span of a 10 s
+# ramp and a 30 s window, 10.5 spans a serving step, which is 9,300
+# spans at today's 45 ms step and 105,000 at the 4 ms the roofline
+# allows. A full ring holds 32-52 MB (244 B a span, 396 B with an
+# attribute). spans.jsonl streams EVERY event and is the source of
+# truth for trace.json.
+RING_CAP = 131_072
 
+# prefix of the program's spans in a profiler trace (the benchmark's
+# own are `bench:<name>`)
+ANNOTATION_PREFIX = "ss:"
 
-class _NullSpan:
-    """Shared do-nothing span: the `off` fast path and the object
-    returned for spans opened while tracing is disabled."""
+# spans that mark one step of the program's outer loop become
+# `StepTraceAnnotation`s, numbered by this attribute
+_STEP_ATTR = {"engine.step": "tick", "step": "step"}
 
-    __slots__ = ()
+# named tracks (`Tracer.track`) take Chrome tids from here up; span
+# nesting depths stay single digits, so the two can never collide
+_FIRST_TRACK_TID = 1000
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def fence(self, *arrays):
-        return None
-
-    def set(self, **attrs):
-        return None
-
-
-_NULL_SPAN = _NullSpan()
-
-# phase hooks (round 17, telemetry/profiler.py): when a sampling
-# profiler runs it installs a (push, pop) pair here and every span
-# enter/exit feeds its NAME into the profiler's cross-thread phase
-# registry — so samples landing inside a `step` span are attributable
-# without the drivers changing. None when no profiler runs: the cost
-# on the hot path is one module-global read per span.
+# phase hooks (telemetry/profiler.py): while a sampling profiler runs
+# it installs a (push, pop) pair here and every span enter/exit feeds
+# its NAME into the profiler's cross-thread phase registry — so samples
+# landing inside a `step` or `decode.prep` span are attributable
+# without the engines knowing. None when no profiler runs: the cost on
+# the hot path is one module-global read per span.
 PHASE_HOOKS = None
+
+# (TraceAnnotation, StepTraceAnnotation) once this process has JAX
+_NOTES = None
+_WATCHING = False
+
+
+def _resolve_notes():
+    """The profiler's annotation classes, once JAX is in the process
+    (never imported from here: see the module docstring). The first
+    resolution also starts the compile watch."""
+    global _NOTES
+    if "jax" not in sys.modules:
+        return None
+    try:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    except ImportError:     # JAX is mid-import on another thread
+        return None
+    _NOTES = (TraceAnnotation, StepTraceAnnotation,
+              TraceAnnotation.is_enabled)
+    watch_compiles()
+    return _NOTES
 
 
 class Span:
@@ -78,45 +120,70 @@ class Span:
     marks arrays whose device completion the exit waits on (at the
     `spans` level only)."""
 
-    __slots__ = ("_tr", "name", "attrs", "_t0", "_fences")
+    __slots__ = ("_tr", "name", "attrs", "seq", "_parent", "_stack",
+                 "_t0", "_fences", "_note")
 
     def __init__(self, tr: "Tracer", name: str, attrs: dict):
         self._tr = tr
         self.name = name
         self.attrs = attrs
-        self._t0 = 0.0
         self._fences: tuple = ()
+        self._note = None
 
     def fence(self, *arrays) -> None:
         """Block the span exit on these arrays' device completion
-        (`spans` level; no-op at `steps`). Call with the step's outputs
-        so the span measures compute, not dispatch."""
+        (`spans` level; no-op at `off` and `steps`). Call with the
+        step's outputs so the span measures compute, not dispatch."""
         if self._tr.level == "spans":
             self._fences += arrays
 
     def set(self, **attrs) -> None:
+        """Attributes learned inside the span (`n_admitted`, ...)."""
         self.attrs.update(attrs)
+        if self._note is not None:
+            self._note.set_metadata(**attrs)
 
     def __enter__(self):
-        self._t0 = self._tr._clock()
-        self._tr._thread_stack().append(self)
+        tr = self._tr
+        stack = self._stack = tr._thread_stack()
+        self._parent = stack[-1].seq if stack else None
+        self.seq = next(tr._ids)
+        stack.append(self)
         if PHASE_HOOKS is not None:
             PHASE_HOOKS[0](self.name)
+        notes = _NOTES or _resolve_notes()
+        # a session is live: an annotation made now is recorded; one
+        # made while none is live would be dropped by the profiler
+        # itself, at four times the price of asking
+        if notes is not None and notes[2]():
+            name, attrs = self.name, self.attrs
+            key = _STEP_ATTR.get(name)
+            if key is not None and key in attrs:
+                note = notes[1](ANNOTATION_PREFIX + name,
+                                step_num=attrs[key], **attrs)
+            else:
+                note = notes[0](ANNOTATION_PREFIX + name, **attrs)
+            note.__enter__()
+            self._note = note
+        self._t0 = tr._clock()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
-        if PHASE_HOOKS is not None:
-            PHASE_HOOKS[1](self.name)
-        if tr.level == "spans" and self._fences:
+        if self._fences and tr.level == "spans":
             _block(self._fences)
         t1 = tr._clock()
-        stack = tr._thread_stack()
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+        if PHASE_HOOKS is not None:
+            PHASE_HOOKS[1](self.name)
+        stack = self._stack
         assert stack and stack[-1] is self, (
             "span nesting violated: exiting a span that is not the "
             "innermost open span")
         stack.pop()
-        tr._record(self, self._t0, t1, depth=len(stack))
+        tr._close((self.seq, self._parent, self.name, self._t0, t1,
+                   self.attrs, len(stack)))
         return False
 
 
@@ -128,11 +195,12 @@ def _block(arrays):
 
 
 class Tracer:
-    """Buffering span recorder with streaming JSONL + Chrome export.
+    """Span recorder: bounded ring, streaming JSONL + Chrome export.
 
-    Single-threaded by design (the engines dispatch from one Python
-    thread); the lock only guards the JSONL append so background
-    threads (prefetch, async save) may also emit spans.
+    The engines dispatch from one Python thread; background threads
+    (prefetch, async save, whoever compiles) may emit spans too, each
+    with its own nesting stack. The lock guards the ring's counter, the
+    JSONL append and the subscribers.
     """
 
     def __init__(self, trace_dir=None, level: str = "off",
@@ -143,19 +211,18 @@ class Tracer:
         self._clock = clock
         self._epoch = clock()
         self._local = threading.local()  # per-thread span stacks
-        self._events: deque = deque(maxlen=_BUF_CAP)
-        self._seq = 0                    # total events ever emitted
-        self._counters: dict[str, float] = {}
-        # named tracks (round 13): explicit Chrome tids above the span
-        # nesting depths, one per serving request — depth-tids stay
-        # single digits, so the offset can never collide
-        self._next_tid = 1000
+        self._ring: deque = deque(maxlen=RING_CAP)
+        self._ids = itertools.count()    # span ids, in order of opening
+        self._n_closed = 0               # spans ever put into the ring
+        # named tracks (round 13): one per serving request
+        self._next_tid = _FIRST_TRACK_TID
         self._lock = threading.Lock()
         self._jsonl = None
         # span-event subscribers (round 12): the live monitor's
         # flight recorder rides here so the incident ring holds the
         # phase spans next to the metrics lines. Called under the
-        # emit lock — keep them O(ring append) cheap.
+        # emit lock — keep them O(ring append) cheap. Fed at `steps`
+        # and `spans` only.
         self.subscribers: list = []
         if self.dir is not None and level != "off":
             self.dir.mkdir(parents=True, exist_ok=True)
@@ -173,11 +240,9 @@ class Tracer:
 
     # ------------------------------------------------------------ spans
 
-    def span(self, name: str, **attrs):
-        """Open a span; use as a context manager. At `off` this returns
-        a shared no-op object (zero allocation beyond the call)."""
-        if self.level == "off":
-            return _NULL_SPAN
+    def span(self, name: str, **attrs) -> Span:
+        """Open a span; use as a context manager. Real at every level
+        (see the module docstring for what `off` does with it)."""
         return Span(self, name, attrs)
 
     def event(self, name: str, **attrs) -> None:
@@ -211,17 +276,18 @@ class Tracer:
 
     def complete(self, name: str, t0: float, t1: float,
                  tid: int | None = None, **attrs) -> None:
-        """Emit an already-closed span (ph "X") directly — the
-        lifecycle path, where phase boundaries are recorded host-side
-        as they happen and exported when the phase ENDS. `t0`/`t1` are
-        on this tracer's clock (`now()`); `tid` targets a named track
-        from `track()`."""
+        """Record an already-closed span directly — the lifecycle
+        path, where phase boundaries are recorded host-side as they
+        happen and exported when the phase ENDS. `t0`/`t1` are on this
+        tracer's clock (`now()`); `tid` targets a named track from
+        `track()`. To the file and the subscribers only (a request's
+        `prefill` phase is no scheduler `prefill` span, and the ring's
+        readers look spans up by name); nothing at `off`."""
         if self.level == "off":
             return
         ev = {"name": name, "ph": "X",
               "ts": round((t0 - self._epoch) * 1e6, 1),
-              "dur": round(max(0.0, t1 - t0) * 1e6, 1),
-              "args": attrs}
+              "dur": round(max(0.0, t1 - t0) * 1e6, 1), "args": attrs}
         if tid is not None:
             ev["tid"] = tid
         self._emit(ev)
@@ -230,24 +296,26 @@ class Tracer:
         """Monotonic/telemetry counter sample (recompiles, HBM bytes)."""
         if self.level == "off":
             return
-        self._counters[name] = value
         self._emit({"name": name, "ph": "C",
                     "ts": round((self._clock() - self._epoch) * 1e6, 1),
                     "args": {"value": value}})
 
-    def _record(self, span: Span, t0: float, t1: float, depth: int):
-        self._emit({
-            "name": span.name, "ph": "X",
-            "ts": round((t0 - self._epoch) * 1e6, 1),
-            "dur": round((t1 - t0) * 1e6, 1),
-            "depth": depth,
-            "args": span.attrs,
-        })
+    def _close(self, entry: tuple) -> None:
+        with self._lock:
+            self._ring.append(entry)
+            self._n_closed += 1
+        if self.level != "off":
+            self._emit(self._as_dict(entry))
+
+    def _as_dict(self, entry: tuple) -> dict:
+        _seq, _parent, name, t0, t1, attrs, depth = entry
+        return {"name": name, "ph": "X",
+                "ts": round((t0 - self._epoch) * 1e6, 1),
+                "dur": round((t1 - t0) * 1e6, 1),
+                "depth": depth, "args": attrs}
 
     def _emit(self, ev: dict) -> None:
         with self._lock:
-            self._events.append(ev)
-            self._seq += 1
             if self._jsonl is not None:
                 self._jsonl.write(json.dumps(ev) + "\n")
                 self._jsonl.flush()
@@ -257,36 +325,41 @@ class Tracer:
                 except Exception:
                     pass  # a monitor bug must not kill the traced run
 
-    # ----------------------------------------------------------- export
+    # ------------------------------------------------------------- ring
+
+    def ring(self) -> list[tuple]:
+        """The buffered spans, oldest close first, as the tuples the
+        module docstring describes (the most recent `RING_CAP`; the
+        ring has dropped its oldest once `event_count` exceeds its
+        length)."""
+        with self._lock:
+            return list(self._ring)
 
     @property
     def event_count(self) -> int:
-        """Total events emitted so far (monotonic; survives buffer
-        eviction — pair with `events_since` to read a window)."""
-        return self._seq
+        """Spans closed so far (monotonic; survives ring eviction —
+        pair with `events_since` to read a window)."""
+        return self._n_closed
 
     @property
     def events(self) -> list[dict]:
-        """The buffered events (the most recent `_BUF_CAP`; the full
-        stream lives in spans.jsonl). Snapshotted under the lock —
-        background threads (prefetch, async save) may emit
-        concurrently, and iterating a mutating deque raises."""
-        with self._lock:
-            return list(self._events)
+        """The buffered spans as dicts in the export's shape (the full
+        stream, instants and counters included, lives in spans.jsonl)."""
+        return [self._as_dict(e) for e in self.ring()]
 
     def events_since(self, seq: int) -> list[dict]:
-        """Events emitted at or after sequence number `seq` (from
-        `event_count`) that are still buffered — the O(window) way to
-        read e.g. one batch's spans without rescanning the run."""
+        """Spans closed at or after count `seq` (from `event_count`)
+        that are still buffered — the O(window) way to read e.g. one
+        batch's spans without rescanning the run."""
         with self._lock:
-            buf = list(self._events)
-            n_evicted = self._seq - len(buf)
-        skip = max(0, seq - n_evicted)
-        return buf[skip:] if skip else buf
+            n = max(0, self._n_closed - seq)
+            tail = list(itertools.islice(reversed(self._ring), n))
+        return [self._as_dict(e) for e in reversed(tail)]
 
     def spans_named(self, name: str) -> list[dict]:
-        return [e for e in self.events
-                if e.get("ph") == "X" and e["name"] == name]
+        return [self._as_dict(e) for e in self.ring() if e[2] == name]
+
+    # ----------------------------------------------------------- export
 
     @staticmethod
     def _chrome_event(e: dict) -> dict:
@@ -302,9 +375,9 @@ class Tracer:
     def chrome_trace(self) -> dict:
         """The trace in Chrome format (Perfetto-loadable). Sourced from
         the streamed spans.jsonl when a trace dir is configured (the
-        COMPLETE stream — the RAM buffer is bounded), else from the
-        buffer. Span depth maps to tid so nesting renders as the usual
-        flame layout; attrs ride in `args`."""
+        COMPLETE stream — the ring is bounded and holds spans only),
+        else from the ring. Span depth maps to tid so nesting renders
+        as the usual flame layout; attrs ride in `args`."""
         src: list = self.events
         if self.dir is not None:
             path = self.dir / "spans.jsonl"
@@ -349,3 +422,47 @@ def configure(trace_dir=None, level: str = "off") -> Tracer:
 def tracer() -> Tracer:
     """The active process-global tracer (default: level 'off')."""
     return _TRACER
+
+
+# ------------------------------------------------------- compile watch
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+def _record(name: str, t0: float, t1: float, attrs: dict) -> None:
+    """A span that JAX reported after the fact, into the global
+    tracer's ring under the calling thread's innermost open span."""
+    tr = _TRACER
+    stack = tr._thread_stack()
+    tr._close((next(tr._ids), stack[-1].seq if stack else None, name,
+               t0, t1, attrs, len(stack)))
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        t1 = _TRACER._clock()
+        _record("compile", t1 - seconds, t1,
+                {"fun": kw.get("fun_name", "")})
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_MISS_EVENT:
+        t = _TRACER._clock()
+        _record("cache_miss", t, t, {})
+
+
+def watch_compiles() -> None:
+    """Register the `jax.monitoring` listeners, once a process (JAX
+    offers no way to take one back, so they serve whichever tracer is
+    global at the time). Called by `runtime.enable_compile_cache()`,
+    before a driver's first compile, and by the first span opened after
+    JAX was imported."""
+    global _WATCHING
+    if _WATCHING:
+        return
+    _WATCHING = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)

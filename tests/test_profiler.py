@@ -6,7 +6,7 @@ Acceptance pins:
   fault under a tight --slo on a real serve.py subprocess produces
   EXACTLY ONE profcap_*.json (the cooldown folds the fault and the
   SLO burn it causes into one window) whose dominant tagged phase
-  names the stalled scheduler phase (`data-load`)
+  names the stalled scheduler phase (the engine's `chaos` span)
   (`test_serving_stall_drill_arms_one_capture`);
 - sampler safety: a profiled serving run compiles ZERO new jit
   executables vs the unprofiled warmup (`executable_counts()`
@@ -40,8 +40,9 @@ from shallowspeed_tpu.telemetry.profiler import (CaptureWindow,
                                                  device_trace_ctx,
                                                  flame_tree,
                                                  merge_profiles,
-                                                 profile_main, tag)
+                                                 profile_main)
 from shallowspeed_tpu.telemetry.schema import validate_file
+from shallowspeed_tpu.telemetry.trace import Tracer
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -49,20 +50,28 @@ ROOT = Path(__file__).resolve().parents[1]
 # ------------------------------------------------------------- tagging
 
 
-def test_tag_is_shared_noop_when_no_profiler_runs():
-    t = tag("data-load")
-    assert t is profiler._NULL_TAG
-    with t:
+def test_spans_feed_no_phase_when_no_profiler_runs():
+    """With no profiler running a span touches the phase registry not
+    at all (one module-global read), at any level and any depth."""
+    from shallowspeed_tpu.telemetry import trace
+
+    assert trace.PHASE_HOOKS is None
+    tr = Tracer(level="off")
+    with tr.span("chaos"):
         assert not profiler._TAGS
-    # and the engine may nest them unconditionally at zero cost
-    assert tag("decode-tick") is t
+        # and the engine may nest them unconditionally
+        with tr.span("decode.prep"):
+            assert not profiler._TAGS
+    assert [e[2] for e in tr.ring()] == ["decode.prep", "chaos"]
 
 
 def test_sample_once_labels_innermost_phase_and_step_membership():
     """Deterministic, clock-free: hooks installed by hand, one
     helper-thread sample per state (the sampler skips its own thread,
-    so the main thread must be the samplee)."""
+    so the main thread must be the samplee). The spans are the `off`
+    tracer's: phase attribution needs no telemetry level."""
     prof = SamplingProfiler()   # never started: no background samples
+    tr = Tracer(level="off")
     profiler._install_hooks()
     try:
         def one():
@@ -70,32 +79,34 @@ def test_sample_once_labels_innermost_phase_and_step_membership():
             th.start()
             th.join()
 
-        with tag("step"):
-            with tag("sampling"):
+        with tr.span("step"):
+            with tr.span("prefill.sample"):
                 one()           # innermost wins; step anywhere counts
-        with tag("decode-tick"):
+        with tr.span("decode.fetch"):
             one()
         one()                   # untagged
     finally:
         profiler._uninstall_hooks()
     assert prof.samples == 3
-    assert prof.phases == {"sampling": 1, "decode-tick": 1,
+    assert prof.phases == {"prefill.sample": 1, "decode.fetch": 1,
                            profiler.UNTAGGED: 1}
     assert prof.step_samples == 1
     # folded stacks are root->leaf module:function strings
     assert all(";" in k and ":" in k for k in prof.folded)
-    # stop() after start(); tag() reverts to the no-op and the
-    # cross-thread registry is cleared
+    # stop() after start(): spans feed the registry only in between,
+    # and the cross-thread registry is cleared
+    ident = threading.get_ident()
     prof2 = SamplingProfiler(hz=200).start()
-    assert tag("x") is not profiler._NULL_TAG
+    with tr.span("x"):
+        assert profiler._TAGS[ident] == ["x"]
     prof2.stop()
-    assert tag("x") is profiler._NULL_TAG and profiler._TAGS == {}
+    with tr.span("x"):
+        assert profiler._TAGS == {}
 
 
-def test_tracer_spans_feed_phase_registry_while_profiler_runs():
-    from shallowspeed_tpu.telemetry.trace import Tracer
-
-    tr = Tracer(level="steps")
+@pytest.mark.parametrize("level", ["off", "steps"])
+def test_tracer_spans_feed_phase_registry_while_profiler_runs(level):
+    tr = Tracer(level=level)
     prof = SamplingProfiler()
     profiler._install_hooks()
     try:
@@ -181,9 +192,9 @@ def test_capture_window_dedup_cooldown_cap_and_dominant_phase(tmp_path):
     cw = CaptureWindow(out_dir=tmp_path, duration_s=0.1, hz=400,
                        max_captures=3, cooldown_s=30.0,
                        clock=lambda: t[0])
-    profiler._install_hooks()   # so tag() is live for the capture
+    profiler._install_hooks()   # so spans name phases for the capture
     try:
-        with tag("data-load"):
+        with Tracer(level="off").span("chaos"):
             assert cw.arm("fault:stall", step=6, trigger={"kind": "stall"})
             time.sleep(0.12)    # the window samples the main thread here
         assert not cw.arm("fault:stall", step=6)    # (reason, step) dedup
@@ -202,7 +213,7 @@ def test_capture_window_dedup_cooldown_cap_and_dominant_phase(tmp_path):
     pay = json.loads((tmp_path / "profcap_6.json").read_text())
     assert pay["reason"] == "fault:stall" and pay["step"] == 6
     assert pay["samples"] > 0
-    assert pay["dominant_phase"] == "data-load"
+    assert pay["dominant_phase"] == "chaos"
     assert pay["trigger"] == {"kind": "stall"}
     assert sum(pay["phases"].values()) == pay["samples"]
 
@@ -357,8 +368,6 @@ def test_host_frac_cross_check_against_step_spans():
     0.10 absolute (the documented cross-check bound)."""
     from shallowspeed_tpu.telemetry import attribution as attr
     from shallowspeed_tpu.telemetry.report import percentile
-    from shallowspeed_tpu.telemetry.trace import Tracer
-
     tr = Tracer(level="steps")
     prof = SamplingProfiler(hz=250).start()
     try:
@@ -480,9 +489,9 @@ def test_serving_stall_drill_arms_one_capture(tmp_path):
     """ISSUE-17 acceptance: a seeded `stall` chaos fault under a
     deliberately-impossible tpot SLO arms EXACTLY ONE capture window
     — the fault fires first, the SLO burn it causes lands inside the
-    cooldown — and the profcap names the stalled phase (`data-load`:
-    chaos stamps observers before the stall sleep, inside the
-    engine's data-load bracket)."""
+    cooldown — and the profcap names the stalled phase (`chaos`: the
+    plan stamps observers before the stall sleep, inside the engine's
+    `chaos` span)."""
     reqs = tmp_path / "reqs.jsonl"
     with open(reqs, "w") as f:
         for i in range(4):
@@ -508,7 +517,7 @@ def test_serving_stall_drill_arms_one_capture(tmp_path):
     pay = json.loads(caps[0].read_text())
     assert pay["reason"] == "fault:stall" and pay["step"] == 6
     assert pay["samples"] > 0
-    assert pay["dominant_phase"] == "data-load", pay["phases"]
+    assert pay["dominant_phase"] == "chaos", pay["phases"]
 
     # the metrics log validates schema v12 and its cumulative profile
     # events are monotone in sample count

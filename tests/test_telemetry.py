@@ -8,8 +8,12 @@ What must hold:
   F:B-ratio-invariant for gpipe, and the static fractions agree with
   the simulators per schedule;
 - HBM live-vs-static cross-check within tolerance on a real engine;
-- `--telemetry off` inserts NO fences and buffers nothing — the
+- `--telemetry off` inserts NO fences, writes nothing and compiles
+  nothing: a span reaches the bounded ring (order, parent, bound) and
+  the profiler's trace as `ss:<name>` while a session is live — the
   engines' async dispatch pipeline is untouched;
+- JAX's compiles become `compile` spans under the span that was open;
+- `--gaps` names the span a device idled in;
 - the recompile counter: every VM stage executable compiles exactly
   once across batches (pins the zero_grad sharding fix this counter
   caught);
@@ -26,7 +30,7 @@ import pytest
 from shallowspeed_tpu.telemetry import bubble, schema
 from shallowspeed_tpu.telemetry import trace as trace_mod
 from shallowspeed_tpu.telemetry.report import RunTelemetry, compile_counts
-from shallowspeed_tpu.telemetry.trace import Tracer, _NULL_SPAN
+from shallowspeed_tpu.telemetry.trace import Tracer
 
 
 # ------------------------------------------------------------- spans
@@ -77,27 +81,76 @@ def test_schema_rejects_malformed_lines():
          "tokens_per_sec": 10.0, "recompiles": 0.5}) != []
 
 
-def test_off_level_is_nullop_and_fenceless(monkeypatch):
-    """`--telemetry off` must insert NO fences and buffer nothing: the
-    span is the shared null object and block_until_ready is never
-    reached (the engines' async dispatch stays async)."""
+def test_off_level_reaches_the_ring_and_nothing_else(monkeypatch,
+                                                     tmp_path):
+    """`--telemetry off`: a span is recorded in the ring, and that is
+    all — no instant event or counter sample, no subscriber call, no
+    file, and block_until_ready is never reached (the engines' async
+    dispatch stays async)."""
     def boom(*_a, **_k):  # any fence attempt explodes
         raise AssertionError("off-level telemetry fenced device work")
 
     monkeypatch.setattr(trace_mod, "_block", boom)
-    tr = Tracer(level="off")
-    sp = tr.span("step", step=0)
-    assert sp is _NULL_SPAN
-    with sp:
+    tr = Tracer(trace_dir=tmp_path / "t", level="off")
+    seen = []
+    tr.subscribers.append(seen.append)
+    with tr.span("step", step=0) as sp:
         sp.fence(object())
     tr.event("x")
     tr.counter("c", 1)
-    assert tr.events == []
+    tr.complete("late", 0.0, 1.0)
+    assert tr.track("request r") == 0
+    ring = tr.ring()
+    assert [(e[1], e[2], e[5]) for e in ring] == [(None, "step",
+                                                  {"step": 0})]
+    assert ring[0][3] <= ring[0][4]
+    assert seen == []
+    tr.close()
+    assert not (tmp_path / "t").exists()
     # and at `steps` level fences are still skipped (dispatch preserved)
     tr2 = Tracer(level="steps")
     with tr2.span("step") as s:
         s.fence(object())
     assert len(tr2.events) == 1
+
+
+def test_ring_order_parent_and_bound(monkeypatch):
+    """The ring is ordered by close, `seq` by open, `parent_seq` is
+    the innermost span open on the thread, and the ring keeps the last
+    `RING_CAP` spans whatever the level."""
+    monkeypatch.setattr(trace_mod, "RING_CAP", 4)
+    tr = Tracer(level="off")
+    with tr.span("engine.step", tick=7):
+        with tr.span("decode"):
+            with tr.span("decode.fetch"):
+                pass
+        with tr.span("admit"):
+            pass
+    by_name = {e[2]: e for e in tr.ring()}
+    assert [e[2] for e in tr.ring()] == ["decode.fetch", "decode",
+                                         "admit", "engine.step"]
+    assert [by_name[n][0] for n in ("engine.step", "decode",
+                                    "decode.fetch", "admit")] == [0, 1, 2, 3]
+    assert by_name["engine.step"][1] is None
+    assert by_name["decode"][1] == by_name["engine.step"][0]
+    assert by_name["decode.fetch"][1] == by_name["decode"][0]
+    assert by_name["admit"][1] == by_name["engine.step"][0]
+    assert [by_name[n][6] for n in ("engine.step", "decode",
+                                    "decode.fetch")] == [0, 1, 2]
+    for i in range(6):
+        with tr.span("s", i=i):
+            pass
+    assert [e[5]["i"] for e in tr.ring()] == [2, 3, 4, 5]
+    assert tr.event_count == 10
+    # a thread's spans nest under that thread's spans only
+    import threading
+
+    with tr.span("main"):
+        th = threading.Thread(
+            target=lambda: tr.span("worker").__enter__().__exit__())
+        th.start()
+        th.join()
+    assert {e[2]: e[1] for e in tr.ring()}["worker"] is None
 
 
 def test_spans_level_fences_on_exit(monkeypatch):
@@ -378,29 +431,37 @@ def test_replay_rejects_mixed_window_and_pads_partial_capture():
 
 
 def test_tracer_event_windows_survive_buffer_eviction(monkeypatch):
-    monkeypatch.setattr(trace_mod, "_BUF_CAP", 4)
+    monkeypatch.setattr(trace_mod, "RING_CAP", 4)
     tr = Tracer(level="steps")
-    tr._events = __import__("collections").deque(maxlen=4)
     for i in range(10):
-        tr.event("e", i=i)
+        with tr.span("e", i=i):
+            pass
     assert tr.event_count == 10
     # a window starting inside the buffer returns exactly that suffix
     assert [e["args"]["i"] for e in tr.events_since(8)] == [8, 9]
     # a window starting before the eviction point returns what remains
     assert [e["args"]["i"] for e in tr.events_since(2)] == [6, 7, 8, 9]
+    assert tr.events_since(10) == []
 
 
 def test_chrome_trace_sources_full_stream_from_jsonl(tmp_path,
-                                                     monkeypatch):
-    """trace.json must carry the COMPLETE stream even when the RAM
-    buffer evicted early events (spans.jsonl is the source of truth)."""
+                                                    monkeypatch):
+    """trace.json must carry the COMPLETE stream even when the ring
+    evicted early spans, and the instants and lifecycle phases the
+    ring never holds (spans.jsonl is the source of truth): a request's
+    `e` phase is no `e` span."""
+    monkeypatch.setattr(trace_mod, "RING_CAP", 2)
     tr = Tracer(trace_dir=tmp_path, level="steps")
-    tr._events = __import__("collections").deque(maxlen=2)
-    for i in range(6):
-        tr.event("e", i=i)
+    for i in range(5):
+        with tr.span("e", i=i):
+            pass
+    tr.event("marker")
+    tr.complete("e", tr.now(), tr.now(), tid=tr.track("request r"), i=9)
     tr.close()
+    assert [e[5]["i"] for e in tr.ring()] == [3, 4]
+    assert [e["args"]["i"] for e in tr.spans_named("e")] == [3, 4]
     chrome = json.loads((tmp_path / "trace.json").read_text())
-    assert len(chrome["traceEvents"]) == 6
+    assert len(chrome["traceEvents"]) == 8
 
 
 def test_make_calibration_twin_trains_at_double_n_mu():
@@ -430,3 +491,204 @@ def test_make_calibration_twin_trains_at_double_n_mu():
     loss = twin.train_batch(tok2, tgt2)
     assert np.isfinite(loss)
     assert eng._step_count == before  # live trajectory untouched
+
+
+# ---------------------------------------- spans inside the program
+
+
+@pytest.fixture
+def global_tracer():
+    """A fresh process-global tracer at `off`, as a driver starts."""
+    tr = trace_mod.configure(level="off")
+    yield tr
+    trace_mod.configure(level="off")
+
+
+@pytest.fixture(scope="module")
+def tiny_serving():
+    from shallowspeed_tpu.models import transformer as T
+    from shallowspeed_tpu.serving import ServingEngine
+
+    cfg = T.TransformerConfig(vocab=48, d_model=24, n_heads=2,
+                              n_layers=2, max_seq=96)
+    eng = ServingEngine(jax.device_put(T.init(cfg, seed=1)), cfg,
+                        n_blocks=48, block_size=8, max_slots=2,
+                        prefill_chunk=16)
+
+    def serve(prefix):
+        rng = np.random.default_rng(3)
+        for i in range(3):
+            eng.submit(rng.integers(0, cfg.vocab, 20).astype(np.int32),
+                       5, rid=f"{prefix}{i}")
+        eng.run()
+
+    serve("warm-")
+    return eng, serve
+
+
+def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
+                                                       tiny_serving):
+    """The scheduler's phases reach the ring at `off`, a served request
+    compiles no new executable for them, and the untagged rest of a
+    step (the self time of `engine.step`) stays a small part of it."""
+    eng, serve = tiny_serving
+    base = eng.executable_counts()
+    serve("ring-")
+    assert eng.executable_counts() == base
+    ring = global_tracer.ring()
+    by_seq = {e[0]: e for e in ring}
+    parent = lambda e: by_seq[e[1]][2] if e[1] is not None else None
+    names = {e[2] for e in ring}
+    assert {"engine.step", "admit", "prefill", "prefill.dispatch",
+            "prefill.sample", "prefill.fetch", "decode", "decode.prep",
+            "decode.dispatch", "decode.fetch", "decode.emit"} <= names
+    assert "compile" not in names
+    for e in ring:
+        want = {"engine.step": None, "admit": "engine.step",
+                "prefill": "engine.step", "decode": "engine.step",
+                "alloc": "decode.prep"}.get(e[2], e[2].split(".")[0])
+        assert parent(e) == want, (e[2], parent(e))
+    steps = [e for e in ring if e[2] == "engine.step"]
+    assert [e[5]["tick"] for e in steps] == sorted(
+        e[5]["tick"] for e in steps)
+    assert sum(e[5]["n_admitted"] for e in ring if e[2] == "admit") == 3
+    decode = next(e for e in ring if e[2] == "decode")
+    assert decode[5] == {"n_active": decode[5]["n_active"], "width": 4}
+    covered = sum(e[4] - e[3] for e in ring
+                  if parent(e) == "engine.step")
+    whole = sum(e[4] - e[3] for e in steps)
+    assert 0.75 * whole <= covered <= whole
+
+
+def test_profiler_session_holds_the_programs_spans(global_tracer,
+                                                   tiny_serving,
+                                                   tmp_path):
+    """Under a `jax.profiler` session the host plane holds the
+    engine's spans as `ss:<name>` on the profiler's clock, nested as
+    the program nests them — with no switch thrown anywhere."""
+    eng, serve = tiny_serving
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        serve("prof-")
+    finally:
+        jax.profiler.stop_trace()
+    found = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert found
+    host = next(p for p in
+                jax.profiler.ProfileData.from_file(str(found[-1])).planes
+                if p.name == "/host:CPU")
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for line in host.lines for e in line.events
+             if e.name.startswith("ss:")]
+    steps = [s for s in spans if s[0] == "ss:engine.step"]
+    fetches = [s for s in spans if s[0] == "ss:decode.fetch"]
+    assert steps and fetches
+    assert len(steps) == sum(1 for e in global_tracer.ring()
+                             if e[2] == "engine.step")
+    for _, a, b in fetches:
+        assert any(s <= a and b <= e for _, s, e in steps)
+
+
+def test_a_compile_is_a_span_under_the_open_span(global_tracer,
+                                                 tmp_path):
+    """A fresh `jax.jit` call inside a span leaves one `compile` span
+    whose parent is that span, and with the persistent cache on a
+    `cache_miss` beside it; the same program again compiles (a fetch
+    from the cache) and misses nothing."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def fresh(x):
+        return x * 3 + 1
+
+    x5, x7 = jnp.ones(5), jnp.ones(7)   # their own compiles, out here
+    with global_tracer.span("outer"):
+        jax.jit(fresh)(x5).block_until_ready()
+    ring = global_tracer.ring()
+    outer = next(e for e in ring if e[2] == "outer")
+    compiles = [e for e in ring if e[2] == "compile" and e[1] == outer[0]]
+    assert [e[5]["fun"] for e in compiles] == ["jit(fresh)"]
+    assert outer[3] <= compiles[0][4] <= outer[4]
+
+    old = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        with global_tracer.span("cold"):
+            jax.jit(lambda x: x * 5 - 2)(x7).block_until_ready()
+        with global_tracer.span("warm"):
+            jax.jit(lambda x: x * 5 - 2)(x7).block_until_ready()
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    by_seq = {e[0]: e for e in global_tracer.ring()}
+    under = lambda name: [by_seq[e[1]][2] for e in by_seq.values()
+                          if e[2] == name and e[1] is not None]
+    assert under("cache_miss") == ["cold"]
+    assert under("compile")[-2:] == ["cold", "warm"]
+
+
+def test_gaps_names_the_span_a_device_idled_in(tmp_path, capsys):
+    """`--gaps`: busy is the union of a device's operations, and each
+    idle gap goes to the innermost `ss:` span open at its middle."""
+    from shallowspeed_tpu.telemetry.__main__ import main
+    from shallowspeed_tpu.telemetry.profiler import device_gaps
+
+    trace = {
+        "/device:TPU:0": {"XLA Ops": [
+            ["%a = f32[8] fusion()", 0.0, 1.0],
+            ["%b = f32[8] fusion()", 0.5, 1.0],     # overlaps: busy 0-1.5
+            ["%c = f32[8] fusion()", 2.0, 1.0],
+            ["%d = f32[8] fusion()", 3.5, 0.5]],
+            "XLA Modules": [["jit__decode_tick(1)", 0.0, 4.0]]},
+        "/device:TPU:1": {"XLA Ops": [["%a = f32[8] fusion()", 0.0, 4.0]]},
+        "/host:CPU": {"python": [
+            ["ss:engine.step", 1.0, 2.8], ["ss:decode", 1.2, 1.0],
+            ["ss:decode.prep", 1.4, 0.6], ["bench:step", 0.9, 3.0],
+            ["ss:engine.step", 3.85, 0.1]]},
+    }
+    dev0, dev1 = device_gaps(trace)
+    assert dev0["traced_s"] == 4.0 and dev0["busy_s"] == pytest.approx(3.0)
+    # gap 1.5-2.0 (middle 1.75) sits in decode.prep, innermost of three;
+    # gap 3.0-3.5 (middle 3.25) in engine.step alone
+    assert dev0["idle_by_span"] == {"decode.prep": pytest.approx(0.5),
+                                    "engine.step": pytest.approx(0.5)}
+    assert dev1["idle_s"] == 0.0 and dev1["idle_by_span"] == {}
+    path = tmp_path / "trace.json"
+    path.write_text(json.dumps(trace))
+    assert main(["--gaps", str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "/device:TPU:0: traced 4.000 s, busy 3.000 s, idle 1.000 s" in out
+    assert "idle in decode.prep" in out
+    path.write_text(json.dumps({"/host:CPU": trace["/host:CPU"]}))
+    assert main(["--gaps", str(path)]) == 1
+
+
+def test_gaps_on_a_trace_recorded_on_the_chip(capsys):
+    """Three decode ticks of `olmo-1b.chat` on a TPU v5e (PR 26; device
+    0's operations and the host's `ss:` / `bench:` spans, names cut
+    short): between two ticks the device waits 2.7 ms, and for most of
+    it the host is still inside `decode.fetch`, which returns 2.3 ms
+    after the tick's last operation."""
+    from pathlib import Path
+
+    from shallowspeed_tpu.telemetry.__main__ import main
+    from shallowspeed_tpu.telemetry.profiler import (device_gaps,
+                                                     load_device_trace)
+
+    path = Path(__file__).parent / "data" / "chat_ticks_trace.json.gz"
+    (dev,) = device_gaps(load_device_trace(path))
+    assert dev["device"] == "/device:TPU:0"
+    assert dev["busy_s"] + dev["idle_s"] == pytest.approx(dev["traced_s"])
+    assert 0.04 < dev["idle_s"] / dev["traced_s"] < 0.08
+    top = next(iter(dev["idle_by_span"]))
+    assert top == "decode.fetch"
+    assert dev["idle_by_span"][top] > 0.9 * dev["idle_s"]
+    assert "bench:step" not in dev["idle_by_span"]
+    assert main(["--gaps", str(path)]) == 0
+    assert "idle in decode.fetch" in capsys.readouterr().out
