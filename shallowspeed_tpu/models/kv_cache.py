@@ -105,14 +105,22 @@ def cache_write(cache_blk, k, v, pos):
 
 
 def masked_attention(q, cache_blk, valid, cfg):
-    """The cache-attention core: q (B, Tq, H, hd) attends over a
-    head-major K/V view (B, Hkv, S, hd) under an explicit validity
-    mask. `valid` is a boolean broadcastable against the
-    (B, Hkv, G, Tq, S) score tensor — contiguous decode passes the
-    position prefix (`cached_attention`), the serving runtime passes
-    per-row masks over a gathered block table with the same math, so
+    """The cache-attention core: q (B, Tq, H, hd) attends over a K/V
+    view under an explicit validity mask. The view is the contiguous
+    head-major cache (B, Hkv, S, hd), or the serving runtime's PAGED
+    view (B, W, Hkv, P, hd) — `serving/cache.gather_table`: W pages of
+    P positions, S = W*P, position w*P + p. `valid` is a boolean
+    broadcastable against the (B, Hkv, G, Tq, S) score tensor —
+    contiguous decode passes the position prefix (`cached_attention`),
+    the serving runtime per-row masks over its gathered block table.
+    One contraction serves both (the contiguous cache is one page), so
     paged and contiguous logits can only differ by gather/fp-reorder
     noise (pinned to 1e-4 in tests/test_serving.py).
+
+    A single query per row (the decode tick) contracts over the pages
+    as gathered: making the table head-major first would copy all of
+    it, per layer, for one use. A chunk of queries (prefill) folds its
+    row's pages into one head-major page first.
 
     GQA caches hold Hkv heads and are read UNREPEATED (grouped einsum):
     decode is HBM-bandwidth-bound on the cache sweep, so the group
@@ -121,12 +129,26 @@ def masked_attention(q, cache_blk, valid, cfg):
     einsums (K's on the score, V's folded into the probability row) so
     the HBM reads stay int8.
     """
-    k, v = cache_blk["k"], cache_blk["v"]       # (B, Hkv, S, hd)
     b, tq, h, hd = q.shape
-    kvh = k.shape[1]
+    if cache_blk["k"].ndim == 4:                # contiguous: one page
+        cache_blk = {n: l[:, None] for n, l in cache_blk.items()}
+    elif tq > 1:
+        # a chunk of queries amortises a head-major copy of its row's
+        # table (and the P.V matmul then needs no relayout of the
+        # probabilities); a single query per row does not
+        cache_blk = {n: jnp.swapaxes(l, 1, 2).reshape(
+            b, 1, l.shape[2], -1, l.shape[-1])
+            for n, l in cache_blk.items()}
+    k, v = cache_blk["k"], cache_blk["v"]       # (B, W, Hkv, P, hd)
+    w, kvh, page = k.shape[1:4]
     quant = "k_s" in cache_blk
     qg = q.reshape(b, tq, kvh, h // kvh, hd)
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+
+    def rowwise(s_leaf):                        # (B,W,Hkv,P,1) -> (B,Hkv,1,1,S)
+        return jnp.swapaxes(s_leaf[..., 0], 1, 2).reshape(
+            b, kvh, 1, 1, w * page)
+
     if quant:
         # int8 sweep: the einsum reads int8 rows (the cast fuses into
         # the load; int8 values are EXACT in bf16, so the MXU runs at
@@ -134,27 +156,22 @@ def masked_attention(q, cache_blk, valid, cfg):
         # scale is constant over hd, so it multiplies the SCORE
         # instead of dequantizing the cache
         cdt = cfg.compute_dtype or cfg.dtype
-        s = jnp.einsum("bqhgd,bhkd->bhgqk", qg.astype(cdt),
-                       k.astype(cdt),
-                       preferred_element_type=jnp.float32)
-        s = s * cache_blk["k_s"][..., 0][:, :, None, None, :]
-        s = s * scale
-    else:
-        s = jnp.einsum("bqhgd,bhkd->bhgqk", qg, k,
-                       preferred_element_type=jnp.float32) * scale
+        qg, k, v = qg.astype(cdt), k.astype(cdt), v.astype(cdt)
+    s = jnp.einsum("bqhgd,bwhkd->bhgqwk", qg, k,
+                   preferred_element_type=jnp.float32)
+    s = s.reshape(b, kvh, h // kvh, tq, w * page)
+    if quant:
+        s = s * rowwise(cache_blk["k_s"])
+    s = s * scale
     s = jnp.where(valid, s, jnp.float32(-1e30))
     p = jax.nn.softmax(s, axis=-1)
     if quant:
         # V's scale varies along the summation index — fold it into the
         # (tiny) probability rows, keeping the V read int8
-        cdt = cfg.compute_dtype or cfg.dtype
-        pv = p * cache_blk["v_s"][..., 0][:, :, None, None, :]
-        out = jnp.einsum("bhgqk,bhkd->bqhgd", pv.astype(cdt),
-                         v.astype(cdt),
-                         preferred_element_type=jnp.float32)
-    else:
-        out = jnp.einsum("bhgqk,bhkd->bqhgd", p.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32)
+        p = p * rowwise(cache_blk["v_s"])
+    p = p.astype(v.dtype).reshape(b, kvh, h // kvh, tq, w, page)
+    out = jnp.einsum("bhgqwk,bwhkd->bqhgd", p, v,
+                     preferred_element_type=jnp.float32)
     return out.reshape(b, tq, h, hd).astype(q.dtype)
 
 

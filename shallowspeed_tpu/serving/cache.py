@@ -9,16 +9,18 @@ request would pay the longest request's slots. The paged layout instead
 carves each layer's cache into fixed `(n_blocks, Hkv, block_size, hd)`
 POOLS (vLLM's PagedAttention memory model, arXiv 2309.06180, rebuilt
 jit-first): a request owns an ordered list of block ids (its *block
-table*), the pools are donated through every compiled tick (no copies,
-stable buffers), and attention reads through a GATHERED view of the
-table — `pool[bt]` — masked by position. Appending a token allocates at
+table*), the pools are donated through every compiled program and
+written IN PLACE (`write_rows`, `write_chunk`: scatters indexed on the
+leading dimension only, so the donated buffer keeps its layout and no
+pool-sized copy runs), and attention reads through a GATHERED view of
+the table — `pool[bt]` — masked by position. Appending a token allocates at
 most one block; freeing a finished request returns its blocks in O(1);
 fragmentation cannot exist because any free block serves any request.
 
 Block 0 is RESERVED as a scratch sink: compiled programs run at a fixed
-slot capacity, so inactive slots (and the padded tail of a prefill
-chunk) still execute their cache write — they are steered to block 0,
-which no live table ever contains. That keeps the tick free of
+slot capacity, so inactive slots (and the blocks past the true end of a
+prefill chunk) still execute their cache write — they are steered to
+block 0, which no live table ever contains. That keeps the tick free of
 host-side branching without ever corrupting a live block.
 
 int8 pools mirror the contiguous int8 cache exactly (same per-(row,
@@ -325,17 +327,27 @@ def gather_table(pool_blk, bt):
     pool_blk: {"k"/"v": (N, Hkv, bs, hd)[, "k_s"/"v_s": (N, Hkv, bs, 1)]}
     bt: (rows, W) int32 block ids (padding rows/tail point at the
     scratch block — the caller's position mask never admits them).
-    Returns the contiguous-cache view {"k"/"v": (rows, Hkv, W*bs, hd),
-    ...} that `kv_cache.masked_attention` consumes: gathered position
-    j IS absolute position j because tables are ordered."""
-    rows, w = bt.shape
-    out = {}
-    for name, leaf in pool_blk.items():
-        n, hkv, bs, tail = leaf.shape
-        g = leaf[bt]                           # (rows, W, Hkv, bs, tail)
-        out[name] = jnp.swapaxes(g, 1, 2).reshape(rows, hkv, w * bs,
-                                                  tail)
-    return out
+    Returns the PAGED view {"k"/"v": (rows, W, Hkv, bs, hd), ...} as the
+    gather leaves it: page w, slot s of a row IS absolute position
+    w*bs + s because tables are ordered. `kv_cache.masked_attention`
+    contracts over it directly — the head-major
+    (rows, Hkv, W*bs, hd) form would cost a transposed copy of the
+    whole gathered table per layer (PERF.md, PR 27)."""
+    return {name: leaf[bt] for name, leaf in pool_blk.items()}
+
+
+def _kv_update(pool_blk, k_rows, v_rows, quant: bool):
+    """{leaf name: (rows, Hkv, tail)} — the values a write stores, in
+    the pool's own dtypes. Quantization matches
+    `kv_cache.cache_write`'s int8 path value-for-value (same
+    absmax-over-hd scales)."""
+    if quant:
+        kq, ks = quantize_kv(k_rows[:, :, None, :])   # (rows,Hkv,1,hd)
+        vq, vs = quantize_kv(v_rows[:, :, None, :])
+        return {"k": kq[:, :, 0], "k_s": ks[:, :, 0],
+                "v": vq[:, :, 0], "v_s": vs[:, :, 0]}
+    return {"k": k_rows.astype(pool_blk["k"].dtype),
+            "v": v_rows.astype(pool_blk["v"].dtype)}
 
 
 def write_rows(pool_blk, k_rows, v_rows, blk_ids, offs, quant: bool):
@@ -345,18 +357,68 @@ def write_rows(pool_blk, k_rows, v_rows, blk_ids, offs, quant: bool):
     (rows,) int32 destination (block id, in-block offset). Rows steered
     to the scratch block may collide — by construction nothing ever
     reads scratch, so the unspecified duplicate-scatter winner is
-    irrelevant. Quantization matches `kv_cache.cache_write`'s int8
-    path value-for-value (same absmax-over-hd scales)."""
-    if quant:
-        kq, ks = quantize_kv(k_rows[:, :, None, :])   # (rows,Hkv,1,hd)
-        vq, vs = quantize_kv(v_rows[:, :, None, :])
-        upd = {"k": kq[:, :, 0], "k_s": ks[:, :, 0],
-               "v": vq[:, :, 0], "v_s": vs[:, :, 0]}
-    else:
-        upd = {"k": k_rows.astype(pool_blk["k"].dtype),
-               "v": v_rows.astype(pool_blk["v"].dtype)}
-    return {name: pool_blk[name].at[blk_ids, :, offs, :].set(val)
-            for name, val in upd.items()}
+    irrelevant.
+
+    The scatter goes through the FLAT view (N*Hkv*bs, tail), row
+    (blk*Hkv + h)*bs + off: one indexed dimension, the leading one.
+    `pool.at[blk, :, off, :]` indexes dimensions 0 and 2, and XLA:TPU
+    gives such a scatter an operand layout with the indexed dimensions
+    major ({3,1,2,0}) while the donated pool lives in {3,2,1,0}: two
+    pool-sized copies per leaf per program run (PERF.md, PR 27). The
+    flat view keeps the pool's own layout, so the scatter updates the
+    donated buffer in place. The reshape is a bitcast when `bs` is a
+    multiple of the dtype's sublane tile and `tail` fills the lanes
+    (bs 16, hd 128: bf16, f32 and int8 alike); otherwise (the
+    (N, Hkv, bs, 1) scale planes of int8 pools, toy shapes) XLA
+    relayouts that leaf as it did before, and nothing large rides on
+    it."""
+    n, hkv, bs, _ = pool_blk["k"].shape
+    row = ((blk_ids[:, None] * hkv + jnp.arange(hkv)) * bs
+           + offs[:, None]).reshape(-1)               # (rows * Hkv,)
+    out = {}
+    for name, val in _kv_update(pool_blk, k_rows, v_rows, quant).items():
+        tail = val.shape[-1]
+        flat = pool_blk[name].reshape(n * hkv * bs, tail)
+        out[name] = flat.at[row].set(
+            val.reshape(-1, tail)).reshape(n, hkv, bs, tail)
+    return out
+
+
+def write_chunk(pool_blk, k_rows, v_rows, table, pos0, n_tok,
+                quant: bool):
+    """Write a prefill chunk: rows j < n_tok of k_rows/v_rows (C, Hkv,
+    hd) land at the CONSECUTIVE positions pos0 + j of the request whose
+    block table is `table` (W,); rows beyond `n_tok` are padding and
+    land nowhere.
+
+    Consecutive positions touch at most C/bs + 1 blocks, so instead of
+    scattering C * Hkv rows the chunk gathers those few blocks, merges
+    its rows into them and scatters WHOLE blocks back: one indexed
+    dimension, the leading one, in the pool's own layout — in place
+    like `write_rows`, with C/bs + 1 indices. `pos0` need not be
+    block-aligned (a fully aligned prefix hit re-prefills the last
+    token of its copied tail block) and `n_tok` may end mid-block, so
+    the first and the last block are MERGED: slots outside
+    [pos0, pos0 + n_tok) keep what they held. Block slots past the
+    chunk's true end are steered to the scratch block and write its
+    own contents back. Decode cannot use this form: draft rows of one
+    request share a block (duplicate indices, different contents)."""
+    c = k_rows.shape[0]
+    bs = pool_blk["k"].shape[2]
+    tb = pos0 // bs + jnp.arange((c + 2 * bs - 2) // bs)   # table slots
+    ids = jnp.where(tb * bs < pos0 + n_tok,
+                    table[jnp.clip(tb, 0, table.shape[0] - 1)],
+                    SCRATCH_BLOCK)
+    slot = tb[:, None] * bs + jnp.arange(bs)               # (T, bs) pos
+    fresh = (slot >= pos0) & (slot < pos0 + n_tok)
+    src = jnp.clip(slot - pos0, 0, c - 1)                  # chunk row
+    out = {}
+    for name, val in _kv_update(pool_blk, k_rows, v_rows, quant).items():
+        rows = jnp.swapaxes(val[src], 1, 2)                # (T,Hkv,bs,tail)
+        merged = jnp.where(fresh[:, None, :, None], rows,
+                           pool_blk[name][ids])
+        out[name] = pool_blk[name].at[ids].set(merged)
+    return out
 
 
 # ------------------------------------------------ per-tick HBM model

@@ -18,7 +18,7 @@ head-of-line behind the longest. This engine serves a STREAM:
   mid-run delays in-flight decodes by at most one chunk per tick
   instead of one full prefill. Chunks are padded to the fixed chunk
   length with the true length traced (the `prompt_bucket_len` idea at
-  chunk granularity), pad positions write to scratch.
+  chunk granularity), pad positions are never written.
 - **Admission + preemption.** A request is admitted when a slot and
   its prompt's blocks are free; a decode append that finds the pool
   empty EVICTS the newest-admitted running request (its blocks free
@@ -111,7 +111,8 @@ from shallowspeed_tpu.serving.cache import (SCRATCH_BLOCK, BlockAllocator,
                                             blocks_for, gather_table,
                                             init_block_pool,
                                             paged_read_bytes_per_tick,
-                                            param_read_bytes, write_rows)
+                                            param_read_bytes, write_chunk,
+                                            write_rows)
 
 
 # finished-request timelines the engine retains in memory for
@@ -196,8 +197,14 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
     token's K/V at (bt[pos // bs], pos % bs) and attends over its
     gathered table under the position mask; inactive slots carry
     pos=0 / bt=scratch and their results are ignored host-side.
-    Returns (next token per slot, updated pools); pools are DONATED —
-    the caches update in place across ticks.
+    Returns (next token per slot, updated pools). The pools are
+    DONATED and every write to them is indexed on the leading
+    dimension alone (`write_rows`' flat view here, whole blocks in
+    `_prefill_chunk`), which is what lets XLA:TPU update the donated
+    buffers in place: a scatter indexed on dimensions 0 and 2 wants a
+    layout the parameter does not have, and paid two pool-sized
+    copies per leaf per run for it
+    (tests/test_tpu_compile.py holds the compiled programs to this).
 
     `attn="flash"` swaps the gather + masked_attention read for the
     fused `paged_flash_decode` kernel (same math, no materialized
@@ -255,9 +262,10 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
                    cow_dst, *, cfg: T.TransformerConfig):
     """One chunk of a request's prefill: tokens (1, C) — C is the
     fixed chunk length, `n_tok` the traced true count (the tail is
-    padding, steered to the scratch block exactly like `generate`'s
-    bucket padding is overwritten-before-read). Writes the chunk's
-    K/V through the block table and attends causally over the table
+    padding: never written, and masked out of every true row's read).
+    Writes the chunk's K/V through the block table (`write_chunk`: the
+    few blocks its consecutive positions touch, merged and written
+    back whole, in place) and attends causally over the table
     (earlier chunks included). Returns (f32 logits at the chunk's last
     true position — consumed only on the final chunk — and the
     updated, donated pools).
@@ -285,11 +293,6 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
               for name, leaf in pool.items()} for pool in pools]
     pos = pos0 + jnp.arange(c)
     x = G._embed(params, tokens, pos0, cfg)                  # (1, C, d)
-    j = jnp.arange(c)
-    keep = j < n_tok
-    blk = jnp.where(keep, bt[0, jnp.clip(pos // bs, 0, w - 1)],
-                    SCRATCH_BLOCK)
-    off = jnp.where(keep, pos % bs, 0)
     span = jnp.arange(w * bs)
     valid = span[None, :] <= pos[:, None]                   # (C, W*bs)
     if cfg.attn_window > 0:
@@ -302,7 +305,8 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
         if cfg.rope:
             q = T.rope_rotate(q, pos, cfg.rope_theta)
             k = T.rope_rotate(k, pos, cfg.rope_theta)
-        pool = {**pool, **write_rows(pool, k[0], v[0], blk, off, quant)}
+        pool = {**pool, **write_chunk(pool, k[0], v[0], bt[0], pos0,
+                                      n_tok, quant)}
         a = masked_attention(q, gather_table(pool, bt), valid, cfg)
         x = x + T._dense(p["proj"], a.reshape(1, c, cfg.d_model))
         h = T._norm(p["ln2"], x, cfg)

@@ -934,6 +934,165 @@ def test_write_rows_scratch_sink_isolation():
                     f"traffic")
 
 
+# ---- the in-place write forms against the indexed scatter they replace
+#
+# `pool.at[blk, :, off, :].set(val)` is what PR 27 took off the device
+# path (two pool-sized layout copies per leaf on the TPU); it stays
+# here as the reference: on the CPU the new forms are bit-identical.
+
+_POOL_KINDS = {"f32": (jnp.float32, ""), "bf16": (jnp.bfloat16, ""),
+               "int8": (jnp.float32, "int8")}
+
+
+def _filled(pool, rng):
+    """The pool with every slot of every leaf drawn from `rng`."""
+    return {name: jnp.asarray(
+        rng.integers(-127, 128, leaf.shape) if leaf.dtype == jnp.int8
+        else rng.normal(size=leaf.shape), leaf.dtype)
+        for name, leaf in pool.items()}
+
+
+def _pool_case(kind, kv_heads, n_blocks=12, bs=8, seed=0):
+    """(cfg, a pool with every block filled from the seed, quant)."""
+    dt, kv_quant = _POOL_KINDS[kind]
+    cfg = replace(CFG, n_kv_heads=kv_heads, compute_dtype=dt)
+    pool = init_block_pool(cfg, n_blocks, bs, kv_quant=kv_quant)[0]
+    return (cfg, _filled(pool, np.random.default_rng(seed)),
+            bool(kv_quant))
+
+
+def _kv_rows(cfg, n, seed):
+    rng = np.random.default_rng(seed)
+    dt = cfg.compute_dtype or cfg.dtype
+    return [jnp.asarray(rng.normal(size=(n, cfg.kv_heads, cfg.head_dim)),
+                        dt) for _ in range(2)]
+
+
+def _indexed_scatter(pool, k, v, blk, off, quant):
+    """The write as it was before PR 27."""
+    from shallowspeed_tpu.serving.cache import _kv_update
+
+    return {name: pool[name].at[blk, :, off, :].set(val)
+            for name, val in _kv_update(pool, k, v, quant).items()}
+
+
+def _assert_pools_bit_equal(got, ref, blocks=slice(None)):
+    assert got.keys() == ref.keys()
+    for name in ref:
+        g, r = np.asarray(got[name]), np.asarray(ref[name])
+        assert g.dtype == r.dtype and g.shape == r.shape, name
+        np.testing.assert_array_equal(
+            g[blocks].view(np.uint8), r[blocks].view(np.uint8),
+            err_msg=f"{name}: differs from the indexed scatter")
+
+
+_ROW_CASES = {
+    # (block ids, offsets); block 0 is the scratch sink
+    "distinct": ([3, 7, 1, 9], [0, 5, 7, 2]),
+    "scratch-colliding": ([3, 0, 0, 0, 7], [1, 0, 0, 4, 6]),
+    "drafts-one-block": ([4, 4, 4, 4, 2], [2, 3, 4, 5, 0]),
+    "drafts-two-blocks": ([4, 4, 6, 6, 0], [6, 7, 0, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_CASES))
+@pytest.mark.parametrize("kv_heads", [0, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("kind", list(_POOL_KINDS))
+def test_write_rows_equals_indexed_scatter_bitwise(kind, kv_heads, case):
+    """The decode tick's row scatter through the flat (N*Hkv*bs, hd)
+    view stores exactly what the (block, :, offset, :) scatter stored,
+    scales included: distinct rows, draft rows of one request inside
+    one block and across two, and colliding scratch rows (whose winner
+    is unspecified, so scratch itself is not compared there)."""
+    from shallowspeed_tpu.serving.cache import write_rows
+
+    cfg, pool, quant = _pool_case(kind, kv_heads)
+    blk, off = (jnp.asarray(a, jnp.int32) for a in _ROW_CASES[case])
+    k, v = _kv_rows(cfg, len(blk), seed=5)
+    got = write_rows(pool, k, v, blk, off, quant)
+    ref = _indexed_scatter(pool, k, v, blk, off, quant)
+    live = slice(1, None) if case == "scratch-colliding" else slice(None)
+    _assert_pools_bit_equal(got, ref, live)
+    assert any(not np.array_equal(np.asarray(got[n]), np.asarray(pool[n]))
+               for n in pool), "nothing was written"
+
+
+_CHUNK_CASES = {
+    # (pos0, n_tok) of a 16-row chunk over blocks of 8
+    "aligned-full": (8, 16),
+    "tail-block-unaligned": (7, 16),      # bs-1 into a copied tail block
+    "ends-mid-block": (16, 11),
+    "unaligned-and-mid-block": (15, 6),
+    "one-token": (23, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(_CHUNK_CASES))
+@pytest.mark.parametrize("kv_heads", [0, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("kind", list(_POOL_KINDS))
+def test_write_chunk_equals_indexed_scatter_bitwise(kind, kv_heads, case):
+    """The prefill chunk's merged whole-block write lands rows
+    j < n_tok at positions pos0 + j and nothing else: every live block
+    equals what the per-row scatter (padding steered to scratch) left,
+    for a `pos0` inside a block and an `n_tok` that ends inside one.
+    Scratch is not compared: the row form parks padding there, the
+    block form writes scratch's own contents back."""
+    from shallowspeed_tpu.serving.cache import SCRATCH_BLOCK, write_chunk
+
+    cfg, pool, quant = _pool_case(kind, kv_heads)
+    bs, c = 8, 16
+    table = jnp.asarray([5, 2, 9, 4, 0, 0], jnp.int32)     # padded W 6
+    pos0, n_tok = _CHUNK_CASES[case]
+    k, v = _kv_rows(cfg, c, seed=6)
+    got = write_chunk(pool, k, v, table, jnp.int32(pos0),
+                      jnp.int32(n_tok), quant)
+    pos = pos0 + np.arange(c)
+    keep = np.arange(c) < n_tok
+    blk = np.where(keep, np.asarray(table)[np.clip(pos // bs, 0, 5)],
+                   SCRATCH_BLOCK)
+    off = np.where(keep, pos % bs, 0)
+    ref = _indexed_scatter(pool, k, v, jnp.asarray(blk, jnp.int32),
+                           jnp.asarray(off, jnp.int32), quant)
+    _assert_pools_bit_equal(got, ref, slice(1, None))
+    _assert_pools_bit_equal({n: l[:1] for n, l in got.items()},
+                            {n: l[:1] for n, l in pool.items()})
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"], ids=["f32", "int8"])
+def test_prefill_chunk_diverging_in_tail_leaves_shared_block_bit_unchanged(
+        params, kv_quant):
+    """The program-level copy-on-write contract under the block-form
+    write: a request that diverges at the LAST slot of a shared tail
+    block (`pos0` = bs-1 into its copy) leaves the shared block and
+    the shared prefix block bit-unchanged; its own copy keeps the
+    donor's first bs-1 slots and takes the new row at slot bs-1."""
+    from shallowspeed_tpu.serving.engine import _prefill_chunk
+
+    bs, c = 8, 8
+    rng = np.random.default_rng(11)
+    pools = [_filled(pool, rng)
+             for pool in init_block_pool(CFG, 12, bs, kv_quant=kv_quant)]
+    before = [{n: np.asarray(l).copy() for n, l in p.items()}
+              for p in pools]
+    prefix, tail, own = 3, 6, 9            # shared, shared, this request's
+    bt = jnp.asarray([[prefix, own, 0, 0]], jnp.int32)
+    tokens = jnp.asarray(toks(3, t=c)[None, :])
+    _, after = _prefill_chunk(params, pools, tokens, jnp.int32(2 * bs - 1),
+                              jnp.int32(1), bt, jnp.int32(tail),
+                              jnp.int32(own), cfg=CFG)
+    for b, a in zip(before, after):
+        for n in b:
+            got = np.asarray(a[n])
+            untouched = [i for i in range(1, 12) if i != own]
+            np.testing.assert_array_equal(got[untouched], b[n][untouched],
+                                          err_msg=f"{n}: a block other "
+                                          f"than the copy changed")
+            np.testing.assert_array_equal(got[own][:, :bs - 1],
+                                          b[n][tail][:, :bs - 1])
+            assert not np.array_equal(got[own][:, bs - 1],
+                                      b[n][tail][:, bs - 1]), n
+
+
 def test_paged_read_bytes_per_tick_model(params):
     """The live-blocks HBM model: params once + touched blocks' K/V
     (+ int8 scales) + token ids — the serving generalization of

@@ -1,0 +1,138 @@
+"""What the TPU compiler makes of the serving programs, without a chip.
+
+libtpu's compile-only topology runs the real XLA:TPU compiler in this
+sandbox (`.claude/skills/verify/SKILL.md`, "Mosaic without a chip").
+Nothing runs, so these tests read the optimized HLO: what was emitted,
+not how long it takes. They skip where libtpu cannot describe a v5e.
+
+The topology is described inside a fixture, never at import: one
+process at a time may load libtpu, and every xdist worker imports every
+test file. Keep further compile-only tests in THIS file for the same
+reason (a second file can land on a worker that cannot load it).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from shallowspeed_tpu.models import transformer as T
+from shallowspeed_tpu.serving.cache import init_block_pool
+from shallowspeed_tpu.serving.engine import _decode_tick, _prefill_chunk
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# published head shapes (head_dim 128), two layers, bf16; the widths
+# that do not shape the cache are cut so the compile stays seconds
+_HEADS = {
+    "olmo-1b-mha": dict(d_model=2048, n_heads=16, n_kv_heads=0),
+    "mistral-7b-gqa": dict(d_model=4096, n_heads=32, n_kv_heads=8),
+}
+N_BLOCKS, BLOCK, SLOTS, WIDTH, CHUNK = 321, 16, 16, 8, 128
+
+_SKIP_OPS = ("parameter", "bitcast", "get-tuple-element", "tuple",
+             "constant")
+
+
+def _compiled_text(program, one_chip, heads):
+    cfg = T.TransformerConfig(
+        vocab=512, d_ff=512, n_layers=2, max_seq=2048, rope=True,
+        norm="rmsnorm", ffn="swiglu", dtype=jnp.bfloat16,
+        compute_dtype=jnp.bfloat16, **_HEADS[heads])
+
+    def spec(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = spec(jax.eval_shape(
+        lambda: T.cast_params(T.init(cfg, seed=0), jnp.bfloat16)))
+    pools = spec(jax.eval_shape(
+        lambda: init_block_pool(cfg, N_BLOCKS, BLOCK)))
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "decode_tick":
+        traced = _decode_tick.trace(
+            params, pools, arr(i32, SLOTS), arr(i32, SLOTS),
+            arr(i32, SLOTS, WIDTH), arr(f32, SLOTS), arr(i32, SLOTS),
+            arr(i32, SLOTS), cfg=cfg, top_k=0, top_p=0.0, attn="gather")
+    else:
+        traced = _prefill_chunk.trace(
+            params, pools, arr(i32, 1, CHUNK), arr(i32), arr(i32),
+            arr(i32, 1, WIDTH), arr(i32), arr(i32), cfg=cfg)
+    text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
+    leaf = pools[0]["k"]
+    return text, len(jax.tree_util.tree_leaves(pools)), leaf.size
+
+
+def _computations(text):
+    """{name: body lines} of every computation of an HLO module."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+            if line.startswith("ENTRY"):
+                out["ENTRY"] = out[name]
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("heads", list(_HEADS))
+@pytest.mark.parametrize("program", ["decode_tick", "prefill_chunk"])
+def test_serving_programs_write_the_pool_in_place(one_chip, program,
+                                                  heads):
+    """The gate of PR 27: in the optimized TPU program every donated
+    pool leaf is aliased to its output, and the ONLY entry instructions
+    whose output is as large as a pool leaf are fusions that end in a
+    scatter or a dynamic-update-slice (which XLA runs on the operand's
+    own buffer). A pool-sized `copy`, `transpose` or relayout fusion
+    here is two of them per leaf per program run on the chip: 45% of
+    `olmo-1b.chat`'s device time before this test existed."""
+    text, n_leaves, pool_elems = _compiled_text(program, one_chip, heads)
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp",
+                        text, re.S).group(1)
+    assert aliased.count("alias)") == n_leaves, aliased
+    comps = _computations(text)
+    offenders, in_place = [], 0
+    for line in comps["ENTRY"]:
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if not m or m.group(2) in _SKIP_OPS:
+            continue
+        elems = 1
+        for d in filter(None, m.group(1).split(",")):
+            elems *= int(d)
+        if elems != pool_elems:
+            continue
+        callee = re.search(r"calls=%?([\w.\-]+)", line)
+        root = next((l for l in comps.get(callee.group(1), [])
+                     if "ROOT" in l), "") if callee else ""
+        if m.group(2) == "fusion" and re.search(
+                r" (scatter|dynamic-update-slice)\(", root):
+            in_place += 1
+        else:
+            offenders.append(line.strip()[:160])
+    assert not offenders, "\n".join(offenders)
+    # each leaf is written once a layer (and copied-on-write once more
+    # in the prefill chunk)
+    assert in_place == n_leaves * (2 if program == "prefill_chunk" else 1)
