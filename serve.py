@@ -45,6 +45,26 @@ def parse_args(argv=None):
     m.add_argument("--n-layers", type=int, default=2)
     m.add_argument("--max-seq", type=int, default=512)
     m.add_argument("--rope", action="store_true")
+    m.add_argument("--rope-theta", type=float, default=10000.0)
+    m.add_argument("--norm", default="layernorm",
+                   choices=["layernorm", "rmsnorm"])
+    m.add_argument("--ffn", default="gelu", choices=["gelu", "swiglu"])
+    m.add_argument("--d-ff", type=int, default=0,
+                   help="dense FFN width (0 = 4 x d-model)")
+    m.add_argument("--latent", type=int, nargs=4, default=None,
+                   metavar=("RANK", "NOPE", "ROPE", "V"),
+                   help="latent attention (MLA): kv_lora_rank and the "
+                        "per-head qk_nope / qk_rope / v sizes; the paged "
+                        "cache then holds one RANK + ROPE row a token a "
+                        "layer (needs --rope)")
+    m.add_argument("--routed-experts", type=int, nargs=4, default=None,
+                   metavar=("EXPERTS", "PER_TOKEN", "SHARED", "WIDTH"),
+                   help="dropless sigmoid-routed SwiGLU experts of WIDTH "
+                        "with SHARED always-on ones, in every layer past "
+                        "--dense-layers")
+    m.add_argument("--routed-scale", type=float, default=1.0)
+    m.add_argument("--dense-layers", type=int, default=0,
+                   help="leading layers that keep the dense FFN")
     m.add_argument("--init-seed", type=int, default=0,
                    help="weight-init seed for the demo model")
     m.add_argument("--ckpt", default=None,
@@ -230,9 +250,21 @@ def main(argv=None) -> int:
     # reducible metrics file, not a truncated one
     install_sigterm_exit()
 
+    kinds = {}
+    if args.latent:
+        kinds.update(zip(("kv_lora_rank", "qk_nope_head_dim",
+                          "qk_rope_head_dim", "v_head_dim"), args.latent))
+    if args.routed_experts:
+        kinds.update(zip(("n_routed_experts", "moe_top_k",
+                          "n_shared_experts", "expert_d_ff"),
+                         args.routed_experts),
+                     routed_scaling_factor=args.routed_scale,
+                     first_dense_layers=args.dense_layers)
     cfg = T.TransformerConfig(
         vocab=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
-        n_layers=args.n_layers, max_seq=args.max_seq, rope=args.rope)
+        n_layers=args.n_layers, max_seq=args.max_seq, rope=args.rope,
+        rope_theta=args.rope_theta, norm=args.norm, ffn=args.ffn,
+        d_ff=args.d_ff, **kinds)
     if args.ckpt:
         from shallowspeed_tpu import checkpoint
 

@@ -29,7 +29,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 from shallowspeed_tpu.ops.attention import attention
-from shallowspeed_tpu.ops.moe import moe_ffn
+from shallowspeed_tpu.ops.latent_attention import latent_attention
+from shallowspeed_tpu.ops.moe import moe_ffn, routed_experts_ffn
 
 
 @dataclass(frozen=True)
@@ -172,6 +173,33 @@ class TransformerConfig:
     # (bench.py's fp8 case) pins that this flag shrinks
     # attrib_mxu_frac vs the bf16 baseline while shadow parity holds.
     fp8_dense: bool = False
+    # Multi-head latent attention (DeepSeek-V2's MLA; 0 = off). Keys and
+    # values of a token are ONE shared row of `kv_lora_rank` values (a
+    # joint down-projection, RMS-normed) plus `qk_rope_head_dim` rotary
+    # key dimensions shared by every head; an up-projection (`kv_b`)
+    # expands the row to `n_heads` x (`qk_nope_head_dim` keys |
+    # `v_head_dim` values). Queries are `n_heads` x (`qk_nope_head_dim` |
+    # `qk_rope_head_dim`), so no head size follows from d_model / n_heads.
+    # The serving cache stores the latent row itself (`serving/cache.py`)
+    # and reads it in the absorbed form (`ops/latent_attention.py`).
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Dropless routed experts with shared experts (DeepSeek-V3's layer;
+    # 0 = off): layers `first_dense_layers`.. replace the dense FFN by
+    # `n_routed_experts` SwiGLU experts of width `expert_d_ff`, of which
+    # each token takes `moe_top_k` (sigmoid scores, chosen on score +
+    # bias, weighted by the scores alone, normalised and scaled by
+    # `routed_scaling_factor`; `ops/moe.py:sigmoid_topk_routing`), plus
+    # one always-on SwiGLU of width `n_shared_experts * expert_d_ff`.
+    # No capacity, no dropped assignment; `n_experts` (above) stays the
+    # capacity-routed training layer.
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    expert_d_ff: int = 0
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0
 
     def __post_init__(self):
         assert self.norm in ("layernorm", "rmsnorm"), self.norm
@@ -190,6 +218,19 @@ class TransformerConfig:
         assert self.n_heads % self.kv_heads == 0, (
             f"n_heads={self.n_heads} must be divisible by "
             f"n_kv_heads={self.kv_heads}")
+        if self.latent:
+            assert self.rope and not self.gqa and not self.attn_window, (
+                "latent attention carries its own rotary dimensions and "
+                "shares one latent row across heads: it needs rope=True "
+                "and takes neither n_kv_heads nor attn_window")
+            assert self.qk_rope_head_dim % 2 == 0 and self.v_head_dim > 0 \
+                and self.qk_nope_head_dim > 0, "latent head sizes unset"
+        if self.n_routed_experts:
+            assert not self.n_experts, (
+                "n_routed_experts (dropless) and n_experts (capacity "
+                "routing) are two different layers; pick one")
+            assert 0 < self.moe_top_k <= self.n_routed_experts \
+                and self.expert_d_ff > 0, "routed expert sizes unset"
         # typed, not an assert: this gates a production precision mode
         if self.fp8_dense and _FP8_DTYPE is None:
             raise ValueError(
@@ -213,6 +254,18 @@ class TransformerConfig:
     def gqa(self) -> bool:
         return self.kv_heads != self.n_heads
 
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values one token leaves in one latent layer's cache."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def routed_layer(self, i: int) -> bool:
+        return self.n_routed_experts > 0 and i >= self.first_dense_layers
+
 
 def _dense_init(rng, in_d, out_d, dtype):
     w = rng.normal(0.0, 1.0 / np.sqrt(in_d), (in_d, out_d)).astype(dtype)
@@ -226,21 +279,44 @@ def init(cfg: TransformerConfig, seed: int = 0):
     dt = cfg.dtype
     d = cfg.d_model
     blocks = []
-    for _ in range(cfg.n_layers):
+    for i in range(cfg.n_layers):
         blk = {
             "ln1": {"g": np.ones((d,), dt), "b": np.zeros((d,), dt)},
-            "proj": _dense_init(rng, d, d, dt),
             "ln2": {"g": np.ones((d,), dt), "b": np.zeros((d,), dt)},
         }
-        if cfg.gqa:  # separate q and (smaller) fused kv projections
-            blk["q"] = _dense_init(rng, d, d, dt)
-            blk["kv"] = _dense_init(
-                rng, d, 2 * cfg.kv_heads * cfg.head_dim, dt)
+        if cfg.latent:
+            h, r = cfg.n_heads, cfg.kv_lora_rank
+            dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+            blk["q"] = _dense_init(rng, d, h * (dn + dr), dt)
+            blk["kv_a"] = _dense_init(rng, d, r + dr, dt)
+            blk["kv_norm"] = {"g": np.ones((r,), dt)}
+            blk["kv_b"] = rng.normal(0.0, 1.0 / np.sqrt(r),
+                                     (r, h, dn + dv)).astype(dt)
+            blk["proj"] = _dense_init(rng, h * dv, d, dt)
         else:
-            blk["qkv"] = _dense_init(rng, d, 3 * d, dt)
-        if cfg.ffn == "swiglu" and cfg.n_experts == 0:
-            blk["gate"] = _dense_init(rng, d, cfg.ffn_dim, dt)
-        if cfg.n_experts > 0:
+            blk["proj"] = _dense_init(rng, d, d, dt)
+            if cfg.gqa:  # separate q and (smaller) fused kv projections
+                blk["q"] = _dense_init(rng, d, d, dt)
+                blk["kv"] = _dense_init(
+                    rng, d, 2 * cfg.kv_heads * cfg.head_dim, dt)
+            else:
+                blk["qkv"] = _dense_init(rng, d, 3 * d, dt)
+        if cfg.routed_layer(i):
+            e, ff = cfg.n_routed_experts, cfg.expert_d_ff
+            blk["experts"] = {
+                "router": rng.normal(0.0, 1.0 / np.sqrt(d), (d, e)).astype(dt),
+                "route_bias": np.zeros((e,), np.float32),
+                "gate": rng.normal(0.0, 1.0 / np.sqrt(d), (e, d, ff)).astype(dt),
+                "up": rng.normal(0.0, 1.0 / np.sqrt(d), (e, d, ff)).astype(dt),
+                "down": rng.normal(0.0, 1.0 / np.sqrt(ff), (e, ff, d)).astype(dt),
+            }
+            if cfg.n_shared_experts:
+                sff = cfg.n_shared_experts * ff
+                blk["shared"] = {"gate": _dense_init(rng, d, sff, dt),
+                                 "up": _dense_init(rng, d, sff, dt),
+                                 "down": _dense_init(rng, sff, d, dt)}
+        elif cfg.n_experts > 0:
             e, ff = cfg.n_experts, cfg.ffn_dim
             blk["moe"] = {
                 "gate": rng.normal(0.0, 0.02, (d, e)).astype(dt),
@@ -250,6 +326,8 @@ def init(cfg: TransformerConfig, seed: int = 0):
                 "bo": np.zeros((e, d), dt),
             }
         else:
+            if cfg.ffn == "swiglu":
+                blk["gate"] = _dense_init(rng, d, cfg.ffn_dim, dt)
             blk["up"] = _dense_init(rng, d, cfg.ffn_dim, dt)
             blk["down"] = _dense_init(rng, cfg.ffn_dim, d, dt)
         blocks.append(blk)
@@ -264,7 +342,11 @@ def init(cfg: TransformerConfig, seed: int = 0):
     return out
 
 
-_NORM_KEYS = {"ln1", "ln2", "ln_f"}
+# leaves `cast_params` leaves in the master dtype: the norms (`kv_norm`
+# is the latent row's RMSNorm) and the routed layer's selection bias,
+# which only ever meets float32 scores
+_NORM_KEYS = {"ln1", "ln2", "ln_f", "kv_norm"}
+_MASTER_KEYS = _NORM_KEYS | {"route_bias"}
 
 # Quantized weight-storage leaves (see `quantize_weights`): "Wq" is the
 # int8/fp8 value tensor, "Ws" the per-out-channel f32 scales. Both stay
@@ -369,7 +451,7 @@ def cast_params(params, compute_dtype):
 
     def cast(path, p):
         keys = {getattr(k, "key", None) for k in path}
-        if keys & _NORM_KEYS or keys & _QUANT_KEYS:
+        if keys & _MASTER_KEYS or keys & _QUANT_KEYS:
             return p
         return (p.astype(compute_dtype)
                 if jnp.issubdtype(p.dtype, jnp.floating) else p)
@@ -574,6 +656,26 @@ def _qkv(p, h, cfg: TransformerConfig):
     return q, k, v
 
 
+def latent_qkv(p, h, cfg: TransformerConfig, rotate):
+    """A latent block's projections of the norm output h (B, T, d):
+    (q_nope (B,T,H,dn), q_rope (B,T,H,dr), c (B,T,r), k_rope (B,T,dr)).
+    `c` is the joint down-projection after its RMSNorm and `k_rope` the
+    one rotary key every head shares, both as the serving cache stores
+    them; `rotate(x (B,T,heads,dr))` applies the rotary phases at the
+    caller's positions (a sequence's, or one per decode row)."""
+    b, t, _ = h.shape
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = _dense(p["q"], h, cfg.fp8_dense).reshape(b, t, cfg.n_heads, -1)
+    kva = _dense(p["kv_a"], h, cfg.fp8_dense)
+    c = _rmsnorm(p["kv_norm"], kva[..., :r])
+    k_rope = rotate(kva[..., None, r:])[:, :, 0]
+    return q[..., :dn], rotate(q[..., dn:]), c, k_rope
+
+
+def latent_scale(cfg: TransformerConfig) -> float:
+    return float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
 def repeat_kv(x, cfg: TransformerConfig):
     """Broadcast K/V heads to the full query-head count (no-op for MHA).
 
@@ -600,25 +702,50 @@ def _supports_prob_dropout(fn) -> bool:
     return bool(getattr(fn, "supports_prob_dropout", False))
 
 
+def _swiglu(p, h, fp8: bool = False):
+    return _dense(p["down"], jax.nn.silu(_dense(p["gate"], h, fp8))
+                  * _dense(p["up"], h, fp8), fp8)
+
+
+def routed_ffn(p, h, cfg: TransformerConfig):
+    """A routed block's FFN on the norm output `h` (..., d): the chosen
+    experts' weighted sum plus the shared expert, and each token's
+    chosen experts (..., K) for whoever counts them (the serving
+    engine's `experts_touched`). No token and no assignment is dropped."""
+    lead = h.shape[:-1]
+    y, idx = routed_experts_ffn(p["experts"], h.reshape(-1, h.shape[-1]),
+                                cfg.moe_top_k, cfg.routed_scaling_factor)
+    y = y.reshape(h.shape)
+    if "shared" in p:
+        y = y + _swiglu(p["shared"], h)
+    return y, idx.reshape(*lead, cfg.moe_top_k)
+
+
 def _ffn(p, x, cfg: TransformerConfig, h, key=None):
-    """Post-attention half of a block: FFN (dense GELU, SwiGLU, or routed
-    MoE) on the norm output `h`, dropout, residual onto `x`.
+    """Post-attention half of a block: FFN (dense GELU, SwiGLU, capacity-
+    routed MoE or dropless routed experts, by what the block's params
+    hold) on the norm output `h`, dropout, residual onto `x`.
     Returns (x, (balance aux, router z-loss)) — both unweighted; `loss`
     owns the weights (so a z-loss-only or balance-only config needs no
     coupling between the two)."""
+    if "experts" in p:
+        y, idx = routed_ffn(p, h, cfg)
+        e = cfg.n_routed_experts
+        load = jax.nn.one_hot(idx, e, dtype=jnp.float32).reshape(-1, e)
+        return (x + _dropout(y, cfg.dropout, key),
+                (0.0, 0.0, {"load": load.mean(0),
+                            "drop_fraction": jnp.float32(0.0)}))
     if "moe" in p:
         y, aux, z, st = moe_ffn(p["moe"], h, cfg.moe_top_k,
                                 cfg.moe_capacity_factor,
                                 priority=cfg.moe_routing == "priority")
         return x + _dropout(y, cfg.dropout, key), (aux, z, st)
     if "gate" in p:  # SwiGLU: silu(gate) * up, both column-parallel
-        u = jax.nn.silu(_dense(p["gate"], h, cfg.fp8_dense)) \
-            * _dense(p["up"], h, cfg.fp8_dense)
+        y = _swiglu(p, h, cfg.fp8_dense)
     else:
-        u = jax.nn.gelu(_dense(p["up"], h, cfg.fp8_dense))
-    return (x + _dropout(_dense(p["down"], u, cfg.fp8_dense),
-                         cfg.dropout, key),
-            (0.0, 0.0, None))
+        y = _dense(p["down"], jax.nn.gelu(_dense(p["up"], h, cfg.fp8_dense)),
+                   cfg.fp8_dense)
+    return x + _dropout(y, cfg.dropout, key), (0.0, 0.0, None)
 
 
 def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
@@ -640,6 +767,22 @@ def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
     elif key is not None and cfg.attn_dropout > 0.0:
         k_prob = key
     h = _norm(p["ln1"], x, cfg)
+    if "kv_a" in p:
+        # latent attention without a cache: the expanded form over the
+        # sequence's own rows, causal (the plain reading of the
+        # equations; the serving engine reads its cache absorbed)
+        assert not with_kv and pos is not None, (
+            "a latent block caches its latent row through the serving "
+            "engine's paged pool, not through generate()'s K/V cache")
+        qn, qr, c, kr = latent_qkv(
+            p, h, cfg, lambda u: rope_rotate(u, pos, cfg.rope_theta))
+        causal = jnp.tril(jnp.ones((t, t), bool))[None, None]
+        a = latent_attention(qn, qr, c, kr, p["kv_b"], causal,
+                             latent_scale(cfg))
+        a = _checkpoint_name(a.reshape(b, t, -1), "attn_out")
+        x = x + _dropout(_dense(p["proj"], a, cfg.fp8_dense),
+                         cfg.dropout, k_attn)
+        return _ffn(p, x, cfg, _norm(p["ln2"], x, cfg), k_ffn)
     # head-major fused layout (H, 3, D): a contiguous slice of the 3d output
     # dim is a whole group of heads, so tensor-parallel column sharding of
     # qkv["W"] keeps attention fully local to each device (Megatron

@@ -26,6 +26,12 @@ host-side branching without ever corrupting a live block.
 int8 pools mirror the contiguous int8 cache exactly (same per-(row,
 head, position) absmax scales via `kv_cache.quantize_kv`), so the paged
 sweep halves its bytes the same way.
+
+A layer's pool is of the layer's kind (`init_block_pool`): K and V
+heads, or for a latent-attention layer ONE row a token that all heads
+share. The kinds share the block ids, so allocator, tables, prefix
+index and copy-on-write are the same for both; only what a write stores
+(`_kv_update`) and how the engine reads the gathered table differ.
 """
 
 from __future__ import annotations
@@ -70,13 +76,35 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
     return max(0, -(-int(n_tokens) // int(block_size)))
 
 
+LATENT = "ckr"      # a latent layer's one leaf: [c | k_rope | 0] a token
+LANES = 128
+
+
+def pool_block_size(pool_blk) -> int:
+    """Positions a block holds, whatever kind of pool it is."""
+    return next(iter(pool_blk.values())).shape[2]
+
+
 def init_block_pool(cfg: T.TransformerConfig, n_blocks: int,
                     block_size: int, kv_quant: str = ""):
-    """Per-layer paged K/V pools (n_blocks, Hkv, block_size, hd),
-    zero-filled; int8 pools add the (n_blocks, Hkv, block_size, 1) f32
-    scale planes, matching `init_kv_cache`'s int8 variant per-position.
-    Layout is the contiguous cache's head-major sweep with the slot
-    axis folded into (block id, offset)."""
+    """Per-layer paged pools, zero-filled, each of its layer's kind.
+
+    A K/V layer holds (n_blocks, Hkv, block_size, hd) for `k` and `v`;
+    int8 pools add the (n_blocks, Hkv, block_size, 1) f32 scale planes,
+    matching `init_kv_cache`'s int8 variant per-position. Layout is the
+    contiguous cache's head-major sweep with the slot axis folded into
+    (block id, offset). A latent layer (`cfg.latent`) holds ONE row a
+    token for all heads, (n_blocks, 1, block_size, r + dr rounded up to
+    whole lanes): the normed down-projection and the rotated shared key
+    side by side, which is how the absorbed read wants them (one
+    contraction), then zeros. The rounding is not optional on the TPU:
+    for a minor dimension of 576 its compact layout puts the BLOCK
+    dimension minor-most, and every program then copies the whole pool
+    in and out around its scatter (compiled for the described v5e, PR
+    28: two pool-sized copies a layer at 576, none at 512 or 640; the
+    tiled layout pads 576 to 640 lanes either way).
+    Every kind shares the block ids: one allocator, one table a
+    request."""
     if kv_quant not in KV_QUANT_MODES:
         raise ValueError(
             f"unsupported kv_quant={kv_quant!r}; expected one of "
@@ -85,6 +113,13 @@ def init_block_pool(cfg: T.TransformerConfig, n_blocks: int,
         raise ValueError(f"n_blocks={n_blocks} leaves no usable blocks "
                          f"past the reserved scratch block")
     dt = cfg.compute_dtype or cfg.dtype
+    if cfg.latent:
+        if kv_quant:
+            raise ValueError("a latent pool has no int8 form: kv_quant "
+                             "must be '' with latent attention")
+        shape = (n_blocks, 1, block_size,
+                 -(-cfg.latent_width // LANES) * LANES)
+        return [{LATENT: jnp.zeros(shape, dt)} for _ in range(cfg.n_layers)]
     shape = (n_blocks, cfg.kv_heads, block_size, cfg.head_dim)
     if kv_quant:
         sshape = shape[:3] + (1,)
@@ -340,7 +375,14 @@ def _kv_update(pool_blk, k_rows, v_rows, quant: bool):
     """{leaf name: (rows, Hkv, tail)} — the values a write stores, in
     the pool's own dtypes. Quantization matches
     `kv_cache.cache_write`'s int8 path value-for-value (same
-    absmax-over-hd scales)."""
+    absmax-over-hd scales). A latent pool stores its two halves, c
+    (rows, 1, r) as `k_rows` and the shared rotary key (rows, 1, dr) as
+    `v_rows`, side by side in its one leaf, zeros after them."""
+    if LATENT in pool_blk:
+        row = jnp.concatenate([k_rows, v_rows], axis=-1)
+        pad = pool_blk[LATENT].shape[-1] - row.shape[-1]
+        return {LATENT: jnp.pad(row, ((0, 0), (0, 0), (0, pad))
+                                ).astype(pool_blk[LATENT].dtype)}
     if quant:
         kq, ks = quantize_kv(k_rows[:, :, None, :])   # (rows,Hkv,1,hd)
         vq, vs = quantize_kv(v_rows[:, :, None, :])
@@ -353,7 +395,8 @@ def _kv_update(pool_blk, k_rows, v_rows, quant: bool):
 def write_rows(pool_blk, k_rows, v_rows, blk_ids, offs, quant: bool):
     """Scatter per-row single-token K/V into one layer's pools.
 
-    k_rows/v_rows: (rows, Hkv, hd) in compute dtype; blk_ids/offs:
+    k_rows/v_rows: (rows, Hkv, hd) in compute dtype (a latent pool: c
+    and the rotary key, `_kv_update`); blk_ids/offs:
     (rows,) int32 destination (block id, in-block offset). Rows steered
     to the scratch block may collide — by construction nothing ever
     reads scratch, so the unspecified duplicate-scatter winner is
@@ -372,7 +415,7 @@ def write_rows(pool_blk, k_rows, v_rows, blk_ids, offs, quant: bool):
     (N, Hkv, bs, 1) scale planes of int8 pools, toy shapes) XLA
     relayouts that leaf as it did before, and nothing large rides on
     it."""
-    n, hkv, bs, _ = pool_blk["k"].shape
+    n, hkv, bs, _ = next(iter(pool_blk.values())).shape
     row = ((blk_ids[:, None] * hkv + jnp.arange(hkv)) * bs
            + offs[:, None]).reshape(-1)               # (rows * Hkv,)
     out = {}
@@ -404,7 +447,7 @@ def write_chunk(pool_blk, k_rows, v_rows, table, pos0, n_tok,
     own contents back. Decode cannot use this form: draft rows of one
     request share a block (duplicate indices, different contents)."""
     c = k_rows.shape[0]
-    bs = pool_blk["k"].shape[2]
+    bs = pool_block_size(pool_blk)
     tb = pos0 // bs + jnp.arange((c + 2 * bs - 2) // bs)   # table slots
     ids = jnp.where(tb * bs < pos0 + n_tok,
                     table[jnp.clip(tb, 0, table.shape[0] - 1)],
@@ -478,7 +521,9 @@ def paged_read_bytes_per_tick(params, cfg: T.TransformerConfig,
         p_bytes = param_read_bytes(params, cfg)
     kv_itemsize = (1 if kv_quant == "int8"
                    else np.dtype(cfg.compute_dtype or cfg.dtype).itemsize)
-    per_block = 2 * cfg.kv_heads * block_size * cfg.head_dim * kv_itemsize
+    per_token = (cfg.latent_width if cfg.latent
+                 else 2 * cfg.kv_heads * cfg.head_dim)
+    per_block = block_size * per_token * kv_itemsize
     if kv_quant == "int8":
         per_block += 2 * cfg.kv_heads * block_size * 4   # f32 scales
     return (p_bytes + cfg.n_layers * int(blocks_touched) * per_block

@@ -106,12 +106,14 @@ from shallowspeed_tpu.telemetry.trace import tracer
 from shallowspeed_tpu.telemetry.tracing import new_span_id, new_trace_id
 from shallowspeed_tpu.models import transformer as T
 from shallowspeed_tpu.models.kv_cache import masked_attention
-from shallowspeed_tpu.serving.cache import (SCRATCH_BLOCK, BlockAllocator,
-                                            OutOfBlocks, PrefixIndex,
-                                            blocks_for, gather_table,
-                                            init_block_pool,
+from shallowspeed_tpu.ops.latent_attention import latent_attention_absorbed
+from shallowspeed_tpu.serving.cache import (LATENT, SCRATCH_BLOCK,
+                                            BlockAllocator, OutOfBlocks,
+                                            PrefixIndex, blocks_for,
+                                            gather_table, init_block_pool,
                                             paged_read_bytes_per_tick,
-                                            param_read_bytes, write_chunk,
+                                            param_read_bytes,
+                                            pool_block_size, write_chunk,
                                             write_rows)
 
 
@@ -184,6 +186,29 @@ def _sample_rows(logits, temp, seeds, idx, top_k: int, top_p: float):
 _sample_jit = jax.jit(_sample_rows, static_argnames=("top_k", "top_p"))
 
 
+def _latent_read(p, pool, bt, q_nope, q_rope, valid, cfg):
+    """A latent layer's attention over its gathered table, absorbed:
+    the (rows, W, 1, bs, r + dr) pages ARE (rows, W*bs, r + dr) latent
+    rows in position order (one shared "head": nothing to make
+    head-major), and every query head contracts with them as stored."""
+    g = pool[LATENT][bt]
+    rows = g.reshape(g.shape[0], -1, g.shape[-1])
+    return latent_attention_absorbed(q_nope, q_rope, rows, p["kv_b"],
+                                     valid, T.latent_scale(cfg))
+
+
+def _ffn_counted(p, x, cfg, h, live):
+    """`T._ffn` for the tick and the chunk. A routed block also gives
+    the (E,) int32 count of the assignments its LIVE rows made (`live`
+    has h's leading shape; padding rows and empty slots run the layer
+    too, their choices are not the traffic's); else None."""
+    if "experts" not in p:
+        return T._ffn(p, x, cfg, h)[0], None
+    y, idx = T.routed_ffn(p, h, cfg)
+    hot = jax.nn.one_hot(idx, cfg.n_routed_experts, dtype=jnp.int32)
+    return x + y, (hot * live[..., None, None]).sum((0, 1, 2))
+
+
 @partial(jax.jit, static_argnames=("cfg", "top_k", "top_p", "attn"),
          donate_argnums=(1,))
 def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
@@ -197,7 +222,12 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
     token's K/V at (bt[pos // bs], pos % bs) and attends over its
     gathered table under the position mask; inactive slots carry
     pos=0 / bt=scratch and their results are ignored host-side.
-    Returns (next token per slot, updated pools). The pools are
+    A latent layer writes its one latent row instead and reads the
+    table absorbed (`_latent_read`); which a layer is follows from its
+    params and its pool, not from an option.
+    Returns (next token per slot, updated pools, and the routed layers'
+    (layers, E) int32 assignment counts of the live rows, None for a
+    model without routed layers). The pools are
     DONATED and every write to them is indexed on the leading
     dimension alone (`write_rows`' flat view here, whole blocks in
     `_prefill_chunk`), which is what lets XLA:TPU update the donated
@@ -215,7 +245,7 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
     rows i < j of the same tick — the single-pass verify."""
     params = T.cast_params(params, cfg.compute_dtype)
     s_rows = tok.shape[0]
-    bs = pools[0]["k"].shape[2]
+    bs = pool_block_size(pools[0])
     w = bt.shape[1]
     quant = "k_s" in pools[0]
     x = params["tok_emb"][tok][:, None, :]                  # (S, 1, d)
@@ -226,35 +256,43 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
     rows = jnp.arange(s_rows)
     blk = bt[rows, pos // bs]
     off = pos % bs
+    live = (bt[:, 0] != SCRATCH_BLOCK)[:, None]             # (S, 1)
+    rope = lambda u: _rope_rows(u, pos, cfg.rope_theta)
     if attn != "flash":
         span = jnp.arange(w * bs)
         valid = span[None, :] <= pos[:, None]               # (S, W*bs)
         if cfg.attn_window > 0:
             valid = valid & (span[None, :]
                              > pos[:, None] - cfg.attn_window)
-        valid = valid[:, None, None, None, :]
-    new_pools = []
+    new_pools, counts = [], []
     for p, pool in zip(params["blocks"], pools):
         h = T._norm(p["ln1"], x, cfg)
-        q, k, v = T._qkv(p, h, cfg)
-        if cfg.rope:
-            q = _rope_rows(q, pos, cfg.rope_theta)
-            k = _rope_rows(k, pos, cfg.rope_theta)
-        pool = {**pool, **write_rows(pool, k[:, 0], v[:, 0], blk, off,
-                                     quant)}
-        if attn == "flash":
-            a = paged_flash_decode(q[:, 0], pool, bt, pos,
-                                   window=cfg.attn_window)
+        if LATENT in pool:
+            qn, qr, c, kr = T.latent_qkv(p, h, cfg, rope)
+            pool = write_rows(pool, c, kr, blk, off, False)
+            a = _latent_read(p, pool, bt, qn, qr, valid[:, None, None, :],
+                             cfg)
         else:
-            a = masked_attention(q, gather_table(pool, bt), valid, cfg)
-        x = x + T._dense(p["proj"], a.reshape(s_rows, 1, cfg.d_model))
-        h = T._norm(p["ln2"], x, cfg)
-        x, _aux = T._ffn(p, x, cfg, h)
+            q, k, v = T._qkv(p, h, cfg)
+            if cfg.rope:
+                q, k = rope(q), rope(k)
+            pool = {**pool, **write_rows(pool, k[:, 0], v[:, 0], blk, off,
+                                         quant)}
+            if attn == "flash":
+                a = paged_flash_decode(q[:, 0], pool, bt, pos,
+                                       window=cfg.attn_window)
+            else:
+                a = masked_attention(q, gather_table(pool, bt),
+                                     valid[:, None, None, None, :], cfg)
+        x = x + T._dense(p["proj"], a.reshape(s_rows, 1, -1))
+        x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
+        if n is not None:
+            counts.append(n)
         new_pools.append(pool)
     x = T._norm(params["ln_f"], x, cfg)
     logits = T.head_logits(params, x[:, 0], cfg).astype(jnp.float32)
     nxt = _sample_rows(logits, temp, seeds, idx, top_k, top_p)
-    return nxt, new_pools
+    return nxt, new_pools, jnp.stack(counts) if counts else None
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -267,8 +305,9 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     few blocks its consecutive positions touch, merged and written
     back whole, in place) and attends causally over the table
     (earlier chunks included). Returns (f32 logits at the chunk's last
-    true position — consumed only on the final chunk — and the
-    updated, donated pools).
+    true position — consumed only on the final chunk — the updated,
+    donated pools, and the routed layers' assignment counts of the
+    chunk's true rows as `_decode_tick` gives them).
 
     PREFIX-CACHE ALIGNMENT CONTRACT: cache hits are granular to WHOLE
     blocks — `pos0` on a hit is the matched aligned token count, so the
@@ -286,7 +325,7 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     change block-table *data*, never the compiled-program set."""
     params = T.cast_params(params, cfg.compute_dtype)
     c = tokens.shape[1]
-    bs = pools[0]["k"].shape[2]
+    bs = pool_block_size(pools[0])
     w = bt.shape[1]
     quant = "k_s" in pools[0]
     pools = [{name: leaf.at[cow_dst].set(leaf[cow_src])
@@ -297,25 +336,33 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     valid = span[None, :] <= pos[:, None]                   # (C, W*bs)
     if cfg.attn_window > 0:
         valid = valid & (span[None, :] > pos[:, None] - cfg.attn_window)
-    valid = valid[None, None, None, :, :]
-    new_pools = []
+    live = (jnp.arange(c) < n_tok)[None, :]                 # (1, C)
+    rope = lambda u: T.rope_rotate(u, pos, cfg.rope_theta)
+    new_pools, counts = [], []
     for p, pool in zip(params["blocks"], pools):
         h = T._norm(p["ln1"], x, cfg)
-        q, k, v = T._qkv(p, h, cfg)
-        if cfg.rope:
-            q = T.rope_rotate(q, pos, cfg.rope_theta)
-            k = T.rope_rotate(k, pos, cfg.rope_theta)
-        pool = {**pool, **write_chunk(pool, k[0], v[0], bt[0], pos0,
-                                      n_tok, quant)}
-        a = masked_attention(q, gather_table(pool, bt), valid, cfg)
-        x = x + T._dense(p["proj"], a.reshape(1, c, cfg.d_model))
-        h = T._norm(p["ln2"], x, cfg)
-        x, _aux = T._ffn(p, x, cfg, h)
+        if LATENT in pool:
+            qn, qr, lat, kr = T.latent_qkv(p, h, cfg, rope)
+            pool = write_chunk(pool, lat[0][:, None], kr[0][:, None],
+                               bt[0], pos0, n_tok, False)
+            a = _latent_read(p, pool, bt, qn, qr, valid[None, None], cfg)
+        else:
+            q, k, v = T._qkv(p, h, cfg)
+            if cfg.rope:
+                q, k = rope(q), rope(k)
+            pool = {**pool, **write_chunk(pool, k[0], v[0], bt[0], pos0,
+                                          n_tok, quant)}
+            a = masked_attention(q, gather_table(pool, bt),
+                                 valid[None, None, None], cfg)
+        x = x + T._dense(p["proj"], a.reshape(1, c, -1))
+        x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
+        if n is not None:
+            counts.append(n)
         new_pools.append(pool)
     x = T._norm(params["ln_f"], x, cfg)
     x_last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 1, False)
     logits = T.head_logits(params, x_last, cfg).astype(jnp.float32)
-    return logits, new_pools
+    return logits, new_pools, jnp.stack(counts) if counts else None
 
 
 class _Req:
@@ -402,6 +449,10 @@ class ServingEngine:
                 f"unsupported attn_impl={attn_impl!r}; expected "
                 f"'gather' (the XLA reference) or 'flash' (the paged "
                 f"Pallas decode kernel)")
+        if cfg.latent and attn_impl == "flash":
+            raise ValueError(
+                "attn_impl='flash' reads K/V pools; a latent cache is "
+                "read through the gathered table (attn_impl='gather')")
         # quantize ONCE at init (host-side, idempotent): every tick
         # then reads 1-byte weights through the fused-dequant matmul
         self.params = T.quantize_weights(params, weight_quant)
@@ -461,7 +512,15 @@ class ServingEngine:
                          "shed_toggles": 0, "spec_drafted": 0,
                          "spec_accepted": 0, "prefix_lookups": 0,
                          "prefix_hits": 0, "prefix_skipped_tokens": 0,
-                         "oom_events": 0}
+                         "oom_events": 0,
+                         # sums over the decode ticks (divide by the
+                         # ticks between two readings): distinct experts
+                         # a routed layer's live rows chose, its largest
+                         # expert's count over the mean count, latent
+                         # cache rows read. 0 where the model has no
+                         # such layer.
+                         "experts_touched": 0.0, "max_load": 0.0,
+                         "latent_tokens": 0}
         # OOM forensics (round 20, the memory observatory): every
         # RECOVERED OutOfBlocks stamps a typed `oom` ledger line and
         # notifies these listeners with (engine, exc) — serve.py wires
@@ -911,11 +970,27 @@ class ServingEngine:
         req = min(pre, key=lambda r: r.admit_seq)     # FIFO
         tr = tracer()
         with tr.span("prefill", rid=req.rid,
-                     chunk=req.written // self.prefill_chunk):
-            self._prefill_chunk_of(req, tr)
+                     chunk=req.written // self.prefill_chunk) as sp:
+            self._prefill_chunk_of(req, tr, sp)
         return True
 
-    def _prefill_chunk_of(self, req, tr) -> None:
+    def _layer_attrs(self, counts, rows_read: int) -> dict:
+        """What a program run's span says of the layers that differ by
+        kind: `latent_tokens` (cache rows its latent layers each read),
+        and from the routed layers' (layers, E) assignment counts
+        `experts_touched` (distinct experts chosen, mean a layer) and
+        `max_load` (the busiest expert's count over the mean count,
+        mean a layer). `counts` arrived with the sampled tokens."""
+        attrs = {}
+        if self.cfg.latent:
+            attrs["latent_tokens"] = int(rows_read)
+        if counts is not None:
+            mean = np.maximum(counts.sum(-1), 1) / counts.shape[-1]
+            attrs["experts_touched"] = float((counts > 0).sum(-1).mean())
+            attrs["max_load"] = float((counts.max(-1) / mean).mean())
+        return attrs
+
+    def _prefill_chunk_of(self, req, tr, sp) -> None:
         c = self.prefill_chunk
         n_tok = min(c, len(req.ctx) - req.written)
         self._lifecycle(req, "prefill", chunk=req.written // c,
@@ -930,7 +1005,7 @@ class ServingEngine:
         cow = req.cow if req.cow is not None \
             else (SCRATCH_BLOCK, SCRATCH_BLOCK)
         with tr.span("prefill.dispatch"):
-            logits, self.pools = _prefill_chunk(
+            logits, self.pools, counts = _prefill_chunk(
                 self.params, self.pools, tokens, np.int32(req.written),
                 np.int32(n_tok), bt, np.int32(cow[0]), np.int32(cow[1]),
                 cfg=self.cfg)
@@ -954,7 +1029,9 @@ class ServingEngine:
                     np.asarray([len(req.generated)], np.int32),
                     top_k=self.top_k, top_p=self.top_p)
             with tr.span("prefill.fetch"):
-                tok = int(np.asarray(tok)[0])
+                tok, counts = jax.device_get((tok, counts))
+                tok = int(tok[0])
+            sp.set(**self._layer_attrs(counts, req.written))
             req.phase = "decode"
             self._lifecycle(req, "decoding")
             self._append_token(req, tok)
@@ -972,12 +1049,19 @@ class ServingEngine:
             actives, drafts, rows = prep
             sp.set(n_active=len(actives), width=rows[2].shape[1])
             with tr.span("decode.dispatch"):
-                nxt, self.pools = _decode_tick(
+                nxt, self.pools, counts = _decode_tick(
                     self.params, self.pools, *rows, cfg=self.cfg,
                     top_k=self.top_k, top_p=self.top_p,
                     attn=self.attn_impl)
             with tr.span("decode.fetch"):
-                nxt = np.asarray(nxt)
+                # one wait for both: the counts leave the device beside
+                # the tokens, not in a second round trip after them
+                nxt, counts = jax.device_get((nxt, counts))
+            attrs = self._layer_attrs(
+                counts, sum(r.written + 1 for r in actives))
+            sp.set(**attrs)
+            for name, value in attrs.items():
+                self.counters[name] += value
             with tr.span("decode.emit"):
                 self._decode_emit(actives, drafts, nxt)
         return True

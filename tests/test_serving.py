@@ -164,9 +164,9 @@ def test_prefill_chunk_logits_match_contiguous_prefill(params):
     tokens[0, :14] = prompt
     bt = np.zeros((1, table_width(len(table), 4)), np.int32)
     bt[0, :len(table)] = table
-    logits, pools = _prefill_chunk(params, pools, tokens, np.int32(0),
-                                   np.int32(14), bt, np.int32(0),
-                                   np.int32(0), cfg=CFG)
+    logits, pools, _ = _prefill_chunk(params, pools, tokens, np.int32(0),
+                                      np.int32(14), bt, np.int32(0),
+                                      np.int32(0), cfg=CFG)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
 
@@ -1077,9 +1077,9 @@ def test_prefill_chunk_diverging_in_tail_leaves_shared_block_bit_unchanged(
     prefix, tail, own = 3, 6, 9            # shared, shared, this request's
     bt = jnp.asarray([[prefix, own, 0, 0]], jnp.int32)
     tokens = jnp.asarray(toks(3, t=c)[None, :])
-    _, after = _prefill_chunk(params, pools, tokens, jnp.int32(2 * bs - 1),
-                              jnp.int32(1), bt, jnp.int32(tail),
-                              jnp.int32(own), cfg=CFG)
+    _, after, _ = _prefill_chunk(params, pools, tokens,
+                                 jnp.int32(2 * bs - 1), jnp.int32(1), bt,
+                                 jnp.int32(tail), jnp.int32(own), cfg=CFG)
     for b, a in zip(before, after):
         for n in b:
             got = np.asarray(a[n])
