@@ -444,7 +444,7 @@ PAGED_DECODE_CASES = (("paged_decode", 0, False, 0),
                       ("paged_decode_int8", 0, True, 0))
 
 
-def paged_decode_errs(d_model=256, n_heads=4, cases=PAGED_DECODE_CASES,
+def paged_decode_errs(d_model=512, n_heads=4, cases=PAGED_DECODE_CASES,
                       bs=16, dtype=None) -> dict:
     """Paged flash-decode kernel vs its XLA reference
     (`serving/cache.gather_table` + `kv_cache.masked_attention`), one
@@ -453,7 +453,9 @@ def paged_decode_errs(d_model=256, n_heads=4, cases=PAGED_DECODE_CASES,
     `kernel_numerics_errs`: the chip runs f32 matmuls as bf16 passes by
     default, so the kernel ("flash") and the reference as the server
     runs it ("xla_floor") are both measured against the reference at
-    highest matmul precision. RAISES on any kernel failure."""
+    highest matmul precision. RAISES on any kernel failure. Heads are
+    128 wide by default: compiled, the kernel's slab DMA addresses rows
+    of whole lanes only (`flash_attention.paged_decode_addresses`)."""
     import jax
     import jax.numpy as jnp
 

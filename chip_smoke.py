@@ -15,9 +15,10 @@ AdamW), with seeded random weights and seeded synthetic data:
   mixed-length requests, then that wave plus a second wave of the same
   shapes arriving mid-run: every request answered with exactly
   `max_new` tokens, no error line, the allocator balanced at drain, the
-  executable counts identical between the two runs, the flash ticks
-  carrying the Mosaic call; gather-vs-flash token agreement is
-  *reported* (f32 matmuls run as bf16 passes on the chip);
+  executable counts identical between the two runs, every decode tick
+  carrying the Mosaic call (the tick reads its pools through the paged
+  kernel whatever `--attn-impl` says; the two names are the same
+  programs since PR 29, and their token agreement is still *reported*);
 - **kernels**: every Pallas entry point, compiled, against its XLA
   reference, at this table's shapes and at the ones `bench.py` lists;
 - with four or more devices, **train** again as `--dp 4` and as
@@ -399,7 +400,7 @@ def run_serve_phase(size: str, out: Path, *, config: str, seed: int = 21,
         bad = child_failure(run, console) or check_serve(
             events, reqs, platform=platform)
         mosaic = mosaic_programs(ir_dir)
-        if (not bad and platform == "tpu" and "flash" in config
+        if (not bad and platform == "tpu"
                 and not any("decode_tick" in n for n in mosaic)):
             bad.append(f"no lowered decode tick holds a {MOSAIC_CALL} "
                        f"(found it in: {mosaic or 'nothing'})")

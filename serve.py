@@ -86,11 +86,10 @@ def parse_args(argv=None):
                         "param sweep behind every decode tick)")
     s.add_argument("--attn-impl", default="gather",
                    choices=["gather", "flash"],
-                   help="decode-tick attention: 'gather' = the XLA "
-                        "reference (gather_table + masked_attention), "
-                        "'flash' = the paged Pallas flash-decode "
-                        "kernel (grid over the block table, no "
-                        "gathered copy)")
+                   help="selects nothing: the decode tick always reads "
+                        "its pools through the paged Pallas kernel and "
+                        "the prefill chunk through the gathered table; "
+                        "kept because callers still pass it")
     s.add_argument("--spec-k", type=int, default=0,
                    help="speculative decoding: up to K self-drafted "
                         "(n-gram prompt-lookup) tokens per decoding "

@@ -428,8 +428,7 @@ def build_serving_decode(budget: int = DEFAULT_BUDGET) -> TargetProbe:
 
     def tick(params, pools, tok, pos, bt, temp, seeds, idx):
         return _decode_tick(params, pools, tok, pos, bt, temp, seeds,
-                            idx, cfg=cfg, top_k=0, top_p=0.0,
-                            attn="flash")
+                            idx, cfg=cfg, top_k=0, top_p=0.0)
 
     s = eng.max_slots
     w = 4

@@ -117,10 +117,12 @@ def masked_attention(q, cache_blk, valid, cfg):
     paged and contiguous logits can only differ by gather/fp-reorder
     noise (pinned to 1e-4 in tests/test_serving.py).
 
-    A single query per row (the decode tick) contracts over the pages
-    as gathered: making the table head-major first would copy all of
-    it, per layer, for one use. A chunk of queries (prefill) folds its
-    row's pages into one head-major page first.
+    A single query per row contracts over the pages as gathered
+    (making the table head-major first would copy all of it for one
+    use): the form the serving tick ran until PR 29 and the reference
+    of the kernel that took its place
+    (`ops.flash_attention.paged_flash_decode`). A chunk of queries
+    (prefill) folds its row's pages into one head-major page first.
 
     GQA caches hold Hkv heads and are read UNREPEATED (grouped einsum):
     decode is HBM-bandwidth-bound on the cache sweep, so the group

@@ -920,176 +920,270 @@ ring_flash_attention.defvjp(_ring_fwd_rule, _ring_bwd_rule)
 
 # ------------------------------------------------------ paged flash decode
 #
-# Single-query attention for the serving runtime's paged KV cache
-# (round 14, ROADMAP item 1). The XLA reference path
-# (`serving/cache.gather_table` + `kv_cache.masked_attention`) first
-# MATERIALIZES each row's gathered table — a contiguous
-# (rows, Hkv, W*bs, hd) copy of every live block — and then attends
-# over it: the hot decode tick pays the cache sweep twice (gather
-# write + attention read). This kernel grids DIRECTLY over the block
-# table instead — grid (slot, kv head, table column), with the table
-# and each row's position as SCALAR-PREFETCH operands so the k/v
-# BlockSpec index maps dereference `bt[slot, col]` and DMA exactly the
-# pool block each program needs. The gather disappears from the hot
-# path; online-softmax scratch merges the per-block partials across
-# the innermost table-column axis (same (m, l, acc) carry as the
-# training kernels above).
+# Single-query attention for the serving runtime's paged cache: the
+# decode tick's read, for K/V pools and the latent pool alike
+# (`serving/engine._decode_tick`). The XLA reference
+# (`serving/cache.gather_table` + `kv_cache.masked_attention`, which
+# the prefill chunk still runs) first MATERIALIZES every row's table at
+# the bucket's width and then contracts one query over all of it, in
+# f32 on the VPU: the tick moved ~640 MB a layer for 19 MB of live
+# blocks (`olmo-1b.chat`, PERF.md PR 29). This kernel reads the pool
+# through the table instead. `bt` and `pos` are scalar-prefetch
+# operands, the pools stay in HBM, and ONE program walks the
+# rows in turn: row r reads blocks [first block of its window,
+# pos // bs] of its own table and nothing else, so a dead row (pos 0,
+# table all scratch) costs one block and bucket padding costs nothing,
+# while the compile key stays (rows, table width).
 #
-# int8 pools are read NATIVELY: the int8 k/v blocks and their f32
-# scale planes stream into VMEM as stored, K's per-position scale
-# multiplies the score row and V's folds into the probability row —
-# the same outside-the-dot placement as `masked_attention`, so HBM
-# reads stay 1 byte/element. Interpreted, the reference parity is
-# fp-reorder noise only (pinned <= 1e-4 in tests/test_serving.py).
-# Compiled, Mosaic runs the f32 dots below as one bf16 pass, so the
-# envelope is bf16 operand rounding: 0.002-0.004 relmax against the
-# reference on a v5e (chip run, PR 21; `bench.paged_decode_pass`).
+# A pool block is one contiguous (Hkv, bs, hd) slab for all its KV
+# heads and is the DMA's unit; `chunk` of them are in flight per
+# compute step, landing head-major in a double-buffered VMEM scratch
+# (Hkv, chunk, bs, hd), so a step is two batched matmuls over
+# chunk * bs positions and the NEXT step's slabs (the next row's first,
+# after a row's last) load under it. A grid step per (row, head, block)
+# cost 0.15-0.35 us each and was all overhead (PR 24: 65 against 37 ms).
+#
+# Tiles are read as stored: bf16 (or int8) meets the MXU with f32
+# accumulation, scores and the running softmax are f32 and live in
+# VMEM only, probabilities meet V in V's dtype: `masked_attention`'s
+# arithmetic. int8 pools keep their f32 scale planes outside the dots
+# (K's on the score row, V's folded into the probability row). A pool
+# of ONE leaf (the latent pool: Hkv 1, every query head a row of the
+# matmul) is read as keys and values both, one DMA serving both.
+# Interpreted, the reference parity is fp-reorder noise only (pinned
+# <= 1e-4 in tests/test_flash_attention.py).
 
 
-def _paged_decode_kernel(bt_ref, pos_ref, *refs, scale, bs, w, window,
-                         groups, quant):
-    """Grid (slot, kv head, table col). One program attends this
-    slot's query group against ONE pool block of its table; scratch
-    carries the online softmax across the sequential col axis. With
-    `quant`, the int8 k/v blocks arrive as stored and their f32 scale
-    planes ride as separate (bs, 1) operands — the DMA reads stay
-    1 byte/element."""
-    if quant:
-        (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    s = pl.program_id(0)
-    jw = pl.program_id(2)
-
-    @pl.when(jw == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    p = pos_ref[s]
-    base = jw * bs
-    # tiles whose whole block is masked (beyond this row's position, or
-    # before its window) skip compute AND their stats update; their DMA
-    # still lands — the table is data, so the grid cannot shrink per
-    # row — but scratch carries the merge past them unchanged
-    live = base <= p
-    if window > 0:
-        live = jnp.logical_and(live, base + bs - 1 > p - window)
-
-    @pl.when(live)
-    def _accum():
-        q = q_ref[0].astype(jnp.float32)                   # (G, hd)
-        kb = k_ref[0].astype(jnp.float32)                  # (bs, hd)
-        vb = v_ref[0].astype(jnp.float32)
-        if quant:
-            ks = ks_ref[0, :, 0].astype(jnp.float32)       # (bs,)
-            vs = vs_ref[0, :, 0].astype(jnp.float32)
-        sc = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)
-        if quant:
-            sc = sc * ks[None, :]
-        sc = sc * scale
-        col = base + jax.lax.broadcasted_iota(
-            jnp.int32, (groups, bs), 1)
-        valid = col <= p
-        if window > 0:
-            valid = valid & (col > p - window)
-        sc = jnp.where(valid, sc, _NEG)
-        m = m_scr[:, 0:1]
-        l = l_scr[:, 0:1]
-        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
-        pr = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + pr.sum(axis=-1, keepdims=True)
-        if quant:  # V's scale folds into the probability row (tiny),
-            #        keeping the V read int8 — masked_attention's rule;
-            #        the normalizer l is accumulated UNSCALED above
-            pr = pr * vs[None, :]
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            pr, vb, preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(jw == w - 1)
-    def _finalize():
-        l = l_scr[:, 0:1]
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+# slab DMAs issued (and waited for) a loop iteration. One an iteration
+# cost 60 ns a slab, twice a 20 KB slab's transfer: the latent read took
+# 368 us a layer so, 247 with a whole step of 32 written out, 262 at 4
+# (chip run, PR 29). Every descriptor written out is traced and lowered
+# on the host once a program, which no compilation cache holds: a step
+# of 48 written out at three places cost 1.7 s a tick program.
+_DMA_UNROLL = 4
 
 
+def _paged_decode_kernel(bt_ref, pos_ref, q_ref, *refs, scale, bs, chunk,
+                         window, n_pools, quant):
+    """One program for the whole slot batch. `refs`: the pool leaves in
+    HBM (keys and values, or the one leaf that is both), with `quant`
+    the two scale tables (`_scale_table`), the output, then one VMEM
+    buffer of two slots per operand ((2, Hkv, chunk, bs, hd) for a
+    leaf, (2, Hkv, 1, lanes) for a scale table) and a DMA
+    semaphore per slot."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_in = n_pools + 2 * quant
+    pools, scales, o_ref = refs[:n_pools], refs[n_pools:n_in], refs[n_in]
+    bufs, sbufs, sem = (refs[n_in + 1:n_in + 1 + n_pools],
+                        refs[n_in + 1 + n_pools:-1], refs[-1])
+    n_rows, hkv, g, _ = q_ref.shape
+    t = chunk * bs
+    unroll = min(chunk, _DMA_UNROLL)
+    # 16-bit operands multiply exactly into the f32 accumulator in one
+    # pass; a process-wide "highest" (the tests') would ask Mosaic for
+    # a multi-pass mode that only f32 operands have
+    prec = (None if q_ref.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+    def blocks_of(r):
+        """Row r's position and the table columns [lo, hi) it reads."""
+        p = pos_ref[r]
+        lo = jnp.maximum(p - (window - 1), 0) // bs if window > 0 else 0
+        if quant:   # a scale table is indexed by whole steps; what this
+            #         adds is masked like the window's first block
+            lo = lo // chunk * chunk
+        return p, lo, p // bs + 1
+
+    def dma(r, b0, hi, slot, start, unroll=unroll):
+        """Start (or wait for) the slabs of table columns [b0, b0 +
+        chunk) of row r that lie below `hi`, `unroll` to a loop
+        iteration, then one by one."""
+        def go(src, dst):
+            cp = pltpu.make_async_copy(src, dst, sem.at[slot])
+            cp.start() if start else cp.wait()
+
+        def one(i, _=None):
+            for pool, buf in zip(pools, bufs):
+                go(pool.at[bt_ref[r, b0 + i]], buf.at[slot, :, i])
+
+        def several(j, _):
+            for i in range(unroll):
+                one(j * unroll + i)
+
+        n = jnp.minimum(chunk, hi - b0)
+        groups = n // unroll if unroll > 1 else 0
+        jax.lax.fori_loop(0, groups, several, None)
+        jax.lax.fori_loop(groups * unroll, n, one, None)
+        for table, sbuf in zip(scales, sbufs):
+            go(table.at[r, b0 // chunk], sbuf.at[slot])
+
+    # a row's last step is partial: positions past `pos` are masked out
+    # of the scores, but 0 * (whatever VMEM held) must stay 0, so the
+    # value side starts from zeros
+    bufs[-1][...] = jnp.zeros_like(bufs[-1])
+    dma(0, blocks_of(0)[1], blocks_of(0)[2], 0, True, unroll=1)
+
+    def row(r, slot):
+        p, lo, hi = blocks_of(r)
+        steps = (hi - lo + chunk - 1) // chunk
+        nxt = jnp.minimum(r + 1, n_rows - 1)
+        _, nlo, nhi = blocks_of(nxt)
+        q = q_ref[r]                                       # (Hkv, g, hd)
+
+        def step(c, carry):
+            m, l, acc, slot = carry
+            b0 = lo + c * chunk
+            last = c + 1 == steps
+
+            # the next step's slabs (the next row's first after this
+            # row's last) load under this step's matmuls
+            @pl.when(jnp.logical_not(last) | (r + 1 < n_rows))
+            def _prefetch():
+                dma(jnp.where(last, nxt, r), jnp.where(last, nlo, b0 + chunk),
+                    jnp.where(last, nhi, hi), 1 - slot, True)
+
+            dma(r, b0, hi, slot, False)
+            k, v = (buf[slot].reshape(hkv, t, buf.shape[-1])
+                    for buf in (bufs[0], bufs[-1]))
+            if quant:
+                # int8 values are exact in the compute dtype; the scales
+                # stay outside the dots (masked_attention's rule)
+                k, v = k.astype(q.dtype), v.astype(q.dtype)
+            s = jnp.einsum("hgd,htd->hgt", q, k, precision=prec,
+                           preferred_element_type=jnp.float32)
+            if quant:
+                s = s * sbufs[0][slot][..., :t]
+            s = s * scale
+            col = b0 * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
+            valid = col <= p
+            if window > 0:
+                valid = valid & (col > p - window)
+            s = jnp.where(valid, s, _NEG)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            pr = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + pr.sum(axis=-1, keepdims=True)
+            if quant:   # the normalizer l is accumulated UNSCALED above
+                pr = pr * sbufs[1][slot][..., :t]
+            acc = acc * alpha + jnp.einsum(
+                "hgt,htd->hgd", pr.astype(v.dtype), v, precision=prec,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc, 1 - slot
+
+        m, l, acc, slot = jax.lax.fori_loop(
+            0, steps, step,
+            (jnp.full((hkv, g, 1), _NEG, jnp.float32),
+             jnp.zeros((hkv, g, 1), jnp.float32),
+             jnp.zeros((hkv, g, o_ref.shape[-1]), jnp.float32), slot))
+        o_ref[r] = (acc / l).astype(o_ref.dtype)
+        return slot
+
+    jax.lax.fori_loop(0, n_rows, row, jnp.int32(0))
+
+
+def _scale_table(plane, bt, chunk):
+    """An int8 pool's scale plane (N, Hkv, bs, 1) read through the
+    table into (S, steps, Hkv, 1, chunk * bs rounded up to whole
+    lanes): a compute step's scales
+    with their positions on the lanes, which is how a score row meets
+    them, and with a minor dimension the DMA can address (Mosaic slices
+    no HBM operand whose minor dimension is under a lane tile). XLA
+    gathers these at the table's width; they are 1/32 of the pool."""
+    s, w = bt.shape
+    hkv, bs = plane.shape[1:3]
+    steps = -(-w // chunk)
+    g = plane[jnp.pad(bt, ((0, 0), (0, steps * chunk - w)))][..., 0]
+    g = g.reshape(s, steps, chunk, hkv, bs)
+    g = jnp.swapaxes(g, 2, 3).reshape(s, steps, hkv, 1, chunk * bs)
+    return jnp.pad(g, ((0, 0),) * 4 + ((0, -chunk * bs % _LANES),))
+
+
+def paged_decode_addresses(pool_blk) -> bool:
+    """Whether `paged_flash_decode` can be compiled for this pool.
+    Mosaic slices no HBM operand whose minor dimension is not whole
+    lanes, so the slab DMA exists for rows of 128, 256, ... values
+    (every published head size, and any latent pool, whose rows are
+    rounded up to whole lanes); a pool of 64-wide heads keeps the
+    gathered read when compiled. Interpreted, every shape goes."""
+    return _interpret_default() or all(
+        leaf.shape[-1] % _LANES == 0
+        for name, leaf in pool_blk.items() if not name.endswith("_s"))
+
+
+# bytes of pool blocks one compute step holds, all leaves together: the
+# olmo shape (128 KB a block) read fastest at 8 blocks a step, mistral
+# (64 KB) at 16, the latent pool (20 KB) at 32-64 (chip run, PR 29)
+_STEP_BYTES = 1 << 20
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "chunk",
+                                             "interpret"))
 def paged_flash_decode(q, pool_blk, bt, pos, *, window: int = 0,
+                       scale: float | None = None,
+                       chunk: int | None = None,
                        interpret: bool | None = None):
     """Single-token attention through a paged block table, fused.
 
     q: (S, H, hd) — one query token per slot; pool_blk: one layer's
-    pools {"k"/"v": (N, Hkv, bs, hd)[, "k_s"/"v_s": (N, Hkv, bs, 1)
-    f32 scales — int8 pools]}; bt: (S, W) int32 block tables (padding
-    columns point at the scratch block); pos: (S,) int32 — each slot's
-    current position (valid cache span is [0, pos], optionally
-    windowed). Returns (S, H, hd) in q's dtype.
+    pool, {"k"/"v": (N, Hkv, bs, hd)[, "k_s"/"v_s": (N, Hkv, bs, 1)
+    f32 scales — int8 pools]} or ONE leaf (N, 1, bs, hd) that is keys
+    and values both (the latent pool: `q` is then the absorbed query
+    and the result a probability-weighted sum of whole rows); bt:
+    (S, W) int32 block tables (padding columns point at the scratch
+    block); pos: (S,) int32 — each slot's current position (valid cache
+    span is [0, pos], optionally windowed). `scale` multiplies the
+    scores (default hd ** -0.5); `chunk` is how many blocks one compute
+    step holds (default: `_STEP_BYTES` of them, in eights). Returns
+    (S, H, hd) in q's dtype.
 
     Matches `masked_attention(q, gather_table(pool, bt), valid)` — the
-    XLA reference that stays in `serving/cache.py` — to fp-reorder
-    noise interpreted (<= 1e-4 pinned) and to bf16 operand rounding
-    compiled (see the block comment above): same score/softmax path,
-    same outside-the-dot int8 scale placement, no gathered copy. GQA is
-    native (H = G * Hkv query heads fold into the program's row axis).
-    """
+    XLA reference that the prefill chunk runs — to fp-reorder noise
+    interpreted (<= 1e-4 pinned): same score/softmax path, same
+    outside-the-dot int8 scale placement, no gathered copy, and only
+    the blocks a row's mask admits are read at all. GQA is native
+    (the G query heads of a KV head are the rows of its matmul).
+
+    Jitted in its own right: a program that calls it once a layer
+    traces and lowers the kernel once, not once a layer (16 of them
+    were 15 s of host time a tick program on the chip's host, which no
+    compilation cache holds)."""
     if interpret is None:
         interpret = _interpret_default()
     from jax.experimental.pallas import tpu as pltpu
 
     s, h, hd = q.shape
-    kp, vp = pool_blk["k"], pool_blk["v"]
-    n, hkv, bs, _ = kp.shape
-    assert h % hkv == 0, (h, hkv)
-    g = h // hkv
-    w = bt.shape[1]
     quant = "k_s" in pool_blk
-    scale = 1.0 / float(np.sqrt(hd))
-    q4 = q.reshape(s, hkv, g, hd)
+    leaves = (list(pool_blk.values()) if len(pool_blk) == 1
+              else [pool_blk["k"], pool_blk["v"]])
+    hkv, bs = leaves[0].shape[1:3]
+    assert h % hkv == 0, (h, hkv)
+    if chunk is None:
+        block_bytes = sum(l[0].size * l.dtype.itemsize for l in leaves)
+        chunk = max(8, _STEP_BYTES // block_bytes // 8 * 8)
+    chunk = max(1, min(bt.shape[1], chunk))
+    scales = ([_scale_table(pool_blk[n], bt, chunk) for n in ("k_s", "v_s")]
+              if quant else [])
     kernel = functools.partial(
-        _paged_decode_kernel, scale=scale, bs=bs, w=w,
-        window=int(window), groups=g, quant=quant)
-
-    def _deref(i, j, k_, bt_ref, pos_ref):
-        # the paged gather, moved into the index map: each program's
-        # k/v (and scale-plane) DMA fetches the pool block its table
-        # column names — no contiguous gathered copy is ever built
-        return (bt_ref[i, k_], j, 0, 0)
-
-    qspec = pl.BlockSpec((1, None, g, hd),
-                         lambda i, j, k_, bt_ref, pos_ref: (i, j, 0, 0))
-    blkspec = pl.BlockSpec((1, None, bs, hd), _deref)
-    sclspec = pl.BlockSpec((1, None, bs, 1), _deref)
-    if quant:
-        in_specs = [qspec, blkspec, sclspec, blkspec, sclspec]
-        operands = (q4, kp, pool_blk["k_s"], vp, pool_blk["v_s"])
-    else:
-        in_specs = [qspec, blkspec, blkspec]
-        operands = (q4, kp, vp)
+        _paged_decode_kernel, bs=bs, chunk=chunk, window=int(window),
+        scale=float(hd ** -0.5 if scale is None else scale),
+        n_pools=len(leaves), quant=quant)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, hkv, w),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, None, g, hd),
-                               lambda i, j, k_, bt_ref, pos_ref:
-                               (i, j, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, _LANES), jnp.float32),  # running max m
-            pltpu.VMEM((g, _LANES), jnp.float32),  # running norm l
-            pltpu.VMEM((g, hd), jnp.float32),      # unnormalized out
-        ],
+        grid=(1,),
+        in_specs=[vmem] + [hbm] * (len(leaves) + len(scales)),
+        out_specs=vmem,
+        scratch_shapes=[pltpu.VMEM((2, hkv, chunk) + l.shape[2:], l.dtype)
+                        for l in leaves]
+        + [pltpu.VMEM((2,) + t.shape[2:], t.dtype) for t in scales]
+        + [pltpu.SemaphoreType.DMA((2,))],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=_sds((s, hkv, g, hd), q.dtype, q),
+        out_shape=_sds((s, hkv, h // hkv, leaves[-1].shape[-1]), q.dtype, q),
         interpret=interpret,
-    )(bt, pos, *operands)
-    return out.reshape(s, h, hd)
-
-
-paged_flash_decode.supports_gqa = True
-paged_flash_decode.supports_window = True
+    )(bt, pos, q.reshape(s, hkv, h // hkv, hd), *leaves, *scales)
+    return out.reshape(s, h, -1)

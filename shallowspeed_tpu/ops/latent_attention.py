@@ -51,23 +51,37 @@ def latent_attention(q_nope, q_rope, c, k_rope, kv_b, valid, scale: float):
     return o.astype(q_nope.dtype)
 
 
+def absorb_query(q_nope, q_rope, kv_b, width: int):
+    """[q_nope @ kv_b's key half | q_rope | 0...] (..., H, width): the
+    query that meets stored rows [c | k_rope | 0...] directly."""
+    dn = q_nope.shape[-1]
+    qa = jnp.einsum("...hn,rhn->...hr", q_nope, kv_b[..., :dn],
+                    preferred_element_type=jnp.float32).astype(q_nope.dtype)
+    qx = jnp.concatenate([qa, q_rope], -1)
+    return jnp.pad(qx, ((0, 0),) * (qx.ndim - 1)
+                   + ((0, width - qx.shape[-1]),))
+
+
+def unabsorb_output(oc, kv_b, dn: int):
+    """Probability-weighted rows (..., H, >= r) through kv_b's value
+    half: (..., H, dv). The rows' tail past r (the rotary key, the
+    padding) is dropped here: reading the rows whole costs a few more
+    operations and no sliced copy of them."""
+    r = kv_b.shape[0]
+    return jnp.einsum("...hr,rhv->...hv", oc[..., :r], kv_b[..., dn:],
+                      preferred_element_type=jnp.float32)
+
+
 def latent_attention_absorbed(q_nope, q_rope, rows, kv_b, valid,
                               scale: float):
     """The absorbed form over stored rows (B,S,X) = [c | k_rope | 0...]
     (X >= r + dr: the cache rounds its rows up to whole lanes): one
-    contraction over X for the scores, one over S for the values (the
-    tail of the result past r is dropped: reading the rows whole costs
-    a few more operations and no sliced copy of the table). Same
+    contraction over X for the scores, one over S for the values. Same
     arguments and result as `latent_attention` otherwise."""
-    dn, f32 = q_nope.shape[-1], jnp.float32
-    r = kv_b.shape[0]
-    qa = jnp.einsum("bthn,rhn->bthr", q_nope, kv_b[..., :dn],
-                    preferred_element_type=f32).astype(q_nope.dtype)
-    qx = jnp.concatenate([qa, q_rope], -1)
-    qx = jnp.pad(qx, ((0, 0),) * 3 + ((0, rows.shape[-1] - qx.shape[-1]),))
+    f32 = jnp.float32
+    qx = absorb_query(q_nope, q_rope, kv_b, rows.shape[-1])
     s = jnp.einsum("bthx,bsx->bhts", qx, rows, preferred_element_type=f32)
     oc = jnp.einsum("bhts,bsx->bthx", _softmax(s, valid, scale, rows.dtype),
-                    rows, preferred_element_type=f32)[..., :r]
-    o = jnp.einsum("bthr,rhv->bthv", oc.astype(rows.dtype), kv_b[..., dn:],
-                   preferred_element_type=f32)
+                    rows, preferred_element_type=f32)
+    o = unabsorb_output(oc.astype(rows.dtype), kv_b, q_nope.shape[-1])
     return o.astype(q_nope.dtype)

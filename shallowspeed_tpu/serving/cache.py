@@ -1,5 +1,6 @@
 """Paged KV cache: block pools, a host-side free-list allocator, and
-the gathered-table read path.
+the gathered-table read (the prefill chunk's, and the reference of the
+decode tick's paged kernel).
 
 The contiguous decode cache (`models/kv_cache.init_kv_cache`) sizes one
 (B, Hkv, slots, hd) buffer per request batch — fine for one `generate()`
@@ -12,8 +13,11 @@ jit-first): a request owns an ordered list of block ids (its *block
 table*), the pools are donated through every compiled program and
 written IN PLACE (`write_rows`, `write_chunk`: scatters indexed on the
 leading dimension only, so the donated buffer keeps its layout and no
-pool-sized copy runs), and attention reads through a GATHERED view of
-the table — `pool[bt]` — masked by position. Appending a token allocates at
+pool-sized copy runs). The decode tick reads the pool where it lies:
+`ops.flash_attention.paged_flash_decode` walks each row's live blocks
+through the table, one query a row. The prefill chunk, whose queries
+amortise it, reads through a GATHERED view of its one row's table —
+`pool[bt]` — masked by position. Appending a token allocates at
 most one block; freeing a finished request returns its blocks in O(1);
 fragmentation cannot exist because any free block serves any request.
 
@@ -31,7 +35,8 @@ A layer's pool is of the layer's kind (`init_block_pool`): K and V
 heads, or for a latent-attention layer ONE row a token that all heads
 share. The kinds share the block ids, so allocator, tables, prefix
 index and copy-on-write are the same for both; only what a write stores
-(`_kv_update`) and how the engine reads the gathered table differ.
+(`_kv_update`) and how the chunk reads the gathered table differ (the
+tick's kernel reads a latent row as key and value both).
 """
 
 from __future__ import annotations
@@ -357,7 +362,10 @@ class PrefixIndex:
 
 
 def gather_table(pool_blk, bt):
-    """Read one layer's cache through a block table.
+    """Read one layer's cache through a block table: the prefill
+    chunk's read, and the XLA reference that the decode tick's kernel
+    (`ops.flash_attention.paged_flash_decode`, which gathers nothing)
+    is pinned against in the tests.
 
     pool_blk: {"k"/"v": (N, Hkv, bs, hd)[, "k_s"/"v_s": (N, Hkv, bs, 1)]}
     bt: (rows, W) int32 block ids (padding rows/tail point at the
@@ -365,9 +373,10 @@ def gather_table(pool_blk, bt):
     Returns the PAGED view {"k"/"v": (rows, W, Hkv, bs, hd), ...} as the
     gather leaves it: page w, slot s of a row IS absolute position
     w*bs + s because tables are ordered. `kv_cache.masked_attention`
-    contracts over it directly — the head-major
-    (rows, Hkv, W*bs, hd) form would cost a transposed copy of the
-    whole gathered table per layer (PERF.md, PR 27)."""
+    contracts over it. It is as wide as the table's bucket whatever the
+    rows hold: for one row and a chunk of queries that is amortised;
+    for every slot of a tick with one query each it was 63% of
+    `olmo-1b.chat`'s device time (PERF.md, PR 29)."""
     return {name: leaf[bt] for name, leaf in pool_blk.items()}
 
 
@@ -469,9 +478,10 @@ def write_chunk(pool_blk, k_rows, v_rows, table, pos0, n_tok,
 # `models/generate.decode_read_bytes_per_token` prices one contiguous
 # decode step: params + the FULL cache sweep. The paged tick's useful
 # sweep is only the LIVE blocks its requests touch — the number below
-# is the per-tick generalization the serving progress lines report
-# (the gathered table also reads its bucket-padding blocks; that
-# padding is the bucketing tax, reported separately as the ratio).
+# is the per-tick generalization the serving progress lines report,
+# and since PR 29 what the tick's kernel reads (the engine's
+# `blocks_read` / `blocks_table` counters say how much of the table's
+# room that was).
 
 
 def param_read_bytes(params, cfg: T.TransformerConfig) -> int:

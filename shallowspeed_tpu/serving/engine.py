@@ -7,7 +7,7 @@ head-of-line behind the longest. This engine serves a STREAM:
 
 - **Fixed-capacity decode slots.** One compiled decode tick advances
   every running request by one token. The tick's row count is pinned
-  to `max_slots` and its gathered block-table width is bucketed
+  to `max_slots` and its block-table width is bucketed
   GEOMETRICALLY in blocks, so requests join and leave the running
   batch between ticks with NO recompiles after warmup — one executable
   per (width bucket), pinned like `test_vm_executables_compile_exactly
@@ -45,7 +45,7 @@ head-of-line behind the longest. This engine serves a STREAM:
   "which request, which phase, which replica" through this.
 
 - **Fast decode path** (round 14, ROADMAP item 1) — three composable
-  levers, each individually gated:
+  levers:
   - *Quantized weight storage* (`weight_quant="int8"|"fp8"`): the
     params tree is quantized ONCE at init (`T.quantize_weights`) into
     int8/fp8-e4m3 matrices + per-out-channel f32 scales; every dense
@@ -54,19 +54,21 @@ head-of-line behind the longest. This engine serves a STREAM:
     never a materialized dequantized copy; proved by the analysis
     `dequant-fusion` rule over this very tick). The params term of
     `paged_read_bytes_per_tick` shrinks to ~0.5x bf16.
-  - *Paged flash-decode kernel* (`attn_impl="flash"`): the tick's
-    attention runs `ops.flash_attention.paged_flash_decode` — grid
-    over the block table via scalar-prefetch index maps, online
-    softmax across a row's blocks, int8 KV + scales read natively —
-    instead of materializing `gather_table`'s contiguous copy.
-    `gather` stays the default AND the reference the kernel is pinned
-    against (<= 1e-4).
+  - *Paged flash-decode kernel*: the tick's attention, for K/V pools
+    and the latent pool alike, runs
+    `ops.flash_attention.paged_flash_decode` — one program that walks
+    each row's live blocks through the table (scalar prefetch, manual
+    DMA from the pool in HBM), online softmax across a row's blocks,
+    int8 KV + scales read natively. No gathered table is built,
+    whatever the bucket's width; `gather_table` + `masked_attention`
+    are the prefill chunk's read and the reference the kernel is
+    pinned against (<= 1e-4). `attn_impl` selects nothing.
   - *Speculative decoding* (`spec_k > 0`): a self-drafting n-gram
     prompt-lookup proposer (`_propose`) fills FREE rows of the
     fixed-capacity tick with up to K draft tokens per decoding
     request at consecutive positions; the same compiled tick verifies
     them all in one pass (each row's mask admits the rows before it —
-    the in-tick writes land before any gather). Acceptance is the
+    the in-tick writes land before any read). Acceptance is the
     deterministic accept/resample rule specialized to a point-mass
     (deterministic) draft distribution under a counter-based sampler:
     every emitted token IS the oracle draw `sample(fold_in(
@@ -81,9 +83,10 @@ head-of-line behind the longest. This engine serves a STREAM:
 
 Stream parity: sampling uses the SAME per-request key schedule as
 `generate()` — token i of a request with sampling seed s draws from
-`fold_in(PRNGKey(s), i)` — and the paged attention shares
-`kv_cache.masked_attention` with the contiguous path, so each
-request's stream reproduces its solo `generate()` stream
+`fold_in(PRNGKey(s), i)` — and the paged attention computes what
+`kv_cache.masked_attention` computes on the contiguous path (the
+chunk through that very function, the tick's kernel pinned to it), so
+each request's stream reproduces its solo `generate()` stream
 token-for-token (pinned in tests/test_serving.py; see `generate`'s
 stream-stability contract for the ~1e-6 numerics caveat).
 """
@@ -101,12 +104,15 @@ import numpy as np
 
 from shallowspeed_tpu import chaos
 from shallowspeed_tpu.models import generate as G
-from shallowspeed_tpu.ops.flash_attention import paged_flash_decode
+from shallowspeed_tpu.ops.flash_attention import (paged_decode_addresses,
+                                                   paged_flash_decode)
 from shallowspeed_tpu.telemetry.trace import tracer
 from shallowspeed_tpu.telemetry.tracing import new_span_id, new_trace_id
 from shallowspeed_tpu.models import transformer as T
-from shallowspeed_tpu.models.kv_cache import masked_attention
-from shallowspeed_tpu.ops.latent_attention import latent_attention_absorbed
+from shallowspeed_tpu.models.kv_cache import masked_attention, position_mask
+from shallowspeed_tpu.ops.latent_attention import (absorb_query,
+                                                   latent_attention_absorbed,
+                                                   unabsorb_output)
 from shallowspeed_tpu.serving.cache import (LATENT, SCRATCH_BLOCK,
                                             BlockAllocator, OutOfBlocks,
                                             PrefixIndex, blocks_for,
@@ -187,7 +193,8 @@ _sample_jit = jax.jit(_sample_rows, static_argnames=("top_k", "top_p"))
 
 
 def _latent_read(p, pool, bt, q_nope, q_rope, valid, cfg):
-    """A latent layer's attention over its gathered table, absorbed:
+    """A latent layer's attention over its gathered table (the prefill
+    chunk's read; the tick's is `_latent_decode`), absorbed:
     the (rows, W, 1, bs, r + dr) pages ARE (rows, W*bs, r + dr) latent
     rows in position order (one shared "head": nothing to make
     head-major), and every query head contracts with them as stored."""
@@ -195,6 +202,19 @@ def _latent_read(p, pool, bt, q_nope, q_rope, valid, cfg):
     rows = g.reshape(g.shape[0], -1, g.shape[-1])
     return latent_attention_absorbed(q_nope, q_rope, rows, p["kv_b"],
                                      valid, T.latent_scale(cfg))
+
+
+def _latent_decode(p, pool, bt, pos, q_nope, q_rope, cfg):
+    """The tick's read of a latent layer: absorb the query, walk each
+    row's live blocks with the paged kernel (one shared "head", every
+    query head a row of its matmuls, the row read once as key and
+    value), un-absorb. q_nope/q_rope: (S, 1, H, .)."""
+    qx = absorb_query(q_nope[:, 0], q_rope[:, 0], p["kv_b"],
+                      pool[LATENT].shape[-1])
+    oc = paged_flash_decode(qx, pool, bt, pos, window=cfg.attn_window,
+                            scale=T.latent_scale(cfg))
+    return unabsorb_output(oc, p["kv_b"], q_nope.shape[-1]
+                           ).astype(q_nope.dtype)
 
 
 def _ffn_counted(p, x, cfg, h, live):
@@ -209,22 +229,24 @@ def _ffn_counted(p, x, cfg, h, live):
     return x + y, (hot * live[..., None, None]).sum((0, 1, 2))
 
 
-@partial(jax.jit, static_argnames=("cfg", "top_k", "top_p", "attn"),
+@partial(jax.jit, static_argnames=("cfg", "top_k", "top_p"),
          donate_argnums=(1,))
 def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
-                 cfg: T.TransformerConfig, top_k: int, top_p: float,
-                 attn: str = "gather"):
+                 cfg: T.TransformerConfig, top_k: int, top_p: float):
     """One compiled decode tick over the whole slot batch.
 
     tok/pos/temp/seeds/idx: (S,) per-slot last token, write position,
     sampling state; bt: (S, W) block tables (W is the bucketed width —
     the ONLY shape that varies across ticks). Each slot writes its
-    token's K/V at (bt[pos // bs], pos % bs) and attends over its
-    gathered table under the position mask; inactive slots carry
-    pos=0 / bt=scratch and their results are ignored host-side.
+    token's K/V at (bt[pos // bs], pos % bs) and attends over the
+    blocks of its table that its position (and window) admit, read
+    from the pool where they lie (`paged_flash_decode`: no gathered
+    table, whatever W is; `paged_decode_addresses` says which pools
+    that excludes when compiled); inactive slots carry pos=0 /
+    bt=scratch, cost one block and their results are ignored host-side.
     A latent layer writes its one latent row instead and reads the
-    table absorbed (`_latent_read`); which a layer is follows from its
-    params and its pool, not from an option.
+    same way, absorbed (`_latent_decode`); which a layer is follows
+    from its params and its pool, not from an option.
     Returns (next token per slot, updated pools, and the routed layers'
     (layers, E) int32 assignment counts of the live rows, None for a
     model without routed layers). The pools are
@@ -236,17 +258,13 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
     copies per leaf per run for it
     (tests/test_tpu_compile.py holds the compiled programs to this).
 
-    `attn="flash"` swaps the gather + masked_attention read for the
-    fused `paged_flash_decode` kernel (same math, no materialized
-    gathered table); "gather" stays the XLA reference the kernel is
-    pinned against. Draft rows (speculative decoding) are ordinary
-    rows at consecutive positions of a shared table: the pool write
-    happens before the read in BOTH paths, so row j's attention sees
-    rows i < j of the same tick — the single-pass verify."""
+    Draft rows (speculative decoding) are ordinary rows at consecutive
+    positions of a shared table: the pool write happens before the
+    read, so row j's attention sees rows i < j of the same tick — the
+    single-pass verify."""
     params = T.cast_params(params, cfg.compute_dtype)
     s_rows = tok.shape[0]
     bs = pool_block_size(pools[0])
-    w = bt.shape[1]
     quant = "k_s" in pools[0]
     x = params["tok_emb"][tok][:, None, :]                  # (S, 1, d)
     if not cfg.rope:
@@ -258,32 +276,30 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
     off = pos % bs
     live = (bt[:, 0] != SCRATCH_BLOCK)[:, None]             # (S, 1)
     rope = lambda u: _rope_rows(u, pos, cfg.rope_theta)
-    if attn != "flash":
-        span = jnp.arange(w * bs)
-        valid = span[None, :] <= pos[:, None]               # (S, W*bs)
-        if cfg.attn_window > 0:
-            valid = valid & (span[None, :]
-                             > pos[:, None] - cfg.attn_window)
+    # heads that are not whole lanes wide (no published size; toy
+    # configurations on the chip) are beyond the kernel's DMA when
+    # compiled and keep the gathered read
+    paged = paged_decode_addresses(pools[0])
+    if not paged:
+        valid = position_mask(bt.shape[1] * bs, pos[:, None],
+                              cfg.attn_window)[:, None, None, None, :]
     new_pools, counts = [], []
     for p, pool in zip(params["blocks"], pools):
         h = T._norm(p["ln1"], x, cfg)
         if LATENT in pool:
             qn, qr, c, kr = T.latent_qkv(p, h, cfg, rope)
             pool = write_rows(pool, c, kr, blk, off, False)
-            a = _latent_read(p, pool, bt, qn, qr, valid[:, None, None, :],
-                             cfg)
+            a = _latent_decode(p, pool, bt, pos, qn, qr, cfg)
         else:
             q, k, v = T._qkv(p, h, cfg)
             if cfg.rope:
                 q, k = rope(q), rope(k)
             pool = {**pool, **write_rows(pool, k[:, 0], v[:, 0], blk, off,
                                          quant)}
-            if attn == "flash":
-                a = paged_flash_decode(q[:, 0], pool, bt, pos,
-                                       window=cfg.attn_window)
-            else:
-                a = masked_attention(q, gather_table(pool, bt),
-                                     valid[:, None, None, None, :], cfg)
+            a = (paged_flash_decode(q[:, 0], pool, bt, pos,
+                                    window=cfg.attn_window) if paged
+                 else masked_attention(q, gather_table(pool, bt), valid,
+                                       cfg))
         x = x + T._dense(p["proj"], a.reshape(s_rows, 1, -1))
         x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
         if n is not None:
@@ -444,20 +460,18 @@ class ServingEngine:
                  log_every: int = 0, clock=time.time,
                  lifecycle: bool = True, chaos_plan=None,
                  prefix_cache: bool = False):
+        # `attn_impl` selects nothing any more: the tick reads every
+        # pool through the paged kernel and the chunk through the
+        # gathered table. The two names are still accepted because the
+        # benchmark's drivers and `serve.py` pass one (ROADMAP D1).
         if attn_impl not in ("gather", "flash"):
             raise ValueError(
                 f"unsupported attn_impl={attn_impl!r}; expected "
-                f"'gather' (the XLA reference) or 'flash' (the paged "
-                f"Pallas decode kernel)")
-        if cfg.latent and attn_impl == "flash":
-            raise ValueError(
-                "attn_impl='flash' reads K/V pools; a latent cache is "
-                "read through the gathered table (attn_impl='gather')")
+                f"'gather' or 'flash' (both name the same programs)")
         # quantize ONCE at init (host-side, idempotent): every tick
         # then reads 1-byte weights through the fused-dequant matmul
         self.params = T.quantize_weights(params, weight_quant)
         self.weight_quant = weight_quant
-        self.attn_impl = attn_impl
         # speculative decoding: K draft tokens per decoding request per
         # tick, drafted by the n-gram prompt-lookup proposer
         self.spec_k = int(spec_k)
@@ -520,7 +534,11 @@ class ServingEngine:
                          # cache rows read. 0 where the model has no
                          # such layer.
                          "experts_touched": 0.0, "max_load": 0.0,
-                         "latent_tokens": 0}
+                         "latent_tokens": 0,
+                         # pool blocks the decode ticks' reads walked
+                         # (every row, dead and draft rows too) and
+                         # the blocks their tables had room for
+                         "blocks_read": 0, "blocks_table": 0}
         # OOM forensics (round 20, the memory observatory): every
         # RECOVERED OutOfBlocks stamps a typed `oom` ledger line and
         # notifies these listeners with (engine, exc) — serve.py wires
@@ -990,6 +1008,16 @@ class ServingEngine:
             attrs["max_load"] = float((counts.max(-1) / mean).mean())
         return attrs
 
+    def _blocks_walked(self, pos) -> int:
+        """Pool blocks one layer's read of a tick walks, from the
+        tick's own positions: each row those of its table that its
+        position and the window admit, a dead row (pos 0) one. (With
+        int8 pools the kernel rounds a window's first block down to a
+        whole step and reads up to a step less one more.)"""
+        bs, window = self.block_size, self.cfg.attn_window
+        first = np.maximum(pos - window + 1, 0) // bs if window > 0 else 0
+        return int((pos // bs + 1 - first).sum())
+
     def _prefill_chunk_of(self, req, tr, sp) -> None:
         c = self.prefill_chunk
         n_tok = min(c, len(req.ctx) - req.written)
@@ -1047,18 +1075,20 @@ class ServingEngine:
             if prep is None:       # every decoder was evicted for blocks
                 return False
             actives, drafts, rows = prep
-            sp.set(n_active=len(actives), width=rows[2].shape[1])
+            pos, bt = rows[1], rows[2]
+            sp.set(n_active=len(actives), width=bt.shape[1])
             with tr.span("decode.dispatch"):
                 nxt, self.pools, counts = _decode_tick(
                     self.params, self.pools, *rows, cfg=self.cfg,
-                    top_k=self.top_k, top_p=self.top_p,
-                    attn=self.attn_impl)
+                    top_k=self.top_k, top_p=self.top_p)
             with tr.span("decode.fetch"):
                 # one wait for both: the counts leave the device beside
                 # the tokens, not in a second round trip after them
                 nxt, counts = jax.device_get((nxt, counts))
             attrs = self._layer_attrs(
                 counts, sum(r.written + 1 for r in actives))
+            attrs["blocks_read"] = self._blocks_walked(pos)
+            attrs["blocks_table"] = bt.size
             sp.set(**attrs)
             for name, value in attrs.items():
                 self.counters[name] += value

@@ -72,3 +72,42 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.nodeid in _SLOW:
             item.add_marker(pytest.mark.slow)
+
+
+def gathered_read(q, pool_blk, bt, pos, *, window=0, scale=None):
+    """What `ops.flash_attention.paged_flash_decode` computes, the way
+    the decode tick computed it before the kernel: the table gathered
+    at its whole width (`gather_table`) and one query a row contracted
+    over all of it under the position mask (`masked_attention`). The
+    kernel's reference in the tests."""
+    from shallowspeed_tpu.models.kv_cache import (masked_attention,
+                                                  position_mask)
+    from shallowspeed_tpu.serving.cache import gather_table, pool_block_size
+
+    if len(pool_blk) == 1:                  # a latent pool: K is V
+        (leaf,) = pool_blk.values()
+        pool_blk = {"k": leaf, "v": leaf}
+    valid = position_mask(bt.shape[1] * pool_block_size(pool_blk),
+                          pos[:, None], window)
+    if scale is not None:                   # masked_attention's is fixed
+        q = q * (scale * q.shape[-1] ** 0.5)
+
+    class Cfg:                              # all masked_attention reads
+        compute_dtype, dtype = None, q.dtype
+
+    return masked_attention(q[:, None], gather_table(pool_blk, bt),
+                            valid[:, None, None, None, :], Cfg)[:, 0]
+
+
+@pytest.fixture
+def gathered_tick(monkeypatch):
+    """Inside this fixture `_decode_tick` reads its pools through
+    `gathered_read` and not through the kernel: the engine-level
+    reference. The tick is traced anew on entry and on exit."""
+    from shallowspeed_tpu.serving import engine
+
+    engine._decode_tick.clear_cache()
+    monkeypatch.setattr(engine, "paged_flash_decode", gathered_read)
+    yield
+    monkeypatch.undo()
+    engine._decode_tick.clear_cache()

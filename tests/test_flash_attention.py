@@ -415,62 +415,69 @@ def test_ring_chunk_numerics_envelope():
 # ------------------------------------------------ paged flash decode
 
 
-@pytest.mark.parametrize("kvh,quant,window", [
-    (0, False, 0),       # MHA, full-precision pools
-    (2, False, 0),       # GQA
-    (0, True, 0),        # int8 pools + f32 scale planes
-    (2, True, 0),        # GQA + int8
-    (0, False, 6),       # sliding window
-    (2, True, 5),        # everything at once
-], ids=["mha", "gqa", "int8", "gqa-int8", "window", "gqa-int8-window"])
-def test_paged_flash_decode_matches_gather_reference(kvh, quant,
-                                                     window):
-    """THE fast-decode kernel pin: `paged_flash_decode` (grid over the
-    block table via scalar-prefetch index maps, online softmax across
-    a row's blocks, int8 KV + scales read natively) matches the XLA
-    reference — `serving/cache.gather_table` + `masked_attention` —
-    to <= 1e-4 in interpret mode, across causal/GQA/int8-KV/window
-    configs. `gather_table` deliberately stays in the tree as this
-    reference; bench.py records the same envelope Mosaic-compiled."""
-    from shallowspeed_tpu.models import transformer as T
-    from shallowspeed_tpu.models.kv_cache import masked_attention
-    from shallowspeed_tpu.ops.flash_attention import paged_flash_decode
-    from shallowspeed_tpu.serving.cache import (gather_table,
-                                                init_block_pool,
-                                                write_rows)
+def _random_pool(rng, n, hkv, bs, hd, kind):
+    """A pool of `kind` filled with random values EVERYWHERE, the
+    scratch block and the blocks no table names included: what a row
+    does not own it must not see."""
+    f = lambda *sh: jnp.asarray(rng.normal(size=sh), jnp.float32)
+    if kind == "latent":
+        return {"ckr": f(n, 1, bs, hd)}
+    if kind == "int8":
+        i8 = lambda: jnp.asarray(
+            rng.integers(-127, 128, (n, hkv, bs, hd)), jnp.int8)
+        sc = lambda: jnp.asarray(
+            rng.uniform(0.01, 0.03, (n, hkv, bs, 1)), jnp.float32)
+        return {"k": i8(), "k_s": sc(), "v": i8(), "v_s": sc()}
+    return {"k": f(n, hkv, bs, hd), "v": f(n, hkv, bs, hd)}
 
-    cfg = T.TransformerConfig(vocab=64, d_model=32, n_heads=4,
-                              n_kv_heads=kvh, n_layers=1, max_seq=128,
-                              attn_window=window)
-    rng = np.random.default_rng(kvh + 10 * quant + window)
-    bs, n, s, w = 8, 16, 4, 3
-    pool = init_block_pool(cfg, n, bs, "int8" if quant else "")[0]
-    bt = rng.integers(1, n, (s, w)).astype(np.int32)
-    pos = np.asarray([bs * w - 1, 13, 20, 0], np.int32)
-    for row in range(s):
-        for p in range(pos[row] + 1):
-            k = jnp.asarray(rng.normal(
-                size=(1, cfg.kv_heads, cfg.head_dim)), jnp.float32)
-            v = jnp.asarray(rng.normal(
-                size=(1, cfg.kv_heads, cfg.head_dim)), jnp.float32)
-            pool = write_rows(pool, k, v,
-                              jnp.asarray([bt[row, p // bs]]),
-                              jnp.asarray([p % bs]), quant)
-    q = jnp.asarray(rng.normal(size=(s, cfg.n_heads, cfg.head_dim)),
-                    jnp.float32)
-    got = paged_flash_decode(q, pool, jnp.asarray(bt),
-                             jnp.asarray(pos), window=window)
-    span = jnp.arange(w * bs)
-    valid = span[None, :] <= pos[:, None]
-    if window > 0:
-        valid = valid & (span[None, :] > pos[:, None] - window)
-    ref = masked_attention(q[:, None], gather_table(pool,
-                                                    jnp.asarray(bt)),
-                           valid[:, None, None, None, :], cfg)[:, 0]
+
+@pytest.mark.parametrize("chunk", [1, 2, None],
+                         ids=["step1", "step2", "step-default"])
+@pytest.mark.parametrize("heads,kind,window", [
+    ((4, 4, 8), "kv", 0),         # MHA, full-precision pools
+    ((4, 2, 8), "kv", 0),         # GQA
+    ((4, 4, 8), "int8", 0),       # int8 pools + f32 scale planes
+    ((4, 2, 8), "int8", 0),       # GQA + int8
+    ((4, 4, 8), "kv", 6),         # a window that binds inside a block
+    ((4, 2, 8), "int8", 13),      # everything at once
+    ((4, 1, 40), "latent", 0),    # one shared head, K is V, rows of
+    ((4, 1, 40), "latent", 11),   # 32 + 8 values: the latent pool
+], ids=["mha", "gqa", "int8", "gqa-int8", "window", "gqa-int8-window",
+        "latent", "latent-window"])
+def test_paged_flash_decode_matches_gather_reference(heads, kind, window,
+                                                     chunk, request):
+    """THE fast-decode kernel pin: `paged_flash_decode` (one program
+    walks each row's live blocks through the table, manual DMA from the
+    pool, online softmax across a row's steps, int8 KV + scales read
+    natively, a one-leaf pool read as keys and values both) matches the
+    XLA reference — `serving/cache.gather_table` + `masked_attention`
+    (tests/conftest.py:gathered_read) — to <= 1e-4 in interpret mode,
+    for ragged rows in ONE call: an all-scratch row at position 0, a
+    row that ends mid-block, one on a block's last slot, one on its
+    first, one at the table's full width; with steps of one block, of
+    two (whole and partial steps, a next row prefetched after a last
+    step) and the default. `gather_table` deliberately stays in the
+    tree as this reference and as the prefill chunk's read."""
+    from conftest import gathered_read
+    from shallowspeed_tpu.ops.flash_attention import paged_flash_decode
+
+    h, hkv, hd = heads
+    rng = np.random.default_rng(len(request.node.name))
+    bs, n, w = 8, 32, 5
+    pool = _random_pool(rng, n, hkv, bs, hd, kind)
+    pos = np.asarray([0, 13, 23, 32, bs * w - 1], np.int32)
+    bt = rng.permutation(np.arange(1, n))[:len(pos) * w].reshape(-1, w)
+    bt = np.where(np.arange(w)[None] <= pos[:, None] // bs, bt, 0)
+    bt[0] = 0                                # a dead slot: all scratch
+    bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(pos)
+    q = jnp.asarray(rng.normal(size=(len(pos), h, hd)), jnp.float32)
+    scale = 0.3 if kind == "latent" else None
+    got = paged_flash_decode(q, pool, bt, pos, window=window, scale=scale,
+                             chunk=chunk)
+    ref = gathered_read(q, pool, bt, pos, window=window, scale=scale)
+    assert got.shape == ref.shape == (len(pos), h, hd)
     err = float(jnp.abs(got - ref).max())
-    scale = max(1e-6, float(jnp.abs(ref).max()))
-    assert err / scale <= 1e-4, (err, scale)
-    assert got.shape == (s, cfg.n_heads, cfg.head_dim)
+    assert err / float(jnp.abs(ref).max()) <= 1e-4, err
 
 
 def test_paged_flash_decode_scratch_rows_are_harmless():

@@ -153,9 +153,64 @@ def test_engine_serves_requests_and_reports_its_layers(params):
 
 def test_engine_refuses_what_a_latent_cache_has_no_form_of(params):
     with pytest.raises(ValueError, match="latent"):
-        E.ServingEngine(params, CFG, attn_impl="flash")
-    with pytest.raises(ValueError, match="latent"):
         init_block_pool(CFG, 8, 8, kv_quant="int8")
+    with pytest.raises(ValueError, match="latent"):
+        E.ServingEngine(params, CFG, kv_quant="int8")
+    # the paged kernel reads a latent pool like any other: the name
+    # that once chose it is accepted and chooses nothing
+    E.ServingEngine(params, CFG, attn_impl="flash")
+
+
+def test_the_ticks_latent_read_equals_the_gathered_one(params):
+    """`_latent_decode` (absorb the query, the paged kernel over the
+    pool with the row as key and value, keep the first r lanes,
+    un-absorb) against `_latent_read` over the gathered table, for
+    ragged rows: a dead slot, mid-block, a block's last slot, the
+    table's full width."""
+    rng = np.random.default_rng(2)
+    blk = params["blocks"][1]
+    bs, n, w = 8, 24, 4
+    pool = {LATENT: jnp.asarray(rng.normal(size=(n, 1, bs, 128)),
+                                jnp.float32)}
+    pos = np.asarray([0, 13, 23, bs * w - 1], np.int32)
+    bt = rng.permutation(np.arange(1, n))[:4 * w].reshape(4, w)
+    bt = np.where(np.arange(w)[None] <= pos[:, None] // bs, bt, 0)
+    bt[0] = 0
+    qn = jnp.asarray(rng.normal(size=(4, 1, 4, 16)), jnp.float32)
+    qr = jnp.asarray(rng.normal(size=(4, 1, 4, 8)), jnp.float32)
+    got = E._latent_decode(blk, pool, jnp.asarray(bt), jnp.asarray(pos),
+                           qn, qr, CFG)
+    valid = jnp.arange(w * bs)[None, :] <= pos[:, None]
+    want = E._latent_read(blk, pool, jnp.asarray(bt), qn, qr,
+                          valid[:, None, None, :], CFG)
+    assert got.shape == (4, 4, 16)          # rows, heads, value size
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, 0]),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["plain", "drafts"])
+def test_latent_tick_tokens_equal_the_gathered_reference(params, spec_k,
+                                                         request):
+    """Engine level, as `tests/test_serving.py` has it for K/V pools:
+    the latent engine's streams through the kernel equal those of the
+    same tick reading the gathered table, with and without drafts."""
+    def streams():
+        eng = E.ServingEngine(params, CFG, n_blocks=48, block_size=8,
+                              max_slots=4, prefill_chunk=16, spec_k=spec_k,
+                              lifecycle=False)
+        motif = _tokens(6, seed=8)
+        for i, p in enumerate((np.tile(motif, 3), _tokens(27, seed=1),
+                               np.tile(motif[:4], 3))):
+            eng.submit(p, 10, rid=f"r{i}")
+        out = eng.run()
+        return {rid: out[rid].tolist() for rid in sorted(out)}, eng
+
+    got, eng = streams()
+    request.getfixturevalue("gathered_tick")
+    want, _ = streams()
+    assert got == want
+    if spec_k:
+        assert eng.counters["spec_drafted"] > 0
 
 
 def test_absorbed_attention_equals_expanded():

@@ -47,7 +47,7 @@ def test_decode_tick_is_named_as_the_benchmark_expects(eng):
     _, _, rows = eng._decode_prep()
     lowered = serving._decode_tick.lower(
         eng.params, eng.pools, *rows, cfg=eng.cfg, top_k=eng.top_k,
-        top_p=eng.top_p, attn=eng.attn_impl)
+        top_p=eng.top_p)
     assert re.search(r"^jit__decode_tick", module_name(lowered))
 
 

@@ -595,19 +595,60 @@ def test_int8_weight_tick_bytes_beat_bf16_baseline():
 
 
 def test_flash_decode_engine_matches_solo_stream(params):
-    """attn_impl='flash' (the paged Pallas kernel, interpret mode on
-    CPU) reproduces the gather-path solo stream token-for-token —
-    kernel-vs-reference logits sit at ~1e-7, far inside sampling's
-    decision boundaries on this model."""
+    """The tick's read is the paged Pallas kernel (interpret mode on
+    CPU) whatever `attn_impl` says: both accepted names reproduce the
+    solo stream of `generate()` (which reads its contiguous cache with
+    `masked_attention`) token-for-token — kernel-vs-reference logits
+    sit at ~1e-7, far inside sampling's decision boundaries on this
+    model."""
     prompt = toks(17, t=21)
     ref = solo(params, prompt, 10, temperature=0.0)
-    eng = ServingEngine(params, CFG, n_blocks=32, block_size=8,
-                        max_slots=4, prefill_chunk=16,
-                        attn_impl="flash")
-    eng.submit(prompt, 10, rid="q")
-    np.testing.assert_array_equal(eng.run()["q"], ref)
+    for name in ("flash", "gather"):
+        eng = ServingEngine(params, CFG, n_blocks=32, block_size=8,
+                            max_slots=4, prefill_chunk=16,
+                            attn_impl=name)
+        eng.submit(prompt, 10, rid="q")
+        np.testing.assert_array_equal(eng.run()["q"], ref)
     with pytest.raises(ValueError, match="attn_impl"):
         ServingEngine(params, CFG, n_blocks=8, attn_impl="paged")
+
+
+def _mixed_streams(params, cfg, spec_k, **kw):
+    """Three requests of different lengths through one engine, two of
+    them self-similar so that `spec_k` has drafts to verify."""
+    eng = ServingEngine(params, cfg, n_blocks=48, block_size=8,
+                        max_slots=4, prefill_chunk=16, spec_k=spec_k, **kw)
+    for i, p in enumerate((spec_prompt(5, t=18), toks(3, t=29),
+                           spec_prompt(9, t=11))):
+        eng.submit(p % cfg.vocab, 12, rid=f"r{i}")
+    out = eng.run()
+    return {rid: out[rid].tolist() for rid in sorted(out)}, eng
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["plain", "drafts"])
+@pytest.mark.parametrize("form", ["mha", "gqa-window", "int8"])
+def test_tick_tokens_equal_the_gathered_reference(params, form, spec_k,
+                                                  request):
+    """Engine level: the streams of a tick that reads through the
+    kernel equal those of the same tick reading the gathered table
+    (`conftest.gathered_tick`), with and without draft rows, which
+    share a table at consecutive positions and must see each other's
+    writes of the same tick."""
+    cfg, kw = CFG, {}
+    if form == "gqa-window":
+        cfg = T.TransformerConfig(vocab=64, d_model=32, n_heads=4,
+                                  n_kv_heads=2, n_layers=2, max_seq=64,
+                                  rope=True, attn_window=12)
+        params = jax.device_put(T.init(cfg, seed=2))
+    elif form == "int8":
+        kw = {"kv_quant": "int8"}
+    got, eng = _mixed_streams(params, cfg, spec_k, **kw)
+    request.getfixturevalue("gathered_tick")
+    want, ref_eng = _mixed_streams(params, cfg, spec_k, **kw)
+    assert got == want
+    assert eng.counters["ticks"] == ref_eng.counters["ticks"]
+    if spec_k:
+        assert eng.counters["spec_drafted"] > 0
 
 
 def test_flash_decode_full_stack_matches_int8_oracle(params):

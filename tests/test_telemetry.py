@@ -553,11 +553,36 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
         e[5]["tick"] for e in steps)
     assert sum(e[5]["n_admitted"] for e in ring if e[2] == "admit") == 3
     decode = next(e for e in ring if e[2] == "decode")
-    assert decode[5] == {"n_active": decode[5]["n_active"], "width": 4}
+    assert set(decode[5]) == {"n_active", "width", "blocks_read",
+                              "blocks_table"}
+    assert decode[5]["width"] == 4
     covered = sum(e[4] - e[3] for e in ring
                   if parent(e) == "engine.step")
     whole = sum(e[4] - e[3] for e in steps)
     assert 0.75 * whole <= covered <= whole
+
+
+def test_decode_span_says_what_the_tick_read(global_tracer, tiny_serving):
+    """`blocks_read` on a `decode` span is the pool blocks its read
+    walked, from the host's own positions: a decoding row at position p
+    its first p // 8 + 1, an idle row one (the scratch block);
+    `blocks_table` is the table's room, slots x width. The engine's
+    counters of the same names are their sums."""
+    eng, serve = tiny_serving
+    before = {k: eng.counters[k] for k in ("blocks_read", "blocks_table")}
+    serve("blocks-")
+    ticks = [e[5] for e in global_tracer.ring() if e[2] == "decode"]
+    assert ticks
+    for a in ticks:
+        assert a["blocks_table"] == eng.max_slots * a["width"]
+        # prompts of 20 and 5 new tokens: positions 20-24, 3 or 4 blocks
+        # a decoding row, 1 an idle one
+        idle = eng.max_slots - a["n_active"]
+        assert 3 * a["n_active"] + idle <= a["blocks_read"] \
+            <= 4 * a["n_active"] + idle <= a["blocks_table"]
+    assert any(a["blocks_read"] < a["blocks_table"] for a in ticks)
+    for k in before:
+        assert eng.counters[k] - before[k] == sum(a[k] for a in ticks)
 
 
 def test_profiler_session_holds_the_programs_spans(global_tracer,

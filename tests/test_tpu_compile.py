@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from shallowspeed_tpu.models import transformer as T
+from shallowspeed_tpu.ops import flash_attention
 from shallowspeed_tpu.serving.cache import init_block_pool
 from shallowspeed_tpu.serving.engine import _decode_tick, _prefill_chunk
 
@@ -50,17 +51,37 @@ _HEADS = {
         n_shared_experts=1, moe_top_k=2, expert_d_ff=256,
         first_dense_layers=1),
 }
-N_BLOCKS, BLOCK, SLOTS, WIDTH, CHUNK = 321, 16, 16, 8, 128
+# a head size no cell has: not whole lanes wide
+_NARROW = dict(_HEADS, **{"heads-of-64": dict(d_model=1024, n_heads=16,
+                                              n_kv_heads=0)})
+# pool leaves of 42 MB and more, as the cells have them (67-252 MB): a
+# leaf that fits the compiler's alternate memory (21 MB did, at 321 and
+# 641 blocks) XLA may prefetch there whole around the Mosaic call that
+# reads it, one copy in and one out, and the gate below would read that
+# as the layout copies it exists to catch
+N_BLOCKS, BLOCK, SLOTS, WIDTH, CHUNK = 1281, 16, 16, 8, 128
 
 _SKIP_OPS = ("parameter", "bitcast", "get-tuple-element", "tuple",
              "constant")
 
 
-def _compiled_text(program, one_chip, heads):
+@pytest.fixture(autouse=True)
+def mosaic_not_interpreted(monkeypatch):
+    """The process's backend is the CPU, where the kernels default to
+    the interpreter; what is compiled here is compiled for the chip."""
+    monkeypatch.setattr(flash_attention, "_interpret_default",
+                        lambda: False)
+    # the kernel's entry point is jitted and would remember either mode
+    flash_attention.paged_flash_decode.clear_cache()
+    yield
+    flash_attention.paged_flash_decode.clear_cache()
+
+
+def _compiled_text(program, one_chip, heads, kv_quant=""):
     cfg = T.TransformerConfig(
         vocab=512, d_ff=512, n_layers=2, max_seq=2048, rope=True,
         norm="rmsnorm", ffn="swiglu", dtype=jnp.bfloat16,
-        compute_dtype=jnp.bfloat16, **_HEADS[heads])
+        compute_dtype=jnp.bfloat16, **_NARROW[heads])
 
     def spec(tree):
         return jax.tree_util.tree_map(
@@ -73,20 +94,20 @@ def _compiled_text(program, one_chip, heads):
     params = spec(jax.eval_shape(
         lambda: T.cast_params(T.init(cfg, seed=0), jnp.bfloat16)))
     pools = spec(jax.eval_shape(
-        lambda: init_block_pool(cfg, N_BLOCKS, BLOCK)))
+        lambda: init_block_pool(cfg, N_BLOCKS, BLOCK, kv_quant)))
     i32, f32 = jnp.int32, jnp.float32
     if program == "decode_tick":
         traced = _decode_tick.trace(
             params, pools, arr(i32, SLOTS), arr(i32, SLOTS),
             arr(i32, SLOTS, WIDTH), arr(f32, SLOTS), arr(i32, SLOTS),
-            arr(i32, SLOTS), cfg=cfg, top_k=0, top_p=0.0, attn="gather")
+            arr(i32, SLOTS), cfg=cfg, top_k=0, top_p=0.0)
     else:
         traced = _prefill_chunk.trace(
             params, pools, arr(i32, 1, CHUNK), arr(i32), arr(i32),
             arr(i32, 1, WIDTH), arr(i32), arr(i32), cfg=cfg)
     text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
     leaf = next(iter(pools[0].values()))
-    return text, len(jax.tree_util.tree_leaves(pools)), leaf.size
+    return text, len(jax.tree_util.tree_leaves(pools)), leaf
 
 
 def _computations(text):
@@ -117,7 +138,8 @@ def test_serving_programs_write_the_pool_in_place(one_chip, program,
     own buffer). A pool-sized `copy`, `transpose` or relayout fusion
     here is two of them per leaf per program run on the chip: 45% of
     `olmo-1b.chat`'s device time before this test existed."""
-    text, n_leaves, pool_elems = _compiled_text(program, one_chip, heads)
+    text, n_leaves, leaf = _compiled_text(program, one_chip, heads)
+    pool_elems = leaf.size
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp",
                         text, re.S).group(1)
     assert aliased.count("alias)") == n_leaves, aliased
@@ -145,3 +167,57 @@ def test_serving_programs_write_the_pool_in_place(one_chip, program,
     # each leaf is written once a layer (and copied-on-write once more
     # in the prefill chunk)
     assert in_place == n_leaves * (2 if program == "prefill_chunk" else 1)
+
+
+@pytest.mark.parametrize("heads", list(_HEADS))
+def test_decode_tick_reads_the_pool_through_the_kernel(one_chip, heads,
+                                                       monkeypatch):
+    """The gate of PR 29: the tick compiled for the chip holds one
+    Mosaic call a layer (`paged_flash_decode`, K/V pools and the latent
+    pool alike) and NO instruction, in any computation and any dtype,
+    whose result has the gathered table's shape: (slots, width, Hkv,
+    block, hd), with slots and width merged as XLA wrote it, or the
+    latent read's (slots, width * block, hd). The
+    gathered table of all 16 slots at the bucket's width, made f32 and
+    contracted elementwise, was 63% of `olmo-1b.chat`'s device time and
+    56% of `moonlight-16b-a3b.gen-batch`'s."""
+    from conftest import gathered_read
+    from shallowspeed_tpu.serving import engine
+
+    def table_ops(text):
+        hkv, _, tail = leaf.shape[1:]
+        n = rf"(?:{SLOTS},{WIDTH}|{SLOTS * WIDTH})"   # as gathered, or merged
+        table = (rf"\[{n},{hkv},{BLOCK},{tail}\]|\[{n},{BLOCK},{tail}\]"
+                 rf"|\[{SLOTS},{WIDTH * BLOCK},{tail}\]")
+        return [l.strip()[:160] for l in text.splitlines() if re.match(
+            rf"\s*(?:ROOT )?%?[\w.\-]+ = \w+(?:{table})", l)]
+
+    text, _, leaf = _compiled_text("decode_tick", one_chip, heads)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not table_ops(text), "\n".join(table_ops(text))
+    # what this looks for is there to be found: the same tick reading
+    # through the gathered table holds it, and no kernel
+    monkeypatch.setattr(engine, "paged_flash_decode", gathered_read)
+    engine._decode_tick.clear_cache()
+    before, _, _ = _compiled_text("decode_tick", one_chip, heads)
+    engine._decode_tick.clear_cache()
+    assert table_ops(before) and "tpu_custom_call" not in before
+    # the chunk's read is not this PR's: it gathers its one row's table
+    chunk, _, _ = _compiled_text("prefill_chunk", one_chip, heads)
+    assert "tpu_custom_call" not in chunk
+
+
+@pytest.mark.parametrize("heads,kv_quant,kernel", [
+    ("olmo-1b-mha", "int8", True),
+    ("heads-of-64", "", False),
+], ids=["int8-pools", "heads-of-64"])
+def test_the_other_pools_compile_for_the_chip(one_chip, heads, kv_quant,
+                                              kernel):
+    """What no cell runs still has to compile for the chip. int8 pools
+    go through the kernel (their scale planes, whose minor dimension
+    Mosaic cannot slice, gathered by XLA with positions on the lanes).
+    Heads that are not whole lanes wide are beyond the slab DMA
+    (`paged_decode_addresses`): their tick keeps the gathered read."""
+    text, _, _ = _compiled_text("decode_tick", one_chip, heads, kv_quant)
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (2 if kernel else 0)
