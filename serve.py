@@ -84,12 +84,6 @@ def parse_args(argv=None):
                    help="quantized weight storage with fused dequant "
                         "(per-out-channel f32 scales; halves the "
                         "param sweep behind every decode tick)")
-    s.add_argument("--attn-impl", default="gather",
-                   choices=["gather", "flash"],
-                   help="selects nothing: the decode tick always reads "
-                        "its pools through the paged Pallas kernel and "
-                        "the prefill chunk through the gathered table; "
-                        "kept because callers still pass it")
     s.add_argument("--spec-k", type=int, default=0,
                    help="speculative decoding: up to K self-drafted "
                         "(n-gram prompt-lookup) tokens per decoding "
@@ -107,7 +101,7 @@ def parse_args(argv=None):
                         "shared KV blocks straight into their tables "
                         "(refcounted, copy-on-write at the tail) and "
                         "skip that prefill. Streams are token-identical "
-                        "to off — off is the parity oracle bench uses")
+                        "to off — off is the parity oracle")
     p.add_argument("--requests", default="-",
                    help="JSONL request file, or - for stdin (ignored "
                         "under --serve unless explicitly set)")
@@ -282,7 +276,7 @@ def main(argv=None) -> int:
                     slots=args.slots, prefill_chunk=args.prefill_chunk,
                     kv_quant=args.kv_quant,
                     weight_quant=args.weight_quant,
-                    attn_impl=args.attn_impl, spec_k=args.spec_k,
+                    spec_k=args.spec_k,
                     prefix_cache=args.prefix_cache)
     if args.replica:
         run_info["replica"] = args.replica
@@ -300,7 +294,7 @@ def main(argv=None) -> int:
         block_size=args.block_size, max_slots=args.slots,
         prefill_chunk=args.prefill_chunk,
         table_bucket=args.table_bucket, kv_quant=args.kv_quant,
-        weight_quant=args.weight_quant, attn_impl=args.attn_impl,
+        weight_quant=args.weight_quant,
         spec_k=args.spec_k, spec_ngram=args.spec_ngram,
         top_k=args.top_k, top_p=args.top_p, metrics=metrics,
         log_every=args.log_every,

@@ -103,36 +103,6 @@ def iter_eqns(jaxpr, path: tuple = (),
 # ------------------------------------------------------------------ bytes
 
 
-def dot_flops(eqn) -> int:
-    """Matmul FLOPs of one `dot_general` equation (2*batch*M*N*K from
-    its dimension numbers; 0 for every other primitive). The telemetry
-    attribution layer prices these at the MXU peak and everything else
-    at the HBM roofline — the same per-op walk the lint rules ride."""
-    if eqn.primitive.name != "dot_general":
-        return 0
-    (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
-    lhs = eqn.invars[0].aval.shape
-    rhs = eqn.invars[1].aval.shape
-    batch = int(np.prod([lhs[i] for i in lb], dtype=np.int64))
-    k = int(np.prod([lhs[i] for i in lc], dtype=np.int64))
-    m = int(np.prod([d for i, d in enumerate(lhs)
-                     if i not in lc and i not in lb], dtype=np.int64))
-    n = int(np.prod([d for i, d in enumerate(rhs)
-                     if i not in rc and i not in rb], dtype=np.int64))
-    return 2 * batch * m * n * k
-
-
-def eqn_bytes(eqn) -> int:
-    """HBM traffic upper bound of one leaf equation: operand + output
-    bytes (what an unfused execution would move — real fused time is
-    lower, so pricing this at the HBM roofline over-explains, never
-    under-explains, a measured step)."""
-    ins = sum(aval_bytes(v.aval) for v in eqn.invars
-              if not isinstance(v, Literal))
-    outs = sum(aval_bytes(v.aval) for v in eqn.outvars)
-    return ins + outs
-
-
 def aval_bytes(aval) -> int:
     """On-device bytes of one abstract value (0 for non-array avals)."""
     shape = getattr(aval, "shape", None)

@@ -57,25 +57,6 @@ _HBM_BPS = {
     "TPU v7": 7370e9,
 }
 
-# Aggregate inter-chip interconnect bandwidth per JAX device, bytes/s,
-# one direction (approximate — published aggregate link rates; the
-# attribution waterfall needs order-of-magnitude wire time, not a
-# topology model, and the per-primitive algorithm factors are
-# deliberately left to the reader like collectives.py's byte counts).
-_ICI_BPS = {
-    "TPU v2": 60e9,
-    "TPU v3": 100e9,
-    "TPU v4": 300e9,     # 2400 Gbps
-    "TPU v5 lite": 200e9,  # 1600 Gbps
-    "TPU v5e": 200e9,
-    "TPU v5": 600e9,     # v5p, 4800 Gbps
-    "TPU v5p": 600e9,
-    "TPU v6 lite": 448e9,
-    "TPU v6e": 448e9,
-    "TPU v7": 1200e9,
-}
-
-
 def _lookup_kind(device, table) -> float | None:
     """Longest-prefix match of `device`'s kind against a peaks table
     ("TPU v5 lite" beats "TPU v5"); None when unknown (CPU meshes)."""
@@ -112,23 +93,15 @@ def device_peak_flops(device=None, dtype: str = "bf16") -> float | None:
     if dtype in ("fp8", "float8", "e4m3", "float8_e4m3fn"):
         # dense fp8 runs the MXU at 2x its bf16 rate on generations
         # that support it natively (see the v7 entry's 4.6PF -> 2.3
-        # note); the same 2x is what telemetry/attribution prices
-        # fp8-operand dot FLOPs at when building the roofline
+        # note)
         return p * 2.0
     return p
 
 
 def device_mem_bandwidth(device=None) -> float | None:
     """Peak HBM bytes/s of one JAX device (None off-TPU) — the
-    denominator for memory-roofline utilization (decode sweeps,
-    telemetry/attribution's fusion pricing)."""
+    denominator for memory-roofline utilization (decode sweeps)."""
     return _lookup_kind(device, _HBM_BPS)
-
-
-def device_ici_bandwidth(device=None) -> float | None:
-    """Approximate aggregate ICI bytes/s of one JAX device (None
-    off-TPU) — telemetry/attribution's exposed-collective wire rate."""
-    return _lookup_kind(device, _ICI_BPS)
 
 
 def _avg_causal_context(seq_len: int, window: int = 0) -> float:

@@ -169,9 +169,7 @@ class TransformerConfig:
     # just-in-time per-tensor stop_gradient scale, weights with the
     # per-out-channel scale, f32 accumulation, straight-through
     # backward. Embeddings, norms and MoE banks stay in compute_dtype
-    # (same exclusions as `quantize_weights`). The attribution gate
-    # (bench.py's fp8 case) pins that this flag shrinks
-    # attrib_mxu_frac vs the bf16 baseline while shadow parity holds.
+    # (same exclusions as `quantize_weights`).
     fp8_dense: bool = False
     # Multi-head latent attention (DeepSeek-V2's MLA; 0 = off). Keys and
     # values of a token are ONE shared row of `kv_lora_rank` values (a

@@ -1,46 +1,12 @@
-"""Pallas blocked matmul — the narrow-K mitigation.
-
-XLA/Mosaic's default lowering of bf16 matmuls with K ~ 1024 runs at
-~1/8 of peak on v5e (measured in BASELINE.md: (16384,1024)@(1024,4096)
-at ~21 TFLOP/s vs 159-170 at K>=2048 — the same op, wider). The
-reference has no analogue (its matmuls are NumPy BLAS calls,
-`/root/reference/shallowspeed/functional.py`); this kernel exists
-purely to claim back the MXU on narrow-K shapes.
-
-Classic 3-D-grid formulation: (M/bm, N/bn, K/bk) programs, an f32 VMEM
-accumulator per (i, j) tile, K innermost so the accumulator stays
-resident while K-blocks stream through. `jnp.dot` inside the kernel
-with `preferred_element_type=f32` drives the MXU directly with our
-block shapes instead of Mosaic's narrow-K choice.
+"""Matmuls over quantized operands: the fused-dequant product behind
+quantized weight storage (`dequant_matmul`) and the fp8-e4m3 training
+matmul with its hand-written straight-through backward (`fp8_dense`).
 """
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-
-def _mm_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(x_ref[...], y_ref[...],
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------ fused-dequant matmul
@@ -80,44 +46,6 @@ def dequant_matmul(x, wq, ws, *, compute_dtype=None):
         (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return (acc * ws.astype(jnp.float32)).astype(x.dtype)
-
-
-@partial(jax.jit,
-         static_argnames=("bm", "bk", "bn", "out_dtype", "interpret"))
-def blocked_matmul(x, y, *, bm: int = 512, bk: int = 512, bn: int = 1024,
-                   out_dtype=None, interpret: bool | None = None):
-    """x (M, K) @ y (K, N) with explicit (bm, bk, bn) MXU tiling and an
-    f32 accumulator. Shapes must divide by the blocks (the training use
-    sites have power-of-two dims; no padding path here). Keep
-    bm*bn*4 + bm*bk*2 + bk*bn*2 well under the 16MB scoped-VMEM ceiling
-    (double buffering roughly doubles the block traffic)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    m, k = x.shape
-    k2, n = y.shape
-    assert k == k2, (x.shape, y.shape)
-    bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
-    assert m % bm == 0 and k % bk == 0 and n % bn == 0, (
-        f"({m},{k})@({k},{n}) must divide by blocks ({bm},{bk},{bn})")
-    out_dtype = out_dtype or x.dtype
-    nk = k // bk
-    kw = {}
-    if not interpret:
-        kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return pl.pallas_call(
-        partial(_mm_kernel, nk=nk),
-        grid=(m // bm, n // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=interpret,
-        **kw,
-    )(x, y)
 
 
 # --------------------------------------------- fp8-e4m3 training matmul

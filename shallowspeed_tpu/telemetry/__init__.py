@@ -88,11 +88,8 @@ _LAZY = {
     "fetch_pack": "health",
     "AnomalyDetector": "anomaly", "GuardPolicy": "anomaly",
     "RobustEWMA": "anomaly", "Verdict": "anomaly",
-    # time attribution + goodput + bench regression gate (round 9)
-    "step_waterfall": "attribution", "roofline_of_jaxpr": "attribution",
-    "device_rates": "attribution",
+    # goodput (round 9)
     "GoodputLedger": "goodput", "run_goodput": "goodput",
-    "check_trajectory": "regress", "load_trajectory": "regress",
     # live telemetry plane (round 12): streaming sketches, /status +
     # /metrics endpoints, SLO burn-rate alerts, flight recorder
     "LogHistogram": "sketch", "MetricSketches": "sketch",

@@ -1,10 +1,8 @@
-"""CLI: validate committed JSONL, gate the bench trajectory, reduce a
-run's goodput ledger, or watch a run live.
+"""CLI: validate committed JSONL, reduce a run's goodput ledger, or
+watch a run live.
 
     python -m shallowspeed_tpu.telemetry --validate docs_runs/*.jsonl
     python -m shallowspeed_tpu.telemetry --validate docs_runs/
-    python -m shallowspeed_tpu.telemetry --regress BENCH_*.json
-    python -m shallowspeed_tpu.telemetry --regress .
     python -m shallowspeed_tpu.telemetry --goodput run/metrics.jsonl
     python -m shallowspeed_tpu.telemetry --goodput run/router.jsonl \
         run/replica_r0.jsonl run/replica_r1.jsonl
@@ -20,10 +18,9 @@ run's goodput ledger, or watch a run live.
         http://127.0.0.1:9101 --port 9200
     python -m shallowspeed_tpu.telemetry --fleet r0.jsonl r1.jsonl --once
 
---validate and --regress are the pre-commit gates for committed
-`docs_runs/*.jsonl` snapshots and the `BENCH_r*.json` trajectory —
-both pure-stdlib checks that cost only the package import (~1 s), not
-a trace or a bench run of anything. --goodput prints the run-level
+--validate is the pre-commit gate for committed `docs_runs/*.jsonl`
+snapshots — a pure-stdlib check that costs only the package import
+(~1 s), not a trace of anything. --goodput prints the run-level
 wall-clock decomposition (goodput + named losses) of one metrics
 JSONL, including runs that span supervisor restarts; extra files
 after the first are replica logs joined BY TRACE ID into the
@@ -62,10 +59,6 @@ def main(argv=None) -> int:
                    help="JSONL files (or directories scanned for "
                         "*.jsonl) to check against the telemetry/"
                         "metrics schema")
-    g.add_argument("--regress", nargs="+", metavar="PATH",
-                   help="BENCH_r*.json files (or directories scanned "
-                        "for them) — fail when the newest round drops "
-                        "below the prior rounds beyond the noise band")
     g.add_argument("--goodput", nargs="+", metavar="JSONL",
                    help="reduce one metrics JSONL to the goodput "
                         "report (wall-clock decomposition + losses, "
@@ -162,10 +155,6 @@ def main(argv=None) -> int:
         return live_main(args.live, slos=args.slo, once=args.once,
                          interval=args.interval)
 
-    if args.regress:
-        from shallowspeed_tpu.telemetry.regress import main as rmain
-
-        return rmain(args.regress)
     if args.goodput:
         from shallowspeed_tpu.telemetry.goodput import (format_report,
                                                         run_goodput)

@@ -1,10 +1,9 @@
 """Continuous profiling plane — the fourth observability pillar.
 
-The waterfall (`telemetry/attribution.py`) prices every fenced step
-into compute/comm/bubble/host fractions, but `attrib_host_frac` is an
-opaque blob: when host time grows — the exact failure mode host-driven
-pipeline schedules suffer at scale (PipeDream, arXiv 1806.03377) —
-nothing says *where* it went. Four parts close that:
+A step's host time is an opaque blob: when it grows — the exact
+failure mode host-driven pipeline schedules suffer at scale (PipeDream,
+arXiv 1806.03377) — nothing says *where* it went. Four parts close
+that:
 
 - **Always-on host sampler** (`SamplingProfiler`): a daemon thread
   reads the MAIN thread's Python stack via `sys._current_frames()` at
@@ -25,8 +24,7 @@ nothing says *where* it went. Four parts close that:
   gateway (one module-global check a span when no profiler runs).
   `phases` decomposes the host blob into named buckets; `step_samples`
   (stack contains a step/batch span) is the sampler's own estimate of
-  in-step time, cross-checked against the waterfall's
-  `attrib_host_frac` in tests.
+  in-step time.
 - **Trigger-driven capture windows** (`CaptureWindow`): a critical SLO
   burn, an anomaly verdict, a chaos fault, or a fleet straggler
   verdict arms ONE bounded high-rate window (~200 Hz for ~0.5 s) via
@@ -73,7 +71,7 @@ DEFAULT_TOP_K = 40
 OTHER_KEY = "(other)"
 UNTAGGED = "(untagged)"
 # tag names whose presence ANYWHERE in the stack marks a sample as
-# inside a fenced step span (attribution.window_step_spans' names)
+# inside a fenced step span
 STEP_TAGS = ("step", "batch")
 
 # ------------------------------------------------------------- tagging
@@ -162,11 +160,11 @@ def _tags_of(ident: int) -> tuple[str, bool]:
 class SamplingProfiler:
     """Daemon-thread stack sampler over the process MAIN thread.
 
-    Main thread only, deliberately: `attrib_host_frac` measures the
+    Main thread only, deliberately: the host time in question is the
     driver/scheduler thread's wall time outside fenced step spans, and
     a monitor HTTP thread parked in `select` would swamp the phase
     buckets with sleep frames. (`all_threads=True` exists for
-    forensics; the attribution cross-check assumes the default.)
+    forensics.)
 
     All counters are CUMULATIVE; `snapshot()` bounds the payload to
     `top_k` folded stacks plus an exact `(other)` remainder, so

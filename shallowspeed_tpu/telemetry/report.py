@@ -39,7 +39,7 @@ def percentile(vals, q: float) -> float | None:
     """Nearest-rank percentile (q in [0, 100]) without numpy dtype
     surprises — None on empty input. The ONE quantile definition the
     repo shares: the request-latency summary below, the goodput
-    reducer's serving block, the attribution q25 step-time pick, and
+    reducer's serving block and
     the streaming sketches (`sketch.LogHistogram.quantile`) all use
     this rank rule, so live and offline quantiles can only disagree by
     the sketch's documented rel_err — never by rank convention.
@@ -304,20 +304,15 @@ def compile_counts(entrypoints) -> dict:
 class RunTelemetry:
     """Aggregates telemetry for one engine over one training run."""
 
-    def __init__(self, engine, tracer=None, check_tolerance: float = 1.05,
-                 dtype: str = "bf16"):
+    def __init__(self, engine, tracer=None, check_tolerance: float = 1.05):
         self.engine = engine
         self.tracer = tracer
         self.tol = check_tolerance
-        self.dtype = dtype          # attribution's MXU peak selector
         self._static = None
         self._bubble: dict = {}
-        self._span_mark = 0         # tracer seq at the last log point
-        self._attrib_scale = None   # frozen self-calibration factor
-        self._attrib_cals = 0       # windows the scale was fit on
         # optional goodput.GoodputLedger the driver also stamps —
         # surfaced in run_summary so telemetry.json carries the
-        # in-process loss totals next to the waterfall
+        # in-process loss totals next to the static report
         self.ledger = None
         # memory observatory (round 20): per-window leak/drift
         # detector over the live-bytes + host-RSS series this
@@ -349,8 +344,8 @@ class RunTelemetry:
         rep = {}
         for ep in eps:
             try:
-                # ONE make_jaxpr per entrypoint, shared by all three
-                # accountings — tracing a big pipeline step costs
+                # ONE make_jaxpr per entrypoint, shared by every
+                # accounting — tracing a big pipeline step costs
                 # seconds and must not run twice
                 import jax
 
@@ -372,19 +367,9 @@ class RunTelemetry:
                 expo = collective_exposure(closed)
             except Exception:
                 expo = None
-            try:
-                # roofline inputs (schema v4, telemetry/attribution):
-                # per-op matmul FLOPs + HBM bytes off the SAME trace
-                from shallowspeed_tpu.telemetry.attribution import (
-                    roofline_of_jaxpr)
-
-                roof = roofline_of_jaxpr(closed)
-            except Exception:
-                roof = None
             rep[ep["name"]] = {"collectives": traffic,
                                "static_peak_bytes": peak,
-                               "exposure": expo,
-                               "roofline": roof}
+                               "exposure": expo}
         self._static = {"entrypoints": rep,
                         "step": eps[0]["name"]}  # first = the step fn
         return self._static
@@ -477,109 +462,7 @@ class RunTelemetry:
         if measured is not None:
             out["coll_bytes_measured"] = measured()
         out.update(self._bubble)
-        # schema v4: the roofline waterfall — spans level only (the
-        # step spans are device-fenced there, so their durations are
-        # attributable time; at `steps` they measure dispatch)
-        try:
-            out.update(self._attribution(window_secs))
-        except Exception:
-            pass
         return out
-
-    def _attribution(self, window_secs: float | None) -> dict:
-        """attrib_* fields for the window just closed: measured fenced
-        step time reconciled against the static roofline + exposed
-        collective wire time + bubble + the window's host/dispatch gap
-        (telemetry/attribution.py)."""
-        tr = self.tracer
-        if tr is None or tr.level != "spans":
-            return {}
-        from shallowspeed_tpu.telemetry import attribution as attr
-
-        events = tr.events_since(self._span_mark)
-        self._span_mark = tr.event_count
-        durs = attr.window_step_spans(events)
-        if not durs:
-            return {}
-        # lower quartile, not median: on a quiet device (TPU) the
-        # fenced durations are tight and q25 == the median; on a
-        # shared/oversubscribed host the distribution is bimodal
-        # (descheduled steps run ~2x slow) and the median flips modes
-        # window to window — q25 tracks the repeatable fast mode,
-        # which is the quantity whose drift means the PROGRAM got
-        # slower (the alarm) rather than the host got busy (noise).
-        # Same nearest-rank helper as the request-latency quantiles —
-        # step-time and serving percentiles share ONE definition.
-        t_step = percentile(durs, 25)
-        if t_step <= 0.0:
-            return {}
-        roof = None
-        exposed_bytes = 0
-        static = self.static_report()
-        if static is not None:
-            acc = {"flops_shard": 0, "flops_global": 0,
-                   "dot_bytes_shard": 0, "dot_bytes_global": 0,
-                   "bytes_shard": 0, "bytes_global": 0}
-            have = False
-            for name, entry in static["entrypoints"].items():
-                if name == "_eval" or "error" in entry:
-                    continue  # eval never runs inside a step span
-                r = entry.get("roofline")
-                if r:
-                    have = True
-                    for k in acc:
-                        acc[k] += r.get(k, 0)
-                traffic = entry.get("collectives")
-                if traffic:
-                    expo = entry.get("exposure") or {}
-                    frac = expo.get("exposed_comm_frac")
-                    frac = 1.0 if frac is None else float(frac)
-                    exposed_bytes += int(traffic["total_bytes"] * frac)
-            roof = acc if have else None
-        if roof is None:
-            # no roofline model (the VM publishes its per-stage
-            # executables without arg skeletons; it measures traffic
-            # and bubble directly) — an all-"unexplained" waterfall
-            # would be noise, not signal
-            return {}
-        host_gap = None
-        if window_secs:
-            host_gap = max(0.0, window_secs - sum(durs)) / len(durs)
-        bubble = self._bubble.get("bubble_measured",
-                                  self._bubble.get("bubble_static"))
-        mesh = getattr(self.engine, "mesh", None)
-        n_dev = int(getattr(getattr(mesh, "devices", None), "size", 1)
-                    or 1)
-        rates = attr.device_rates(dtype=self.dtype)
-        scale = None
-        if roof is not None and rates.get("source") == "calibrated":
-            # no published peak for this device (CPU test meshes):
-            # probe rates only fix the MXU/HBM split — self-scale the
-            # compute component so the calibration window balances by
-            # construction, then freeze it; later windows' unexplained
-            # measures drift from that baseline (the regression-alarm
-            # semantics; absolute roofline truth off-TPU would just
-            # measure host-load noise). The fit runs on the first TWO
-            # windows and freezes on the second: the first log window
-            # usually contains the compile-heavy step 0, and a scale
-            # fit against compile time would misread every steady
-            # window after it.
-            if self._attrib_cals < 2:
-                secs = attr.roofline_seconds(roof, rates, n_dev)
-                other = ((0.0 if bubble is None else float(bubble))
-                         + (0.0 if host_gap is None
-                            else host_gap / t_step)
-                         + exposed_bytes / rates["ici"] / t_step)
-                residual = max(0.05, 1.0 - other) * t_step
-                self._attrib_scale = residual / max(
-                    secs["mxu_s"] + secs["hbm_s"], 1e-12)
-                self._attrib_cals += 1
-            scale = self._attrib_scale
-        return attr.step_waterfall(
-            t_step, roofline=roof, coll_bytes=exposed_bytes,
-            bubble_fraction=bubble, host_gap=host_gap,
-            n_devices=n_dev, dtype=self.dtype, rates=rates,
-            compute_scale=scale)
 
     # -------------------------------------------------------- summary
 

@@ -27,8 +27,7 @@ from pathlib import Path
 # the comm/compute-overlap fields (`exposed_comm_frac` /
 # `overlap_ratio` — the step program's dataflow communication
 # exposure, `parallel/overlap.collective_exposure` — and the engine's
-# `overlap` mode flag); 4 = v3 plus the time-attribution waterfall
-# (`attrib_*` step fields, `telemetry/attribution.py`), the goodput
+# `overlap` mode flag); 4 = v3 plus the goodput
 # ledger (`"ledger"` events, `telemetry/goodput.py`) and the absolute
 # `wall` timestamp every metrics line now carries so the ledger
 # reducer can account wall clock ACROSS process restarts. Writers
@@ -106,7 +105,7 @@ from pathlib import Path
 # `telemetry/profiler.py`): `"profile"` events — periodic CUMULATIVE
 # snapshots of the host sampling profiler (folded-stack top-K counts
 # + an exact `other` remainder, the span-tagged `phases` breakdown,
-# `step_samples` for the attrib_host_frac cross-check, `max_gap_ms`
+# `step_samples` (samples inside a step span), `max_gap_ms`
 # the sampler-liveness bound) that merge across replicas like the v7
 # sketch snapshots: the LAST event per process stanza is that
 # stanza's whole story, and `python -m shallowspeed_tpu.telemetry
@@ -149,7 +148,7 @@ from pathlib import Path
 # every recovered block-exhaustion event.
 # The validator accepts ALL dialects — every versioned field is
 # optional, so committed v1-v14 artifacts (no version stamp / no
-# health / overlap / attrib / wall / fault / request / monitor /
+# health / overlap / wall / fault / request / monitor /
 # straggler / lifecycle / speculation / routing / tracing / profile /
 # numerics / prefix / memory fields) keep validating unchanged.
 SCHEMA_VERSION = 15
@@ -309,8 +308,8 @@ _SCALE_OPTIONAL = {"replica": str, "reason": str, "n_replicas": int,
 # `folded` maps "frame;frame;..." strings to exact sample counts
 # (top-K; `other` is the exact remainder so counts still sum to
 # `samples`), `phases` maps innermost span-tag names to counts,
-# `step_samples` counts samples inside a step/batch span (the
-# attrib_host_frac cross-check), `max_gap_ms` is the worst
+# `step_samples` counts samples inside a step/batch span,
+# `max_gap_ms` is the worst
 # inter-sample gap (the GIL-safety bound the tests pin)
 _PROFILE_OPTIONAL = {"step_samples": int, "hz": _NUM, "top_k": int,
                      "folded": dict, "other": int, "phases": dict,
@@ -332,13 +331,6 @@ _STEP_TELEMETRY = {
     "health_groups": dict,
     # --- schema v3: comm/compute-overlap fields (parallel/overlap.py)
     "exposed_comm_frac": _NUM, "overlap_ratio": _NUM, "overlap": bool,
-    # --- schema v4: time-attribution waterfall (telemetry/
-    # attribution.py) — fractions of the measured (fenced) step time
-    "attrib_compute_frac": _NUM, "attrib_mxu_frac": _NUM,
-    "attrib_comm_exposed_frac": _NUM, "attrib_bubble_frac": _NUM,
-    "attrib_host_frac": _NUM, "attrib_unexplained_frac": _NUM,
-    "attrib_t_step_ms": _NUM, "attrib_rates_source": str,
-    "attrib_compute_scale": _NUM,
     # --- schema v13: numerics-observatory fields (telemetry/
     # numerics.py) — the fp8 pack's host-side reduction + the
     # shadow-parity series vs the frozen master-precision oracle

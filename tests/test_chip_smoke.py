@@ -81,7 +81,7 @@ def test_train_phase_holds_at_toy_width(tmp_path):
 
 
 def test_serve_phase_holds_at_toy_width(tmp_path):
-    res = chip_smoke.run_serve_phase("toy", tmp_path, config="flash_int8",
+    res = chip_smoke.run_serve_phase("toy", tmp_path, config="int8",
                                      platform="cpu", env=CPU_ENV)
     assert res["ok"], res["failed"]
     w1, w2 = chip_smoke.make_requests("toy", 21)

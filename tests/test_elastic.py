@@ -225,9 +225,12 @@ def test_gang_member_failure_restarts_whole_gang(tmp_path):
         runs.write_text(str(int(runs.read_text()) + 1
                             if runs.exists() else 1))
         if pid == 1 and not pathlib.Path(r'%s').exists():
+            while not runs.with_name('runs_0').exists():
+                time.sleep(0.01)    # member 0 has counted its first run
             pathlib.Path(r'%s').write_text('x')
             sys.exit(3)     # member 1 dies on the first attempt
-        time.sleep(0.3)     # member 0 would outlive member 1's crash
+        if pid == 0 and runs.read_text() == '1':
+            time.sleep(60)  # outlives member 1's crash: killed with the gang
     """ % (tmp_path, marker, marker))
     sup = GangSupervisor(cmd, 2,
                          RestartPolicy(max_restarts=2, backoff=0.01),

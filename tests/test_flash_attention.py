@@ -400,8 +400,8 @@ def test_ring_chunk_numerics_envelope():
     above it on-chip (BENCH_r05). The bf16-chunk variant is measured
     alongside to prove the carry — not some unrelated drift — is what
     closes the gap. BASELINE.md 'ring-chunk numerics envelope'
-    documents the mechanism; bench.py certifies the same bound on the
-    compiled kernels every bench round."""
+    documents the mechanism; `chip_smoke.py`'s kernels phase holds the
+    compiled kernels to the same kind of bound on the chip."""
     err_f32, floor = _ring_pair_err(jnp.float32)
     err_bf16, _ = _ring_pair_err(None)  # old behavior: chunk o in bf16
     assert err_f32 <= 1.25 * floor, (

@@ -169,27 +169,3 @@ def test_mse_global_batch_scaling_invariant():
 def test_ops_are_jittable(fn_args):
     fn, args = fn_args
     np.testing.assert_allclose(jax.jit(fn)(*args), fn(*args), rtol=1e-6)
-
-
-def test_blocked_matmul_matches_xla():
-    """The narrow-K Pallas matmul (ops/matmul.py) is exact vs jnp.dot
-    in f32 and close in bf16 (f32 accumulator), across odd block
-    splits."""
-    import jax.numpy as jnp
-
-    from shallowspeed_tpu.ops.matmul import blocked_matmul
-
-    rng = np.random.default_rng(0)
-    x = rng.normal(size=(256, 128)).astype(np.float32)
-    y = rng.normal(size=(128, 384)).astype(np.float32)
-    ref = x @ y
-    out = blocked_matmul(jnp.asarray(x), jnp.asarray(y),
-                         bm=64, bk=32, bn=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5,
-                               atol=1e-4)
-    xb = jnp.asarray(x, jnp.bfloat16)
-    yb = jnp.asarray(y, jnp.bfloat16)
-    outb = blocked_matmul(xb, yb, bm=128, bk=128, bn=384,
-                          interpret=True)
-    np.testing.assert_allclose(np.asarray(outb, np.float32), ref,
-                               rtol=0.1, atol=0.5)

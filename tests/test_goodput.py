@@ -1,12 +1,10 @@
-"""Goodput ledger + reducer + bench regression gate (telemetry/
-goodput.py, regress.py) and the driver/elastic integrations.
+"""Goodput ledger + reducer (telemetry/goodput.py) and the
+driver/elastic integrations.
 
-The two acceptance pins live here:
-- a supervisor kill/restart run whose ledger accounts for >= 95% of
-  wall clock, with restart downtime itemized and cross-checked against
-  the child processes' own JSONL wall stamps;
-- the `--regress` gate passing on a steady trajectory and
-  demonstrably failing on a synthetic regression.
+The acceptance pin lives here: a supervisor kill/restart run whose
+ledger accounts for >= 95% of wall clock, with restart downtime
+itemized and cross-checked against the child processes' own JSONL wall
+stamps.
 """
 
 import json
@@ -206,65 +204,6 @@ def test_supervisor_autodetects_child_log_file(tmp_path):
     assert Supervisor(["prog"], log=lambda *a: None).ledger_file is None
 
 
-# ------------------------------------------------- bench --regress gate
-
-
-def _write_trajectory(dirpath, mfus):
-    """One BENCH_rNN.json per entry of `mfus`, in the driver's record
-    layout (round number `n`, headline metrics under `parsed`)."""
-    for n, mfu in enumerate(mfus, start=1):
-        (dirpath / f"BENCH_r{n:02d}.json").write_text(json.dumps(
-            {"n": n, "parsed": {"value": 3.6e6 + 1e4 * n,
-                                "vs_baseline": 90.0 + n,
-                                "transformer_mfu": mfu}}))
-
-
-def test_regress_gate_passes_on_steady_trajectory(tmp_path, capsys):
-    from shallowspeed_tpu.telemetry.regress import main as rmain
-
-    _write_trajectory(tmp_path, [0.552, 0.561, 0.575, 0.582, 0.566])
-    assert rmain([str(tmp_path)]) == 0
-    out = capsys.readouterr().out
-    assert "regress gate: OK" in out and "5 round(s)" in out
-
-
-def test_regress_gate_fails_on_synthetic_regression(tmp_path, capsys):
-    from shallowspeed_tpu.telemetry.regress import main as rmain
-
-    # the gate only judges the NEWEST round: ~29% below the median
-    _write_trajectory(tmp_path, [0.552, 0.561, 0.575, 0.582, 0.566,
-                                 0.40])
-    assert rmain([str(tmp_path)]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "transformer_mfu" in out
-
-
-def test_regress_band_widens_with_recorded_spread():
-    from shallowspeed_tpu.telemetry import regress
-
-    entries = [{"n": i, "path": f"r{i}", "parsed":
-                {"value": 100.0, "spread": {"tpu": 0.08}}}
-               for i in range(1, 4)]
-    # 20% drop: beyond the 15% floor but inside 3x the recorded 8%
-    entries.append({"n": 4, "path": "r4",
-                    "parsed": {"value": 80.0,
-                               "spread": {"tpu": 0.08}}})
-    probs, _ = regress.check_trajectory(entries)
-    assert probs == []
-    # without the recorded spread the floor (15%) catches it
-    for e in entries:
-        e["parsed"].pop("spread")
-    probs, _ = regress.check_trajectory(entries)
-    assert len(probs) == 1 and "value" in probs[0]
-
-
-def test_regress_vacuous_on_short_trajectory(tmp_path):
-    from shallowspeed_tpu.telemetry.regress import main as rmain
-
-    _write_trajectory(tmp_path, [0.552])
-    assert rmain([str(tmp_path)]) == 0
-
-
 # ------------------------------- driver integration + xprof smoke test
 
 
@@ -278,8 +217,8 @@ def test_driver_goodput_profile_and_decode_lines(tmp_path, driver):
     event, and — with `--profile host+device` on the same run — the
     continuous profiling plane riding the SAME device-capture entry
     point (`profiler.device_trace_ctx`) as --profile-dir, streaming
-    schema-v12 profile events next to the spans-level attribution
-    fields, all schema-valid."""
+    schema-v12 profile events next to the spans-level step fields,
+    all schema-valid."""
     import train_lm
 
     log = tmp_path / "metrics.jsonl"
@@ -308,7 +247,7 @@ def test_driver_goodput_profile_and_decode_lines(tmp_path, driver):
     kinds = {r["kind"] for r in recs if r["event"] == "ledger"}
     assert {"init", "val", "ckpt_save"} <= kinds, kinds
     steps = [r for r in recs if r["event"] == "step"]
-    assert steps and "attrib_unexplained_frac" in steps[-1], steps[-1]
+    assert steps and "hbm_live_mib" in steps[-1], steps[-1]
     gen = [r for r in recs if r["event"] == "generate"]
     assert len(gen) == 1 and gen[0]["tokens_per_sec"] > 0
     assert gen[0]["hbm_util"] is None  # CPU: no invented HBM peak

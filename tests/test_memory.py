@@ -145,9 +145,12 @@ def test_registry_stale_and_broken_resolvers_cost_zero():
 def test_top_live_arrays_names_owners():
     big = jax.device_put(np.ones((256, 256), np.float32))   # 256 KiB
     memory.register_owner("test.big", lambda: big)
-    top = memory.top_live_arrays(3)
-    assert 1 <= len(top) <= 3
-    assert top[0]["nbytes"] >= top[-1]["nbytes"]    # sorted descending
+    assert 1 <= len(memory.top_live_arrays(3)) <= 3
+    # every live array: a worker that has run other tests holds larger
+    # ones than this 256 KiB, and the top three need not include it
+    top = memory.top_live_arrays(len(jax.live_arrays()))
+    sizes = [r["nbytes"] for r in top]
+    assert sizes == sorted(sizes, reverse=True)
     mine = [r for r in top if r["owner"] == "test.big"]
     assert mine and mine[0]["shape"] == [256, 256]
     assert mine[0]["dtype"] == "float32"

@@ -1,6 +1,5 @@
 """Numerics observatory (round 18): runtime precision telemetry over
-the fp8-e4m3 trainer, shadow-parity gating, and the attribution-gated
-rollout contract.
+the fp8-e4m3 trainer and shadow-parity gating.
 
 Coverage map:
 - the numerics pack rides the ONE compiled fp8 step (zero new
@@ -14,14 +13,10 @@ Coverage map:
   without an amax history;
 - schema v13: num_* step lines validate (good AND bad), pre-v13 lines
   keep validating;
-- attribution prices float8-operand dots at FP8_FLOPS_RATIO (and
-  `flops.device_peak_flops` doubles the fp8 peak);
+- `flops.device_peak_flops` doubles the fp8 peak;
 - the --goodput numerics block + `shadow_parity` ledger exclusion;
 - the static prover's calibration ranges contain measured RUNTIME
   amax telemetry (the certificate's conditioning holds live);
-- bench_fp8: the fp8-on transformer case shrinks attrib_mxu_frac vs
-  the bf16 baseline inside the unexplained/parity envelopes, and the
-  headline is banded by --regress;
 - the end-to-end drill (tier-1): a seeded scale_poison run under
   --health guard detects the collapse at the poisoned step, dumps a
   flight record + profiler capture, falls back to bf16, and finishes
@@ -33,7 +28,6 @@ import math
 import os
 import subprocess
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -308,36 +302,7 @@ def test_step_fields_from_live_run_validate():
     assert "num_scale_min" in line and "num_parity_loss_rel" in line
 
 
-# ------------------------------------------------- attribution pricing
-
-
-def test_attribution_prices_fp8_dots():
-    from shallowspeed_tpu.ops.matmul import fp8_dense
-    from shallowspeed_tpu.telemetry.attribution import (FP8_FLOPS_RATIO,
-                                                        roofline_of_jaxpr,
-                                                        roofline_seconds)
-
-    if not hasattr(jnp, "float8_e4m3fn"):
-        pytest.skip("no float8 dtype in this jax build")
-    x = jnp.ones((16, 32), jnp.float32)
-    w = jnp.ones((32, 8), jnp.float32)
-
-    roof = roofline_of_jaxpr(jax.make_jaxpr(
-        lambda a, b: fp8_dense(a, b, jnp.float32(0.1)))(x, w))
-    fl = roof["flops_global"]
-    assert fl >= 2 * 16 * 32 * 8
-    assert roof["flops_fp8_global"] == fl  # every dot is quantized here
-    plain = roofline_of_jaxpr(jax.make_jaxpr(
-        lambda a, b: a @ b)(x, w))
-    assert plain["flops_fp8_global"] == 0
-
-    # flop-bound rates: the fp8 subset runs FP8_FLOPS_RATIO x faster
-    rates = {"flops": 1e6, "hbm": 1e12, "ici": 1e12}
-    quant = roofline_seconds(
-        {"flops_global": 1000, "flops_fp8_global": 1000}, rates)
-    base = roofline_seconds({"flops_global": 1000}, rates)
-    assert quant["mxu_s"] == pytest.approx(
-        base["mxu_s"] / FP8_FLOPS_RATIO)
+# ------------------------------------------------------ the fp8 peak
 
 
 def test_device_peak_flops_fp8_doubles_bf16():
@@ -467,40 +432,7 @@ def test_static_calibration_ranges_contain_runtime_amax():
     assert min(eng.health_snapshot()["fp8_scale"]) > COLLAPSE_FLOOR
 
 
-# ------------------------------------------------ the bench gate
-
-
-def test_bench_fp8_attribution_gate():
-    """The rollout pin: the fp8-on transformer case's attrib_mxu_frac
-    sits STRICTLY below the bf16 baseline's, unexplained stays inside
-    the 0.10 pin, the one-batch parity is inside the shadow envelope,
-    and the headline ratio is banded by --regress."""
-    import bench
-    from shallowspeed_tpu.telemetry import attribution as attr
-    from shallowspeed_tpu.telemetry.regress import METRICS
-
-    for _attempt in range(6):
-        out = bench.bench_fp8()
-        if "fp8_error" in out:
-            pytest.skip(out["fp8_error"])
-        cases = out["fp8_attribution"]
-        if (cases["bf16"]["attrib_unexplained_frac"] <= 0.10
-                and cases["fp8"]["attrib_unexplained_frac"] <= 0.10):
-            break
-        # shared CI host: step times drift between the fit and frozen
-        # windows often enough that one attempt flakes (the same
-        # bounded-retry contract as test_attribution)
-        time.sleep(0.5)
-        attr.recalibrate()
-    assert cases["fp8"]["attrib_mxu_frac"] < cases["bf16"]["attrib_mxu_frac"]
-    assert out["fp8_mxu_shrink"] > 1.0
-    assert cases["fp8"]["fp8_dot_flops"] > 0
-    assert cases["bf16"]["fp8_dot_flops"] == 0
-    assert cases["bf16"]["attrib_unexplained_frac"] <= 0.10
-    assert cases["fp8"]["attrib_unexplained_frac"] <= 0.10
-    assert cases["parity_loss_rel"] <= PARITY_LOSS_BUDGET
-    band, spread = METRICS["fp8_mxu_shrink"]
-    assert 0 < band < 1 and spread is None
+# ------------------------------------------ the transformer's fp8 flag
 
 
 def test_transformer_fp8_dense_config():

@@ -4,8 +4,8 @@ The reference cannot execute in this image (mpi4py/mpirun absent, and its
 OpenML fetch needs egress), so the strongest available parity check is
 the one its own DDP script uses — absolute weight divergence against an
 independently-executed implementation of the same math
-(`/root/reference/scripts/DDP_PyTorch_MNIST.py:159-167`). `bench.py`'s
-NumPy baseline step IS the reference's math (same forward, hand-written
+(`/root/reference/scripts/DDP_PyTorch_MNIST.py:159-167`). The NumPy
+step below IS the reference's math (same forward, hand-written
 backward, microbatch grad accumulation over the GLOBAL-batch-scaled MSE
 grad, SGD; `functional.py`, `layers.py`, `optimizer.py`); here we train
 both it and the jitted `FusedDPEngine` from the SAME seeded init on the
@@ -14,12 +14,58 @@ same batches and require the weights to stay together.
 
 import numpy as np
 
-from bench import GBS, LAYER_SIZES, LR, N_MU, numpy_baseline_step_fn
-
 from shallowspeed_tpu.engine import FusedDPEngine
-from shallowspeed_tpu.models.mlp import MLPStage
+from shallowspeed_tpu.models.mlp import MLPStage, init_stage_params
 from shallowspeed_tpu.optim import SGD
 from shallowspeed_tpu.parallel.mesh import make_mesh
+
+# the reference's training config (`/root/reference/train.py:56-59,98,107`)
+LAYER_SIZES = [784, 128, 127, 126, 125, 124, 123, 10]
+GBS = 128
+N_MU = 4
+LR = 0.006
+
+
+def numpy_baseline_step_fn():
+    """Reference-equivalent pure-NumPy training step (measured, not copied:
+    same math as shallowspeed_tpu.ops.functional on the NumPy substrate)."""
+    params = [{k: np.asarray(v) for k, v in layer.items()}
+              for layer in init_stage_params(LAYER_SIZES)]
+    n = len(params)
+
+    def step(xs, ys):  # xs: (N_MU, mubs, 784); mutates `params` in place
+        grads = [{"W": np.zeros_like(p["W"]), "b": np.zeros_like(p["b"])}
+                 for p in params]
+        for mu in range(N_MU):
+            x, t = xs[mu], ys[mu]
+            acts = [x]
+            masks = []
+            h = x
+            for i, p in enumerate(params):
+                z = h @ p["W"].T + p["b"]
+                if i < n - 1:
+                    masks.append(z > 0)
+                    h = np.maximum(z, 0.0)
+                else:
+                    h = z
+                acts.append(h)
+            e = np.exp(h - h.max())
+            probs = e / (e.sum(axis=1, keepdims=True) + 1e-7)
+            dout = -2.0 * (t - probs) / GBS
+            g = probs * dout
+            dout = g - probs * g.sum(axis=-1, keepdims=True)
+            for i in range(n - 1, -1, -1):
+                if i < n - 1:
+                    dout = dout * masks[i]
+                grads[i]["W"] += dout.T @ acts[i]
+                grads[i]["b"] += dout.sum(axis=0, keepdims=True)
+                dout = dout @ params[i]["W"]
+        for p, g in zip(params, grads):
+            p["W"] -= LR * g["W"]
+            p["b"] -= LR * g["b"]
+
+    step.params = params
+    return step
 
 
 def make_data(seed, n_batches):

@@ -81,6 +81,26 @@ def test_schema_rejects_malformed_lines():
          "tokens_per_sec": 10.0, "recompiles": 0.5}) != []
 
 
+def test_schema_v4_ledger_lines_validate():
+    assert schema.SCHEMA_VERSION >= 4  # later versions extend, never narrow
+    step = {"event": "step", "step": 3, "loss": 1.0,
+            "tokens_per_sec": 10.0, "wall": 123.4}
+    assert schema.validate_line(step) == []
+    led = {"event": "ledger", "kind": "val", "seconds": 1.25,
+           "wall": 123.4, "t": 0.5}
+    assert schema.validate_line(led) == []
+    assert schema.validate_line({"event": "ledger"})  # kind is required
+    assert schema.validate_line({"event": "ledger", "kind": "x",
+                                 "seconds": "long"})
+    gen = {"event": "generate", "tokens_per_sec": 55.0,
+           "bytes_per_token": 1024, "hbm_util": None}
+    assert schema.validate_line(gen) == []
+    # v1-v3 lines (no wall/ledger) keep validating
+    old = {"event": "step", "step": 0, "loss": 2.0,
+           "tokens_per_sec": 5.0}
+    assert schema.validate_line(old) == []
+
+
 def test_off_level_reaches_the_ring_and_nothing_else(monkeypatch,
                                                      tmp_path):
     """`--telemetry off`: a span is recorded in the ring, and that is

@@ -565,7 +565,7 @@ def train(args) -> float:
         args.telemetry = "steps"  # --trace-dir implies tracing
     tracer = tele.configure(trace_dir=args.trace_dir or None,
                             level=args.telemetry)
-    telem = (tele.RunTelemetry(engine, tracer, dtype="f32")
+    telem = (tele.RunTelemetry(engine, tracer)
              if args.telemetry != "off" else None)
     if telem is not None:
         telem.ledger = ledger

@@ -689,7 +689,7 @@ def train(args) -> float:
                             logit_softcap=args.logit_softcap,
                             attn_window=args.attn_window)
     if jax.default_backend() == "tpu" and 256 < args.d_model <= 1024:
-        # measured on this v5e (scripts/bench_matmul.py, BASELINE.md):
+        # measured on a v5e (BASELINE.md's matmul table):
         # ops with K and N both <= 1024 run far below MXU peak (fixed
         # per-pass costs dominate), so d_model <= 1024 configs cap out
         # around 26-35% MFU while d_model >= 2048 reaches ~57%. Tiny
@@ -865,8 +865,7 @@ def train(args) -> float:
         args.telemetry = "steps"  # --trace-dir implies tracing
     tracer = tele.configure(trace_dir=args.trace_dir or None,
                             level=args.telemetry)
-    telem = (tele.RunTelemetry(engine, tracer,
-                               dtype="bf16" if args.bf16 else "f32")
+    telem = (tele.RunTelemetry(engine, tracer)
              if args.telemetry != "off" else None)
     if telem is not None:
         telem.ledger = ledger  # loss totals ride telemetry.json too
@@ -910,7 +909,7 @@ def train(args) -> float:
     # continuous profiling plane (round 17): host stack sampler into
     # the same metrics JSONL + trigger-armed capture windows; the
     # tracer's step/phase spans tag each sample via trace.PHASE_HOOKS,
-    # so `--profile <log>` decomposes attrib_host_frac by name
+    # so `--profile <log>` decomposes the host's time by name
     from shallowspeed_tpu.telemetry import profiler as profiler_mod
 
     plane = profiler_mod.from_args(args, metrics)
@@ -1245,24 +1244,6 @@ def train(args) -> float:
                                 f"RECOMPILES {tfields['recompiles']}")
                         if parts:
                             rprint("             " + "  ".join(parts))
-                        if "attrib_unexplained_frac" in tfields:
-                            wf = [f"compute "
-                                  f"{tfields['attrib_compute_frac']:.0%}"]
-                            if "attrib_comm_exposed_frac" in tfields:
-                                wf.append(
-                                    f"comm {tfields['attrib_comm_exposed_frac']:.0%}")
-                            if "attrib_bubble_frac" in tfields:
-                                wf.append(
-                                    f"bubble {tfields['attrib_bubble_frac']:.0%}")
-                            if "attrib_host_frac" in tfields:
-                                wf.append(
-                                    f"host {tfields['attrib_host_frac']:.0%}")
-                            rprint(
-                                "             waterfall "
-                                + " + ".join(wf) + " -> unexplained "
-                                + f"{tfields['attrib_unexplained_frac']:.0%}"
-                                + f"  (t_step "
-                                  f"{tfields['attrib_t_step_ms']:.0f} ms)")
                     if (telem is not None
                             and args.telemetry == "spans"
                             and args.pp > 1
