@@ -426,9 +426,9 @@ def build_serving_decode(budget: int = DEFAULT_BUDGET) -> TargetProbe:
     eng.submit(np.arange(6, dtype=np.int32) % cfg.vocab, 4)
     eng.run()
 
-    def tick(params, pools, tok, pos, bt, temp, seeds, idx):
+    def tick(params, pools, tok, pos, bt, temp, seeds, idx, prev, ahead):
         return _decode_tick(params, pools, tok, pos, bt, temp, seeds,
-                            idx, cfg=cfg, top_k=0, top_p=0.0)
+                            idx, prev, ahead, cfg=cfg, top_k=0, top_p=0.0)
 
     s = eng.max_slots
     w = 4
@@ -441,9 +441,11 @@ def build_serving_decode(budget: int = DEFAULT_BUDGET) -> TargetProbe:
                     jax.ShapeDtypeStruct((s, w), np.int32),
                     jax.ShapeDtypeStruct((s,), np.float32),
                     jax.ShapeDtypeStruct((s,), np.uint32),
-                    jax.ShapeDtypeStruct((s,), np.int32)),
+                    jax.ShapeDtypeStruct((s,), np.int32),
+                    jax.ShapeDtypeStruct((s,), np.int32),
+                    jax.ShapeDtypeStruct((s,), np.bool_)),
                    ("params", "pools", "tok", "pos", "bt", "temp",
-                    "seeds", "idx")),
+                    "seeds", "idx", "prev", "ahead")),
     ]
     return probe.seal()
 

@@ -81,6 +81,13 @@ head-of-line behind the longest. This engine serves a STREAM:
     mask can admit them (the prefill-padding argument). Zero new
     executables: drafts are data in rows that already executed empty.
 
+- **One tick in flight.** The decode loop dispatches tick N+1 before
+  it fetches tick N's tokens (`ServingEngine._decode_step`): the host
+  knows N+1's rows without them, each row's input token is read from
+  N's output on the device, and the fetch and the bookkeeping of N run
+  while the device runs N+1. The host's view trails the device by one
+  tick; the tokens are the same.
+
 Stream parity: sampling uses the SAME per-request key schedule as
 `generate()` — token i of a request with sampling seed s draws from
 `fold_in(PRNGKey(s), i)` — and the paged attention computes what
@@ -231,13 +238,22 @@ def _ffn_counted(p, x, cfg, h, live):
 
 @partial(jax.jit, static_argnames=("cfg", "top_k", "top_p"),
          donate_argnums=(1,))
-def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
-                 cfg: T.TransformerConfig, top_k: int, top_p: float):
+def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
+                 ahead, *, cfg: T.TransformerConfig, top_k: int,
+                 top_p: float):
     """One compiled decode tick over the whole slot batch.
 
     tok/pos/temp/seeds/idx: (S,) per-slot last token, write position,
     sampling state; bt: (S, W) block tables (W is the bucketed width —
-    the ONLY shape that varies across ticks). Each slot writes its
+    the ONLY shape that varies across ticks). The decode loop keeps
+    one tick in flight (`ServingEngine._decode_step`), so the host may
+    not have a row's last token yet: `prev` is the previous tick's
+    `nxt` as it left that program (a device array the host never
+    fetched for this), and a row whose `ahead` flag is set reads
+    `prev[row]` where the others read the host's `tok[row]` (a request
+    whose prefill just sampled its first token, every row when nothing
+    is in flight). A request keeps its slot, so the row is the same in
+    both ticks. Each slot writes its
     token's K/V at (bt[pos // bs], pos % bs) and attends over the
     blocks of its table that its position (and window) admit, read
     from the pool where they lie (`paged_flash_decode`: no gathered
@@ -263,6 +279,7 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, *,
     read, so row j's attention sees rows i < j of the same tick — the
     single-pass verify."""
     params = T.cast_params(params, cfg.compute_dtype)
+    tok = jnp.where(ahead, prev, tok)
     s_rows = tok.shape[0]
     bs = pool_block_size(pools[0])
     quant = "k_s" in pools[0]
@@ -388,7 +405,7 @@ class _Req:
                  "generated", "n_preempt", "phase", "slot", "ctx",
                  "table", "written", "admit_seq", "admit_t",
                  "queued_at", "wait_s", "first_tok_t", "last_tok",
-                 "timeline", "track", "trace_t0", "n_drafted",
+                 "in_flight", "timeline", "track", "trace_t0", "n_drafted",
                  "n_accepted", "ctx_ids", "spec_idx",
                  "trace", "span", "parent", "attempt",
                  "hit_blocks", "skipped_tok", "cow")
@@ -413,6 +430,10 @@ class _Req:
         self.wait_s = 0.0               # queue time over every stint
         self.first_tok_t = None
         self.last_tok = 0
+        # tokens of this request still on the device: 1 while it has a
+        # row in the tick in flight, whose token the host has not
+        # fetched yet (`ServingEngine._decode_step`), else 0
+        self.in_flight = 0
         # lifecycle tracing (schema v8): the host-side phase timeline,
         # plus this request's named Chrome-trace track
         self.timeline: list[dict] = []
@@ -522,7 +543,11 @@ class ServingEngine:
         # the JSONL "lifecycle" stream is the out-of-process surface
         self.timelines: dict[str, list] = {}
         self.counters = {"submitted": 0, "finished": 0, "preempted": 0,
-                         "ticks": 0, "prefill_chunks": 0,
+                         "ticks": 0,
+                         # ticks dispatched while the tick before
+                         # them was still in flight (its tokens on
+                         # the device, not yet on the host)
+                         "ticks_ahead": 0, "prefill_chunks": 0,
                          "shed_toggles": 0, "spec_drafted": 0,
                          "spec_accepted": 0, "prefix_lookups": 0,
                          "prefix_hits": 0, "prefix_skipped_tokens": 0,
@@ -597,6 +622,13 @@ class ServingEngine:
         # the jit cache and stamp nothing
         self._tick_widths: set[int] = set()
         self._last_width = 0
+        # the decode tick in flight (`_decode_step`): (its requests,
+        # their drafts, its `nxt` and routed counts, both still on the
+        # device), or None. `_no_tok` stands in for `nxt` in a tick
+        # dispatched with nothing in flight, whose rows all read the
+        # host's tokens.
+        self._flight = None
+        self._no_tok = jnp.zeros((self.max_slots,), jnp.int32)
 
     # ------------------------------------------------------ public API
 
@@ -697,7 +729,16 @@ class ServingEngine:
         across prefilling requests), one decode tick over every
         decoding slot. Returns whether any work ran — decodes advance
         every step even while a long prompt prefills, which is the
-        chunked-prefill no-stall contract."""
+        chunked-prefill no-stall contract.
+
+        The decode tick a step dispatches stays IN FLIGHT when the
+        step returns: its tokens are fetched and booked by the next
+        step, after that step has dispatched its own tick
+        (`_decode_step`). So the host's view (`poll`, the records, a
+        freed slot) trails the device by one tick, and a step that
+        finds a tick in flight and nothing left to dispatch lands it
+        and counts as work: `run()` and a `drain()` loop step until
+        `pending()` is 0 and so deliver every token."""
         plan = self.chaos_plan if self.chaos_plan is not None \
             else chaos.active()
         # the scheduler's host phases as spans (telemetry/trace.py):
@@ -1065,47 +1106,108 @@ class ServingEngine:
             self._append_token(req, tok)
 
     def _decode_step(self) -> bool:
-        if not any(r is not None and r.phase == "decode"
-                   for r in self.slots):
+        """One turn of the decode loop, which keeps ONE tick in flight.
+
+        Tick N+1 is prepared and dispatched BEFORE tick N's tokens are
+        fetched: the host knows which rows N+1 has without them
+        (positions advance by one, a request finishes by count, tables
+        grow from positions), and each row's input token is taken from
+        N's `nxt` on the device (`_decode_tick`'s `prev` / `ahead`).
+        Then N is landed, fetched (`decode.fetch`, which waits for the
+        tick BEFORE the one this span dispatched) and emitted, while
+        the device runs N+1. Nothing is speculated: only when the host
+        learns a token changes, never which token it is.
+
+        Who lands the tick in flight: the next turn, as above (also
+        when it has nothing to dispatch: the last tick of a drain);
+        `_ensure_block` when the pool runs out, before it evicts.
+        Draft rows (`spec_k > 0`) are proposed from the last token on
+        the host, so with them every tick is landed in the turn that
+        dispatched it: the same loop with nothing in flight."""
+        had = self._flight is not None
+        if not had and not any(
+                r is not None and r.phase == "decode" for r in self.slots):
             return False
         tr = tracer()
         with tr.span("decode") as sp:
             with tr.span("decode.prep"):
                 prep = self._decode_prep()
-            if prep is None:       # every decoder was evicted for blocks
-                return False
-            actives, drafts, rows = prep
-            pos, bt = rows[1], rows[2]
-            sp.set(n_active=len(actives), width=bt.shape[1])
-            with tr.span("decode.dispatch"):
-                nxt, self.pools, counts = _decode_tick(
-                    self.params, self.pools, *rows, cfg=self.cfg,
-                    top_k=self.top_k, top_p=self.top_p)
-            with tr.span("decode.fetch"):
-                # one wait for both: the counts leave the device beside
-                # the tokens, not in a second round trip after them
-                nxt, counts = jax.device_get((nxt, counts))
-            attrs = self._layer_attrs(
-                counts, sum(r.written + 1 for r in actives))
-            attrs["blocks_read"] = self._blocks_walked(pos)
-            attrs["blocks_table"] = bt.size
+            # what is in flight NOW: prep lands it itself where it
+            # ran out of blocks
+            ahead = int(prep is not None and self._flight is not None)
+            sp.set(ahead=ahead)
+            new = None
+            if prep is not None:
+                actives, drafts, rows = prep
+                pos, bt = rows[1], rows[2]
+                with tr.span("decode.dispatch"):
+                    nxt, self.pools, counts = _decode_tick(
+                        self.params, self.pools, *rows, cfg=self.cfg,
+                        top_k=self.top_k, top_p=self.top_p)
+                new = (actives, drafts, nxt, counts)
+                self.counters["ticks_ahead"] += ahead
+                read = {"blocks_read": self._blocks_walked(pos),
+                        "blocks_table": bt.size}
+                sp.set(n_active=len(actives), width=bt.shape[1], **read)
+                for name, value in read.items():
+                    self.counters[name] += value
+            if self.spec_k > 0:
+                # the next drafts need this tick's tokens on the host:
+                # it is the one to land, and nothing stays in flight
+                self._flight, new = new, None
+            self._land(sp)
+            if new is not None:
+                self._flight = new
+                for r in new[0]:
+                    r.in_flight = 1
+        # no work only where every decoder was evicted for blocks and
+        # prep landed no tick on the way
+        return had or prep is not None
+
+    def _land(self, sp=None) -> bool:
+        """Fetch and book the tick in flight, if there is one: its
+        tokens and routed counts leave the device in one wait, the
+        layers' attrs of THAT tick go on `sp` (the `decode` span open
+        now, which may have dispatched the tick after it) and into the
+        counters, `_decode_emit` appends. Returns whether a tick was
+        landed."""
+        if self._flight is None:
+            return False
+        (actives, drafts, nxt, counts), self._flight = self._flight, None
+        tr = tracer()
+        with tr.span("decode.fetch"):
+            # one wait for both: the counts leave the device beside
+            # the tokens, not in a second round trip after them
+            nxt, counts = jax.device_get((nxt, counts))
+        attrs = self._layer_attrs(
+            counts, sum(r.written + 1 for r in actives))
+        if sp is not None:
             sp.set(**attrs)
-            for name, value in attrs.items():
-                self.counters[name] += value
-            with tr.span("decode.emit"):
-                self._decode_emit(actives, drafts, nxt)
+        for name, value in attrs.items():
+            self.counters[name] += value
+        with tr.span("decode.emit"):
+            self._decode_emit(actives, drafts, nxt)
         return True
 
     def _decode_prep(self):
-        """The tick's host-side inputs: (decoding requests, their
-        speculative drafts, `_decode_tick`'s per-row arrays in its own
-        order), or None when no request is left to decode."""
+        """The next tick's host-side inputs: (its requests, their
+        speculative drafts, `_decode_tick`'s per-row arguments in its
+        own order), or None when no request has a row to take.
+
+        Works from the host's positions PLUS what is in flight: a
+        request with a row in the tick in flight (`in_flight`) writes
+        one position further and samples one index further than the
+        host has booked, and reads its token from the device; one whose
+        token in flight is its last takes no row. Which rows are live
+        is exact either way."""
+        owed = lambda r: len(r.generated) + r.in_flight < r.max_new
         for req in [r for r in self.slots
                     if r is not None and r.phase == "decode"]:
-            if req.slot is not None:          # not evicted meanwhile
+            # not evicted meanwhile, nor finished by a landing
+            if req.slot is not None and owed(req):
                 self._ensure_block(req)
         actives = [r for r in self.slots
-                   if r is not None and r.phase == "decode"]
+                   if r is not None and r.phase == "decode" and owed(r)]
         if not actives:
             return None
         s = self.max_slots
@@ -1131,15 +1233,17 @@ class ServingEngine:
         temp = np.zeros(s, np.float32)
         seeds = np.zeros(s, np.uint32)
         idx = np.zeros(s, np.int32)
+        ahead = np.zeros(s, np.bool_)
         w = table_width(max(len(r.table) for r in actives),
                         self.table_bucket)
         bt = np.full((s, w), SCRATCH_BLOCK, np.int32)
         for r in actives:
             tok[r.slot] = r.last_tok
-            pos[r.slot] = r.written
+            ahead[r.slot] = r.in_flight
+            pos[r.slot] = r.written + r.in_flight
             temp[r.slot] = r.temp
             seeds[r.slot] = r.seed
-            idx[r.slot] = len(r.generated)
+            idx[r.slot] = len(r.generated) + r.in_flight
             bt[r.slot, :len(r.table)] = r.table
         for r, assigned in drafts.values():
             # draft row j: the j-th draft token at position written+j,
@@ -1167,7 +1271,9 @@ class ServingEngine:
                                  tick=self.counters["ticks"])
             self._tick_widths.add(w)
         self._last_width = w
-        return actives, drafts, (tok, pos, bt, temp, seeds, idx)
+        prev = self._no_tok if self._flight is None else self._flight[2]
+        return actives, drafts, (tok, pos, bt, temp, seeds, idx, prev,
+                                 ahead)
 
     def _decode_emit(self, actives, drafts, nxt) -> None:
         """Book the tick's tokens: counters, appends (which finish
@@ -1190,6 +1296,7 @@ class ServingEngine:
                 self.counters["spec_drafted"] += len(assigned)
                 self._win_drafted += len(assigned)
             tok_next = int(nxt[r.slot])
+            r.in_flight = 0
             r.written += 1
             self._append_token(r, tok_next)
             emitted += 1
@@ -1285,15 +1392,24 @@ class ServingEngine:
         return d
 
     def _ensure_block(self, req) -> bool:
-        """Grow `req`'s table to cover its next write position,
+        """Grow `req`'s table to cover its next write position (one
+        past the host's, for a request with a token in flight),
         evicting the newest-admitted running request on OOM (possibly
-        `req` itself). Returns whether `req` is still running."""
-        while req.written // self.block_size >= len(req.table):
+        `req` itself). Returns whether `req` is still running.
+
+        Before anything is evicted the tick in flight is landed:
+        `_evict` rebuilds `ctx` from `generated`, which has to hold
+        every token, and a request that finishes on that tick frees
+        blocks, so the allocation is tried again first."""
+        while (req.written + req.in_flight) // self.block_size \
+                >= len(req.table):
             try:
                 with tracer().span("alloc"):
                     req.table.extend(self.alloc.alloc(1, rid=req.rid))
             except OutOfBlocks as e:
                 self._note_oom(e)
+                if self._land():
+                    continue
                 live = [r for r in self.slots if r is not None]
                 victim = max(live, key=lambda r: r.admit_seq)
                 if victim is req and len(live) == 1:

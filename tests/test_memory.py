@@ -294,7 +294,7 @@ def test_oom_drill_recovers_with_forensics(params, tmp_path):
     eng = ServingEngine(params, CFG, n_blocks=14, block_size=8,
                         max_slots=4, prefill_chunk=16,
                         metrics=MetricsLogger(path, kind="serve"),
-                        log_every=2)
+                        log_every=1)
     dumps = []
     eng.oom_listeners.append(
         lambda en, exc: dumps.append(en.oom_forensics(exc)))

@@ -575,12 +575,13 @@ def test_int8_weight_tick_bytes_beat_bf16_baseline():
     s, w = rows, 4
     i32 = lambda *sh: jax.ShapeDtypeStruct(sh, np.int32)  # noqa: E731
     closed = jax.make_jaxpr(
-        lambda p, pl, tok, pos, bt, temp, seeds, idx: _decode_tick(
-            p, pl, tok, pos, bt, temp, seeds, idx, cfg=cfg, top_k=0,
-            top_p=0.0))(
+        lambda p, pl, tok, pos, bt, temp, seeds, idx, prev, ahead:
+        _decode_tick(p, pl, tok, pos, bt, temp, seeds, idx, prev, ahead,
+                     cfg=cfg, top_k=0, top_p=0.0))(
         cast, pools, i32(s), i32(s), i32(s, w),
         jax.ShapeDtypeStruct((s,), np.float32),
-        jax.ShapeDtypeStruct((s,), np.uint32), i32(s))
+        jax.ShapeDtypeStruct((s,), np.uint32), i32(s), i32(s),
+        jax.ShapeDtypeStruct((s,), np.bool_))
     n_param = len(jax.tree_util.tree_leaves(cast))
     traced_param_bytes = sum(aval_bytes(v.aval)
                              for v in closed.jaxpr.invars[:n_param])

@@ -573,7 +573,7 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
         e[5]["tick"] for e in steps)
     assert sum(e[5]["n_admitted"] for e in ring if e[2] == "admit") == 3
     decode = next(e for e in ring if e[2] == "decode")
-    assert set(decode[5]) == {"n_active", "width", "blocks_read",
+    assert set(decode[5]) == {"ahead", "n_active", "width", "blocks_read",
                               "blocks_table"}
     assert decode[5]["width"] == 4
     covered = sum(e[4] - e[3] for e in ring
@@ -589,10 +589,13 @@ def test_decode_span_says_what_the_tick_read(global_tracer, tiny_serving):
     `blocks_table` is the table's room, slots x width. The engine's
     counters of the same names are their sums."""
     eng, serve = tiny_serving
-    before = {k: eng.counters[k] for k in ("blocks_read", "blocks_table")}
+    before = {k: eng.counters[k]
+              for k in ("blocks_read", "blocks_table", "ticks")}
     serve("blocks-")
-    ticks = [e[5] for e in global_tracer.ring() if e[2] == "decode"]
-    assert ticks
+    # a turn that only lands the tick in flight dispatched none
+    ticks = [e[5] for e in global_tracer.ring()
+             if e[2] == "decode" and "blocks_read" in e[5]]
+    assert len(ticks) == eng.counters["ticks"] - before.pop("ticks")
     for a in ticks:
         assert a["blocks_table"] == eng.max_slots * a["width"]
         # prompts of 20 and 5 new tokens: positions 20-24, 3 or 4 blocks

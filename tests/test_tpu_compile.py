@@ -100,7 +100,8 @@ def _compiled_text(program, one_chip, heads, kv_quant=""):
         traced = _decode_tick.trace(
             params, pools, arr(i32, SLOTS), arr(i32, SLOTS),
             arr(i32, SLOTS, WIDTH), arr(f32, SLOTS), arr(i32, SLOTS),
-            arr(i32, SLOTS), cfg=cfg, top_k=0, top_p=0.0)
+            arr(i32, SLOTS), arr(i32, SLOTS), arr(jnp.bool_, SLOTS),
+            cfg=cfg, top_k=0, top_p=0.0)
     else:
         traced = _prefill_chunk.trace(
             params, pools, arr(i32, 1, CHUNK), arr(i32), arr(i32),
