@@ -65,6 +65,14 @@ def parse_args(argv=None):
     m.add_argument("--routed-scale", type=float, default=1.0)
     m.add_argument("--dense-layers", type=int, default=0,
                    help="leading layers that keep the dense FFN")
+    m.add_argument("--model-config", default=None, metavar="FILE",
+                   help="a JSON object of TransformerConfig's own fields "
+                        "that the flags above do not reach (n_kv_heads, "
+                        "attn_head_dim, embed_scale, layers: one [window, "
+                        "rotary] pair a layer; any other field too), "
+                        "laid over them, and `block_parts`: what each "
+                        "block holds beyond the plain one (qk_norm, "
+                        "attn_gate, post_norm)")
     m.add_argument("--init-seed", type=int, default=0,
                    help="weight-init seed for the demo model")
     m.add_argument("--ckpt", default=None,
@@ -253,17 +261,26 @@ def main(argv=None) -> int:
                          args.routed_experts),
                      routed_scaling_factor=args.routed_scale,
                      first_dense_layers=args.dense_layers)
-    cfg = T.TransformerConfig(
+    fields = dict(
         vocab=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, max_seq=args.max_seq, rope=args.rope,
         rope_theta=args.rope_theta, norm=args.norm, ffn=args.ffn,
         d_ff=args.d_ff, **kinds)
+    parts = ()
+    if args.model_config:
+        extra = json.loads(Path(args.model_config).read_text())
+        parts = tuple(extra.pop("block_parts", ()))
+        if "layers" in extra:       # JSON has no tuples; the config is hashed
+            extra["layers"] = tuple(map(tuple, extra["layers"]))
+        fields.update(extra)
+    cfg = T.TransformerConfig(**fields)
     if args.ckpt:
         from shallowspeed_tpu import checkpoint
 
         params = checkpoint.restore(args.ckpt)["params"]
     else:
-        params = jax.device_put(T.init(cfg, seed=args.init_seed))
+        params = jax.device_put(T.init(cfg, seed=args.init_seed,
+                                       parts=parts))
     # replica mode: requests arrive over HTTP; the default "-" must
     # not block on a subprocess's empty stdin
     reqs = ([] if args.serve and args.requests == "-"
@@ -479,11 +496,13 @@ def main(argv=None) -> int:
             "pending_at_exit": eng.pending(),
             "drained": drained_clean,
             "executables": eng.executable_counts(),
+            # every layer group's pool together (`eng.allocs`)
             "blocks_free_at_drain":
-                f"{eng.alloc.n_free}/{eng.alloc.n_usable}",
+                f"{sum(al.n_free for al in eng.allocs)}"
+                f"/{sum(al.n_usable for al in eng.allocs)}",
             # with the prefix cache on, finished requests donate their
             # blocks to the cold list: free + cold == usable at drain
-            "blocks_cold_at_drain": eng.alloc.n_cold,
+            "blocks_cold_at_drain": sum(al.n_cold for al in eng.allocs),
         })
         print(json.dumps({"event": "summary", **summary}), flush=True)
         if plane is not None:

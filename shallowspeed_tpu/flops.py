@@ -137,7 +137,7 @@ def transformer_flops_per_token(cfg, seq_len: int,
         per_layer += 2.0 * d * 3 * d                        # fused qkv
     per_layer += 2.0 * d * d                                # out proj
     # attention itself: QK^T and AV are each 2*head_dim*ctx per head
-    ctx = _avg_causal_context(seq_len, getattr(cfg, "attn_window", 0))
+    ctx = _avg_causal_context(seq_len, cfg.window)
     per_layer += 2 * (2.0 * cfg.n_heads * cfg.head_dim * ctx)
     # FFN
     if cfg.n_experts > 0:

@@ -43,18 +43,22 @@ _cache_write = cache_write
 _cached_attention = cached_attention
 
 
-def _block_decode(p, x, cfg: T.TransformerConfig, cache_blk, pos):
+def _block_decode(p, x, cfg: T.TransformerConfig, cache_blk, pos,
+                  spec=None):
     """One block on a single-token slice x (B, 1, d); writes this token's
-    K/V at `pos` and attends over the cache. Returns (x, cache_blk)."""
+    K/V at `pos` and attends over the cache. `spec` is the layer's
+    (window, rotary) of `cfg.layer_specs` (None: the one of a uniform model).
+    Returns (x, cache_blk)."""
     b = x.shape[0]
+    window, rotary = spec or (cfg.window, cfg.rope)
     h = T._norm(p["ln1"], x, cfg)
     q, k, v = T._qkv(p, h, cfg)
-    if cfg.rope:  # rotate at this token's position; cache stores rotated K
+    if rotary:  # rotate at this token's position; cache stores rotated K
         q = T.rope_rotate(q, pos, cfg.rope_theta)
         k = T.rope_rotate(k, pos, cfg.rope_theta)
     cache_blk = _cache_write(cache_blk, k, v, pos)
-    a = _cached_attention(q, cache_blk, pos, cfg).reshape(b, 1, cfg.d_model)
-    x = x + T._dense(p["proj"], a)
+    a = _cached_attention(q, cache_blk, pos, cfg, window).reshape(b, 1, -1)
+    x = T.attn_residual(p, x, a, h, cfg)
     h = T._norm(p["ln2"], x, cfg)
     x, _aux = T._ffn(p, x, cfg, h)
     return x, cache_blk
@@ -63,7 +67,7 @@ def _block_decode(p, x, cfg: T.TransformerConfig, cache_blk, pos):
 def _embed(params, tokens, pos0, cfg):
     t = tokens.shape[1]
     pos = pos0 + jnp.arange(t)
-    x = params["tok_emb"][tokens]
+    x = T.embed_tokens(params, tokens, cfg)
     if not cfg.rope:  # rope replaces the learned absolute embedding
         x = x + params["pos_emb"][pos]
     if cfg.compute_dtype is not None:
@@ -100,16 +104,15 @@ def prefill(params, tokens, cfg: T.TransformerConfig, cache,
         cfg = _replace(cfg, attn_dropout=0.0)
     x = _embed(params, tokens, 0, cfg)
     if attn_impl == "flash":
-        from shallowspeed_tpu.ops.flash_attention import flash_attention
-
-        attn = partial(flash_attention, causal=True,
-                       window=cfg.attn_window)
+        from shallowspeed_tpu.ops.flash_attention import flash_attention as fn
     else:
-        attn = partial(T.attention, causal=True, window=cfg.attn_window)
+        fn = T.attention
     pos = jnp.arange(tp)
     for i, blk in enumerate(params["blocks"]):
-        x, _aux, (k, v) = T._block(blk, x, cfg, attn, with_kv=True,
-                                   pos=pos)
+        window, rotary = cfg.layer_specs[i]
+        x, _aux, (k, v) = T._block(
+            blk, x, cfg, partial(fn, causal=True, window=window),
+            with_kv=True, pos=pos, rotary=rotary)
         cache[i] = _cache_write(cache[i], k, v, 0)
     x = T._norm(params["ln_f"], x, cfg)
     if last_idx is None:
@@ -129,8 +132,8 @@ def decode_step(params, token, pos, cache, cfg: T.TransformerConfig):
     params = T.cast_params(params, cfg.compute_dtype)
     x = _embed(params, token[:, None], pos, cfg)
     new_cache = []
-    for blk, cblk in zip(params["blocks"], cache):
-        x, cblk = _block_decode(blk, x, cfg, cblk, pos)
+    for blk, cblk, spec in zip(params["blocks"], cache, cfg.layer_specs):
+        x, cblk = _block_decode(blk, x, cfg, cblk, pos, spec)
         new_cache.append(cblk)
     x = T._norm(params["ln_f"], x, cfg)
     logits = T.head_logits(params, x[:, 0], cfg)

@@ -188,9 +188,12 @@ def position_mask(slots: int, pos, window: int = 0):
     return valid
 
 
-def cached_attention(q, cache_blk, pos, cfg):
+def cached_attention(q, cache_blk, pos, cfg, window: int | None = None):
     """q: (B, 1, H, hd) at position `pos`; attends over cache[:, :pos+1]
-    — `masked_attention` under the contiguous position prefix."""
-    valid = position_mask(cache_blk["k"].shape[2], pos, cfg.attn_window)
+    — `masked_attention` under the contiguous position prefix, cut to
+    the layer's `window` (of `cfg.layer_specs`; 0 = all of it, None = a
+    uniform model's `cfg.window`)."""
+    valid = position_mask(cache_blk["k"].shape[2], pos,
+                          cfg.window if window is None else window)
     return masked_attention(q, cache_blk,
                             valid[None, None, None, None, :], cfg)

@@ -198,6 +198,26 @@ class TransformerConfig:
     expert_d_ff: int = 0
     routed_scaling_factor: float = 1.0
     first_dense_layers: int = 0
+    # The layers' attention pattern, where they differ: one (window,
+    # rotary) pair a layer, window 0 = every earlier token, rotary False
+    # = queries and keys of that layer are not rotated at all; the
+    # windowed layers share one window size. Empty = every layer is
+    # (attn_window, rope): those two are what a constructor of a
+    # uniform model writes, and NOTHING reads `attn_window` but
+    # `layer_specs`. Whoever needs a layer's window or rotation reads
+    # `layer_specs` (the forward pass without a cache, `generate()`,
+    # the serving engine, whose cache groups the layers by window:
+    # `serving/cache.py:layer_groups`); code that takes one window for
+    # the whole model (the training engines' attention substrates, the
+    # FLOP count) reads `window`, which refuses a pattern.
+    layers: tuple = ()
+    # A head's size where it is not d_model / n_heads (0): queries are
+    # then n_heads x attn_head_dim wide and `proj` maps that back to
+    # d_model. Read through `head_dim`.
+    attn_head_dim: int = 0
+    # The token embedding is multiplied by this on its way in (a muP
+    # model's sqrt(d_model)); 1 = as gathered.
+    embed_scale: float = 1.0
 
     def __post_init__(self):
         assert self.norm in ("layernorm", "rmsnorm"), self.norm
@@ -223,6 +243,15 @@ class TransformerConfig:
                 "and takes neither n_kv_heads nor attn_window")
             assert self.qk_rope_head_dim % 2 == 0 and self.v_head_dim > 0 \
                 and self.qk_nope_head_dim > 0, "latent head sizes unset"
+        if self.layers:
+            assert len(self.layers) == self.n_layers and self.rope \
+                and not self.attn_window and not self.latent, (
+                    "a layer pattern names every layer, and replaces "
+                    "attn_window; its model has no learned positions "
+                    "(rope=True) and no latent attention")
+            assert all(w >= 0 for w, _ in self.layers) and len(
+                {w for w, _ in self.layers if w}) <= 1, (
+                    f"windowed layers share one window size: {self.layers}")
         if self.n_routed_experts:
             assert not self.n_experts, (
                 "n_routed_experts (dropless) and n_experts (capacity "
@@ -237,6 +266,8 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.attn_head_dim:
+            return self.attn_head_dim
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -264,18 +295,46 @@ class TransformerConfig:
     def routed_layer(self, i: int) -> bool:
         return self.n_routed_experts > 0 and i >= self.first_dense_layers
 
+    @property
+    def layer_specs(self) -> tuple:
+        """(window, rotary) of every layer: THE per-layer spec."""
+        return tuple((int(w), bool(r)) for w, r in self.layers) \
+            or ((self.attn_window, self.rope),) * self.n_layers
+
+    @property
+    def window(self) -> int:
+        """The window of a model whose layers all share one spec, for
+        code that takes one window a model."""
+        assert len(set(self.layer_specs)) == 1, (
+            f"one window and one rotation for the whole model here; the "
+            f"layers differ: {self.layers}")
+        return self.layer_specs[0][0]
+
 
 def _dense_init(rng, in_d, out_d, dtype):
     w = rng.normal(0.0, 1.0 / np.sqrt(in_d), (in_d, out_d)).astype(dtype)
     return {"W": w, "b": np.zeros((out_d,), dtype)}
 
 
-def init(cfg: TransformerConfig, seed: int = 0):
+BLOCK_PARTS = ("qk_norm", "attn_gate", "post_norm")
+
+
+def init(cfg: TransformerConfig, seed: int = 0, parts=()):
     """Host-side deterministic init (seeded like the MLP family's
-    dims-keyed init, `layers.py:104-113`, but one seed for the whole tree)."""
+    dims-keyed init, `layers.py:104-113`, but one seed for the whole tree).
+
+    `parts` names what every block holds beyond the plain pre-norm
+    block (`BLOCK_PARTS`): `qk_norm` the per-head RMSNorm scales
+    `q_norm` / `k_norm`, `attn_gate` the sigmoid gate's projection,
+    `post_norm` the norms `ln1_post` / `ln2_post` of each sub-layer's
+    output. A block's kind follows what its params hold, so nothing in
+    the config repeats them; they are drawn after the block's other
+    leaves, which keeps every earlier model's weights what they were."""
+    assert set(parts) <= set(BLOCK_PARTS), parts
     rng = np.random.default_rng(seed)
     dt = cfg.dtype
     d = cfg.d_model
+    hd_all = cfg.n_heads * cfg.head_dim
     blocks = []
     for i in range(cfg.n_layers):
         blk = {
@@ -293,13 +352,13 @@ def init(cfg: TransformerConfig, seed: int = 0):
                                      (r, h, dn + dv)).astype(dt)
             blk["proj"] = _dense_init(rng, h * dv, d, dt)
         else:
-            blk["proj"] = _dense_init(rng, d, d, dt)
+            blk["proj"] = _dense_init(rng, hd_all, d, dt)
             if cfg.gqa:  # separate q and (smaller) fused kv projections
-                blk["q"] = _dense_init(rng, d, d, dt)
+                blk["q"] = _dense_init(rng, d, hd_all, dt)
                 blk["kv"] = _dense_init(
                     rng, d, 2 * cfg.kv_heads * cfg.head_dim, dt)
             else:
-                blk["qkv"] = _dense_init(rng, d, 3 * d, dt)
+                blk["qkv"] = _dense_init(rng, d, 3 * hd_all, dt)
         if cfg.routed_layer(i):
             e, ff = cfg.n_routed_experts, cfg.expert_d_ff
             blk["experts"] = {
@@ -328,6 +387,14 @@ def init(cfg: TransformerConfig, seed: int = 0):
                 blk["gate"] = _dense_init(rng, d, cfg.ffn_dim, dt)
             blk["up"] = _dense_init(rng, d, cfg.ffn_dim, dt)
             blk["down"] = _dense_init(rng, cfg.ffn_dim, d, dt)
+        if "qk_norm" in parts:
+            blk["q_norm"] = {"g": np.ones((cfg.head_dim,), dt)}
+            blk["k_norm"] = {"g": np.ones((cfg.head_dim,), dt)}
+        if "attn_gate" in parts:
+            blk["attn_gate"] = _dense_init(rng, d, hd_all, dt)
+        if "post_norm" in parts:
+            for name in ("ln1_post", "ln2_post"):
+                blk[name] = {"g": np.ones((d,), dt), "b": np.zeros((d,), dt)}
         blocks.append(blk)
     out = {
         "tok_emb": rng.normal(0.0, 0.02, (cfg.vocab, d)).astype(dt),
@@ -341,9 +408,11 @@ def init(cfg: TransformerConfig, seed: int = 0):
 
 
 # leaves `cast_params` leaves in the master dtype: the norms (`kv_norm`
-# is the latent row's RMSNorm) and the routed layer's selection bias,
-# which only ever meets float32 scores
-_NORM_KEYS = {"ln1", "ln2", "ln_f", "kv_norm"}
+# is the latent row's RMSNorm, `q_norm` / `k_norm` a head's, `ln1_post`
+# / `ln2_post` a sub-layer's output's) and the routed layer's selection
+# bias, which only ever meets float32 scores
+_NORM_KEYS = {"ln1", "ln2", "ln_f", "kv_norm", "q_norm", "k_norm",
+              "ln1_post", "ln2_post"}
 _MASTER_KEYS = _NORM_KEYS | {"route_bias"}
 
 # Quantized weight-storage leaves (see `quantize_weights`): "Wq" is the
@@ -651,7 +720,42 @@ def _qkv(p, h, cfg: TransformerConfig):
         qkv = _dense(p["qkv"], h, cfg.fp8_dense).reshape(
             b, t, cfg.n_heads, 3, cfg.head_dim)
         q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
+    if "q_norm" in p:   # each head RMS-normed over its own dimensions,
+        #                 before any rotation
+        q, k = _rmsnorm(p["q_norm"], q), _rmsnorm(p["k_norm"], k)
     return q, k, v
+
+
+def embed_tokens(params, tokens, cfg: TransformerConfig):
+    """The token embedding rows, times `cfg.embed_scale`."""
+    x = params["tok_emb"][tokens]
+    return x if cfg.embed_scale == 1.0 else x * jnp.asarray(
+        cfg.embed_scale, x.dtype)
+
+
+def attn_residual(p, x, a, h, cfg: TransformerConfig, key=None):
+    """The attention sub-layer from the heads' output `a` (..., H * hd)
+    to the residual stream, by what the block's params hold: the
+    sigmoid gate computed from the sub-layer's input `h` (`attn_gate`),
+    the output projection, the norm of the sub-layer's output
+    (`ln1_post`), dropout, the add onto `x`. One function for the
+    forward pass without a cache, `generate()` and the serving engine's
+    two programs."""
+    if "attn_gate" in p:
+        g = _dense(p["attn_gate"], h, cfg.fp8_dense)
+        a = a * jax.nn.sigmoid(g.astype(jnp.float32)).astype(a.dtype)
+    y = _dense(p["proj"], a, cfg.fp8_dense)
+    if "ln1_post" in p:
+        y = _norm(p["ln1_post"], y, cfg)
+    return x + _dropout(y, cfg.dropout, key)
+
+
+def ffn_residual(p, x, y, cfg: TransformerConfig, key=None):
+    """The FFN's output `y` onto the residual stream: normed first
+    where the block holds `ln2_post`."""
+    if "ln2_post" in p:
+        y = _norm(p["ln2_post"], y, cfg)
+    return x + _dropout(y, cfg.dropout, key)
 
 
 def latent_qkv(p, h, cfg: TransformerConfig, rotate):
@@ -730,32 +834,36 @@ def _ffn(p, x, cfg: TransformerConfig, h, key=None):
         y, idx = routed_ffn(p, h, cfg)
         e = cfg.n_routed_experts
         load = jax.nn.one_hot(idx, e, dtype=jnp.float32).reshape(-1, e)
-        return (x + _dropout(y, cfg.dropout, key),
+        return (ffn_residual(p, x, y, cfg, key),
                 (0.0, 0.0, {"load": load.mean(0),
                             "drop_fraction": jnp.float32(0.0)}))
     if "moe" in p:
         y, aux, z, st = moe_ffn(p["moe"], h, cfg.moe_top_k,
                                 cfg.moe_capacity_factor,
                                 priority=cfg.moe_routing == "priority")
-        return x + _dropout(y, cfg.dropout, key), (aux, z, st)
+        return ffn_residual(p, x, y, cfg, key), (aux, z, st)
     if "gate" in p:  # SwiGLU: silu(gate) * up, both column-parallel
         y = _swiglu(p, h, cfg.fp8_dense)
     else:
         y = _dense(p["down"], jax.nn.gelu(_dense(p["up"], h, cfg.fp8_dense)),
                    cfg.fp8_dense)
-    return x + _dropout(y, cfg.dropout, key), (0.0, 0.0, None)
+    return ffn_residual(p, x, y, cfg, key), (0.0, 0.0, None)
 
 
 def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
-           pos=None, key=None):
+           pos=None, key=None, rotary=None):
     """One pre-LN block; returns (x, aux) where aux is the MoE
     load-balancing loss (0.0 for dense blocks). With `with_kv` also
     returns this block's (k, v) — the decode prefill
     (`models/generate.py`) captures them into its cache; the training
     path never requests them, so XLA dead-code-eliminates the extra
     outputs there. `pos` (global positions) is required when cfg.rope.
-    `key` (training only) seeds this block's attention/FFN dropout."""
+    `key` (training only) seeds this block's attention/FFN dropout.
+    `rotary` is this layer's half of its spec (`cfg.layer_specs`; None
+    = the model's `cfg.rope`), its window the caller's `attn_fn`."""
     b, t, d = x.shape
+    if rotary is None:
+        rotary = cfg.rope
     k_attn = k_ffn = k_prob = None
     if key is not None and cfg.dropout > 0.0 and cfg.attn_dropout > 0.0:
         k_attn, k_ffn, k_prob = jax.random.split(key, 3)
@@ -787,7 +895,7 @@ def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
     # alignment; see parallel/tensor.py). Under GQA, _qkv splits into
     # q / kv projections instead.
     q, k, v = _qkv(p, h, cfg)
-    if cfg.rope:
+    if rotary:
         assert pos is not None, "cfg.rope needs positions threaded in"
         q = rope_rotate(q, pos, cfg.rope_theta)
         k = rope_rotate(k, pos, cfg.rope_theta)
@@ -800,16 +908,15 @@ def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
             "probabilities inside their score blocks)")
         extra = {"dropout": cfg.attn_dropout, "dropout_key": k_prob}
     if _supports_gqa(attn_fn):  # native GQA: no repeated K/V materialized
-        a = attn_fn(q, k, v, **extra).reshape(b, t, d)
+        a = attn_fn(q, k, v, **extra).reshape(b, t, -1)
     else:
         a = attn_fn(q, repeat_kv(k, cfg), repeat_kv(v, cfg),
-                    **extra).reshape(b, t, d)
+                    **extra).reshape(b, t, -1)
     # name for selective remat: cfg.remat_policy "attn"/"dots" saves this
     # value so the backward replay never re-runs the attention substrate
     # (no-op outside a policied jax.checkpoint)
     a = _checkpoint_name(a, "attn_out")
-    x = x + _dropout(_dense(p["proj"], a, cfg.fp8_dense),
-                     cfg.dropout, k_attn)
+    x = attn_residual(p, x, a, h, cfg, k_attn)
     h = _norm(p["ln2"], x, cfg)
     x, aux = _ffn(p, x, cfg, h, k_ffn)
     if with_kv:
@@ -839,8 +946,12 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
     layer keys are fold_in-derived, so remat recompute sees identical
     masks.
     """
-    if attn_fn is None:
-        attn_fn = partial(attention, causal=True, window=cfg.attn_window)
+    assert attn_fn is None or not cfg.layers, (
+        "a layer pattern (cfg.layers) runs the default attention, one "
+        "window a layer; a caller's substrate takes one for the model")
+    specs = cfg.layer_specs
+    attn_of = {w: attn_fn or partial(attention, causal=True, window=w)
+               for w, _ in specs}
     params = cast_params(params, cfg.compute_dtype)
     b, t = tokens.shape
     # Under jit an out-of-range gather silently clamps to pos_emb's last row;
@@ -853,7 +964,7 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
     if cfg.dropout == 0.0 and cfg.attn_dropout == 0.0:
         dropout_key = None
     pos = pos_offset + jnp.arange(t)
-    x = params["tok_emb"][tokens]
+    x = embed_tokens(params, tokens, cfg)
     if not cfg.rope:  # rope replaces the learned absolute embedding
         x = x + params["pos_emb"][pos]
     if dropout_key is not None:
@@ -863,12 +974,14 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
     stats_sum, n_moe = None, 0
     block_fn = _block
     if cfg.remat:
-        block_fn = jax.checkpoint(_block, static_argnums=(2, 3, 4),
+        block_fn = jax.checkpoint(_block, static_argnums=(2, 3, 4, 7),
                                   policy=_remat_policy(cfg))
     for i, blk in enumerate(params["blocks"]):
         k_i = (None if dropout_key is None
                else jax.random.fold_in(dropout_key, i))
-        x, (aux, z, st) = block_fn(blk, x, cfg, attn_fn, False, pos, k_i)
+        window, rotary = specs[i]
+        x, (aux, z, st) = block_fn(blk, x, cfg, attn_of[window], False, pos,
+                                   k_i, rotary)
         aux_total = aux_total + aux
         z_total = z_total + z
         if st is not None:

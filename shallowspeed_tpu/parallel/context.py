@@ -98,7 +98,7 @@ class ContextParallelEngine:
         # Sliding windows compose with EVERY substrate: all of them take
         # `window=` with identical semantics (`ops/attention.py` masks,
         # the flash kernel skips out-of-window tiles outright).
-        w = cfg.attn_window
+        w = cfg.window
         if cfg.attn_dropout > 0.0:
             # probability dropout lives on the plain substrate only; at
             # sp=1 the ring degenerates to it, so swap transparently

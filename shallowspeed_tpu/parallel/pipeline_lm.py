@@ -422,7 +422,7 @@ class PipelineLMEngine:
             def psum_tp(x):
                 return x
 
-        w = cfg.attn_window  # windows compose with every substrate
+        w = cfg.window  # windows compose with every substrate
         if self.attn == "flash":
             # the fused Pallas kernel drops into the stage block
             # unchanged: per-device heads, full (unsharded) microbatch
@@ -1893,7 +1893,7 @@ class PipelineLMEngine:
         assert not self.fsdp, (
             "pipelined decode needs stage-resident params; restore the "
             "checkpoint into a non-fsdp pipeline to sample")
-        attn = partial(attention, causal=True, window=cfg.attn_window)
+        attn = partial(attention, causal=True, window=cfg.window)
         dt = cfg.compute_dtype or cfg.dtype
         l_local = self.l_local
         vpp = self.vpp

@@ -33,15 +33,29 @@ sweep halves its bytes the same way.
 
 A layer's pool is of the layer's kind (`init_block_pool`): K and V
 heads, or for a latent-attention layer ONE row a token that all heads
-share. The kinds share the block ids, so allocator, tables, prefix
-index and copy-on-write are the same for both; only what a write stores
-(`_kv_update`) and how the chunk reads the gathered table differ (the
-tick's kernel reads a latent row as key and value both).
+share; only what a write stores (`_kv_update`) and how the chunk reads
+the gathered table differ (the tick's kernel reads a latent row as key
+and value both).
+
+Layers fall into GROUPS by how their cache grows (`layer_groups`, from
+`cfg.layer_specs`): `full` layers keep every token of a request,
+`window` layers only the blocks that a future query can still see. Each
+group has pools of its own size, a `BlockAllocator` of its own, and a
+request holds ONE TABLE A GROUP. A window group's table starts at the
+first block the request still holds: the engine releases a block to the
+group's free list once its last position has left the window of every
+query to come (`first_live_block`), and hands the programs the table
+with `base`, the position of its first entry's first token. Relative to
+`base` everything is as in a full group (a rotated key carries its
+position in its values, a mask compares differences), so the kernel and
+the writes are the same for both kinds. A model with one kind of layer
+has one group and one table, as before there were groups.
 """
 
 from __future__ import annotations
 
 import hashlib
+from dataclasses import dataclass
 
 import jax.numpy as jnp
 import numpy as np
@@ -63,14 +77,17 @@ class OutOfBlocks(RuntimeError):
     its historical "need N blocks, F free + C cold" shape."""
 
     def __init__(self, requested: int, n_free: int = 0, n_cold: int = 0,
-                 n_live: int = 0, rid=None):
+                 n_live: int = 0, rid=None, group: str = ""):
         self.requested = int(requested)
         self.n_free = int(n_free)
         self.n_cold = int(n_cold)
         self.n_live = int(n_live)
         self.rid = rid
+        self.group = group      # the layer group whose pool ran out
         msg = (f"need {self.requested} blocks, {self.n_free} free + "
                f"{self.n_cold} cold")
+        if group:
+            msg += f" in the {group!r} group"
         if rid is not None:
             msg += f" (request {rid!r})"
         super().__init__(msg)
@@ -90,9 +107,68 @@ def pool_block_size(pool_blk) -> int:
     return next(iter(pool_blk.values())).shape[2]
 
 
-def init_block_pool(cfg: T.TransformerConfig, n_blocks: int,
+@dataclass(frozen=True)
+class LayerGroup:
+    """Layers whose cache grows the same way: `window` 0 keeps every
+    token (`full`), else the last `window` (`window`)."""
+    name: str
+    window: int
+    layers: tuple
+
+    def first_live_block(self, query_pos: int, block_size: int) -> int:
+        """The first block (as a column of an absolute table) that a
+        query at `query_pos` or later still sees: key j is visible to
+        query i iff i - window < j <= i, so every block before that of
+        position `query_pos - window + 1` is dead for good."""
+        if self.window <= 0:
+            return 0
+        return max(query_pos - self.window + 1, 0) // block_size
+
+    def held_bound(self, block_size: int, ahead: int) -> int:
+        """Most blocks a request holds in this group while it writes up
+        to `ahead` positions in one program (a prefill chunk, a tick's
+        draft rows): a window's `ceil(window / bs) + 1`, plus the
+        blocks of what is being written. 0 = no bound (`full`)."""
+        if self.window <= 0:
+            return 0
+        return blocks_for(self.window, block_size) + 1 \
+            + blocks_for(ahead, block_size)
+
+
+def layer_groups(cfg: T.TransformerConfig) -> tuple:
+    """The model's layers by kind of growth, from `cfg.layer_specs`:
+    the `full` group first where there is one, then the `window` group
+    (the windowed layers of a model share one window size)."""
+    windows = sorted({w for w, _ in cfg.layer_specs})
+    return tuple(LayerGroup(
+        "window" if w else "full", w,
+        tuple(i for i, (wi, _) in enumerate(cfg.layer_specs) if wi == w))
+        for w in windows)
+
+
+def group_of_layer(cfg: T.TransformerConfig) -> tuple:
+    """For each layer the index of its group in `layer_groups(cfg)`."""
+    groups = layer_groups(cfg)
+    return tuple(next(g for g, grp in enumerate(groups) if i in grp.layers)
+                 for i in range(cfg.n_layers))
+
+
+def group_blocks(cfg: T.TransformerConfig, n_blocks) -> dict:
+    """{group name: n_blocks} from an int (every group that many) or a
+    dict that names every group."""
+    names = [g.name for g in layer_groups(cfg)]
+    if isinstance(n_blocks, dict):
+        if set(n_blocks) != set(names):
+            raise ValueError(f"n_blocks names {sorted(n_blocks)}; the "
+                             f"model's layer groups are {names}")
+        return {n: int(n_blocks[n]) for n in names}
+    return {n: int(n_blocks) for n in names}
+
+
+def init_block_pool(cfg: T.TransformerConfig, n_blocks,
                     block_size: int, kv_quant: str = ""):
-    """Per-layer paged pools, zero-filled, each of its layer's kind.
+    """Per-layer paged pools, zero-filled, each of its layer's kind and
+    of its group's size (`n_blocks`: an int, or {group name: blocks}).
 
     A K/V layer holds (n_blocks, Hkv, block_size, hd) for `k` and `v`;
     int8 pools add the (n_blocks, Hkv, block_size, 1) f32 scale planes,
@@ -114,27 +190,29 @@ def init_block_pool(cfg: T.TransformerConfig, n_blocks: int,
         raise ValueError(
             f"unsupported kv_quant={kv_quant!r}; expected one of "
             f"{KV_QUANT_MODES} ('' = pool in the compute dtype)")
-    if n_blocks < 2:
+    sizes = group_blocks(cfg, n_blocks)
+    if min(sizes.values()) < 2:
         raise ValueError(f"n_blocks={n_blocks} leaves no usable blocks "
                          f"past the reserved scratch block")
+    names = [g.name for g in layer_groups(cfg)]
+    per_layer = [sizes[names[g]] for g in group_of_layer(cfg)]
     dt = cfg.compute_dtype or cfg.dtype
     if cfg.latent:
         if kv_quant:
             raise ValueError("a latent pool has no int8 form: kv_quant "
                              "must be '' with latent attention")
-        shape = (n_blocks, 1, block_size,
-                 -(-cfg.latent_width // LANES) * LANES)
-        return [{LATENT: jnp.zeros(shape, dt)} for _ in range(cfg.n_layers)]
-    shape = (n_blocks, cfg.kv_heads, block_size, cfg.head_dim)
+        tail = (1, block_size, -(-cfg.latent_width // LANES) * LANES)
+        return [{LATENT: jnp.zeros((n,) + tail, dt)} for n in per_layer]
+    tail = (cfg.kv_heads, block_size, cfg.head_dim)
     if kv_quant:
-        sshape = shape[:3] + (1,)
-        return [{"k": jnp.zeros(shape, jnp.int8),
-                 "k_s": jnp.zeros(sshape, jnp.float32),
-                 "v": jnp.zeros(shape, jnp.int8),
-                 "v_s": jnp.zeros(sshape, jnp.float32)}
-                for _ in range(cfg.n_layers)]
-    return [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-            for _ in range(cfg.n_layers)]
+        stail = tail[:2] + (1,)
+        return [{"k": jnp.zeros((n,) + tail, jnp.int8),
+                 "k_s": jnp.zeros((n,) + stail, jnp.float32),
+                 "v": jnp.zeros((n,) + tail, jnp.int8),
+                 "v_s": jnp.zeros((n,) + stail, jnp.float32)}
+                for n in per_layer]
+    return [{"k": jnp.zeros((n,) + tail, dt), "v": jnp.zeros((n,) + tail, dt)}
+            for n in per_layer]
 
 
 class BlockAllocator:
@@ -158,11 +236,13 @@ class BlockAllocator:
     raises instead of double-appending `i` to the free list); block 0
     (scratch) is never handed out."""
 
-    def __init__(self, n_blocks: int, index: "PrefixIndex | None" = None):
+    def __init__(self, n_blocks: int, index: "PrefixIndex | None" = None,
+                 group: str = ""):
         if n_blocks < 2:
             raise ValueError(f"n_blocks={n_blocks} leaves no usable "
                              f"blocks past the reserved scratch block")
         self.n_blocks = int(n_blocks)
+        self.group = group      # the layer group this pool belongs to
         # LIFO free list: recently freed (still-warm) blocks are reused
         # first; ids 1..n-1 — block 0 is the scratch sink
         self._free = list(range(self.n_blocks - 1, 0, -1))
@@ -211,7 +291,8 @@ class BlockAllocator:
         if n > len(self._free) + len(self._cold):
             raise OutOfBlocks(n, n_free=len(self._free),
                               n_cold=len(self._cold),
-                              n_live=len(self._ref), rid=rid)
+                              n_live=len(self._ref), rid=rid,
+                              group=self.group)
         while len(self._free) < n:
             self._reclaim_one()
         ids = [self._free.pop() for _ in range(n)]
@@ -273,7 +354,8 @@ class BlockAllocator:
         forensics. `consistent` restates the allocator invariant
         (n_free + n_live + n_cold == n_usable) so a dump self-reports
         bookkeeping corruption."""
-        return {"n_blocks": self.n_blocks, "n_usable": self.n_usable,
+        return {"group": self.group,
+                "n_blocks": self.n_blocks, "n_usable": self.n_usable,
                 "n_free": self.n_free, "n_live": self.n_live,
                 "n_cold": self.n_cold, "peak_live": self.peak_live,
                 "cold_reclaims": self.cold_reclaims,
@@ -339,12 +421,22 @@ class PrefixIndex:
             ids.append(bid)
         return ids
 
-    def insert(self, tokens, table) -> int:
-        """Map the leading `len(table)` full chunks of `tokens` to the
-        given block ids (first-writer-wins). Returns how many NEW
-        entries landed."""
+    def lookup(self, hashes) -> list:
+        """The block id indexed under each chain hash, None where there
+        is none: what a group that no longer holds a prefix's first
+        blocks (`LayerGroup.first_live_block`) can still offer."""
+        return [self._blocks.get(h) for h in hashes]
+
+    def insert(self, tokens, table, first: int = 0) -> int:
+        """Map the full chunks `first`, `first + 1`, ... of `tokens` to
+        the given block ids, `len(table)` of them (first-writer-wins;
+        `first` > 0 where the table no longer starts at the prompt's
+        first block). Returns how many NEW entries landed."""
         new = 0
         for k, h in enumerate(chunk_hashes(tokens, self.block_size)):
+            k -= first
+            if k < 0:
+                continue
             if k >= len(table):
                 break
             bid = int(table[k])
@@ -509,7 +601,7 @@ def param_read_bytes(params, cfg: T.TransformerConfig) -> int:
 
 
 def paged_read_bytes_per_tick(params, cfg: T.TransformerConfig,
-                              blocks_touched: int, block_size: int,
+                              blocks_touched, block_size: int,
                               n_rows: int, kv_quant: str = "",
                               p_bytes: int | None = None) -> int:
     """HBM READ bytes one decode tick usefully moves: every param leaf
@@ -518,7 +610,9 @@ def paged_read_bytes_per_tick(params, cfg: T.TransformerConfig,
     blocks the tick's active requests attend over (+ int8 scale
     planes) + the token ids. `blocks_touched` = sum over active rows
     of blocks_for(context_len) — the live-blocks generalization of the
-    contiguous model's full-cache sweep. Pass a precomputed `p_bytes`
+    contiguous model's full-cache sweep — or one such sum a layer group
+    (`layer_groups(cfg)`'s order), where a window group's rows count
+    the blocks their windows reach and no more. Pass a precomputed `p_bytes`
     (`param_read_bytes`) on hot paths — the param term never changes.
 
     This is the byte model behind the fast-decode gates: the
@@ -536,5 +630,9 @@ def paged_read_bytes_per_tick(params, cfg: T.TransformerConfig,
     per_block = block_size * per_token * kv_itemsize
     if kv_quant == "int8":
         per_block += 2 * cfg.kv_heads * block_size * 4   # f32 scales
-    return (p_bytes + cfg.n_layers * int(blocks_touched) * per_block
-            + n_rows * 4)
+    groups = layer_groups(cfg)
+    if np.ndim(blocks_touched) == 0:
+        blocks_touched = [blocks_touched] * len(groups)
+    layer_blocks = sum(len(g.layers) * int(b)
+                       for g, b in zip(groups, blocks_touched, strict=True))
+    return p_bytes + layer_blocks * per_block + n_rows * 4

@@ -123,7 +123,9 @@ from shallowspeed_tpu.ops.latent_attention import (absorb_query,
 from shallowspeed_tpu.serving.cache import (LATENT, SCRATCH_BLOCK,
                                             BlockAllocator, OutOfBlocks,
                                             PrefixIndex, blocks_for,
-                                            gather_table, init_block_pool,
+                                            chunk_hashes, gather_table,
+                                            group_blocks, group_of_layer,
+                                            init_block_pool, layer_groups,
                                             paged_read_bytes_per_tick,
                                             param_read_bytes,
                                             pool_block_size, write_chunk,
@@ -218,8 +220,7 @@ def _latent_decode(p, pool, bt, pos, q_nope, q_rope, cfg):
     value), un-absorb. q_nope/q_rope: (S, 1, H, .)."""
     qx = absorb_query(q_nope[:, 0], q_rope[:, 0], p["kv_b"],
                       pool[LATENT].shape[-1])
-    oc = paged_flash_decode(qx, pool, bt, pos, window=cfg.attn_window,
-                            scale=T.latent_scale(cfg))
+    oc = paged_flash_decode(qx, pool, bt, pos, scale=T.latent_scale(cfg))
     return unabsorb_output(oc, p["kv_b"], q_nope.shape[-1]
                            ).astype(q_nope.dtype)
 
@@ -233,19 +234,36 @@ def _ffn_counted(p, x, cfg, h, live):
         return T._ffn(p, x, cfg, h)[0], None
     y, idx = T.routed_ffn(p, h, cfg)
     hot = jax.nn.one_hot(idx, cfg.n_routed_experts, dtype=jnp.int32)
-    return x + y, (hot * live[..., None, None]).sum((0, 1, 2))
+    return (T.ffn_residual(p, x, y, cfg),
+            (hot * live[..., None, None]).sum((0, 1, 2)))
+
+
+def _group_tables(bt, base, pos):
+    """What each layer group addresses its cache by, [(table, position
+    in the table's own coordinates)]: `bt` is one table a group (a lone
+    array: one group), `base[g]` the position of the first token of
+    table g's first entry (None: every table starts at position 0).
+    A full group's base is 0; a window group's table starts at the
+    first block its request still holds, and relative to that
+    everything is as in a full one."""
+    bts = tuple(bt) if isinstance(bt, (tuple, list)) else (bt,)
+    return [(b, pos if base is None else pos - base[g])
+            for g, b in enumerate(bts)]
 
 
 @partial(jax.jit, static_argnames=("cfg", "top_k", "top_p"),
          donate_argnums=(1,))
 def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
-                 ahead, *, cfg: T.TransformerConfig, top_k: int,
+                 ahead, base=None, *, cfg: T.TransformerConfig, top_k: int,
                  top_p: float):
     """One compiled decode tick over the whole slot batch.
 
     tok/pos/temp/seeds/idx: (S,) per-slot last token, write position,
     sampling state; bt: (S, W) block tables (W is the bucketed width —
-    the ONLY shape that varies across ticks). The decode loop keeps
+    the ONLY shape that varies across ticks), one a layer group
+    (`cache.layer_groups`; a tuple of them, or the one table of a model
+    with one group), and `base` (groups, S) where a group's tables do
+    not start at position 0 (`_group_tables`). The decode loop keeps
     one tick in flight (`ServingEngine._decode_step`), so the host may
     not have a row's last token yet: `prev` is the previous tick's
     `nxt` as it left that program (a device array the host never
@@ -283,41 +301,43 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
     s_rows = tok.shape[0]
     bs = pool_block_size(pools[0])
     quant = "k_s" in pools[0]
-    x = params["tok_emb"][tok][:, None, :]                  # (S, 1, d)
+    x = T.embed_tokens(params, tok, cfg)[:, None, :]        # (S, 1, d)
     if not cfg.rope:
         x = x + params["pos_emb"][pos][:, None, :]
     if cfg.compute_dtype is not None:
         x = x.astype(cfg.compute_dtype)
     rows = jnp.arange(s_rows)
-    blk = bt[rows, pos // bs]
-    off = pos % bs
-    live = (bt[:, 0] != SCRATCH_BLOCK)[:, None]             # (S, 1)
+    tables = [(b, at, b[rows, at // bs])
+              for b, at in _group_tables(bt, base, pos)]
+    off = pos % bs          # a base is a whole number of blocks
+    live = (tables[0][0][:, 0] != SCRATCH_BLOCK)[:, None]   # (S, 1)
     rope = lambda u: _rope_rows(u, pos, cfg.rope_theta)
     # heads that are not whole lanes wide (no published size; toy
     # configurations on the chip) are beyond the kernel's DMA when
     # compiled and keep the gathered read
     paged = paged_decode_addresses(pools[0])
-    if not paged:
-        valid = position_mask(bt.shape[1] * bs, pos[:, None],
-                              cfg.attn_window)[:, None, None, None, :]
     new_pools, counts = [], []
-    for p, pool in zip(params["blocks"], pools):
+    for p, pool, (window, rotary), g in zip(
+            params["blocks"], pools, cfg.layer_specs, group_of_layer(cfg)):
+        bt_g, at, blk = tables[g]
         h = T._norm(p["ln1"], x, cfg)
         if LATENT in pool:
             qn, qr, c, kr = T.latent_qkv(p, h, cfg, rope)
             pool = write_rows(pool, c, kr, blk, off, False)
-            a = _latent_decode(p, pool, bt, pos, qn, qr, cfg)
+            a = _latent_decode(p, pool, bt_g, at, qn, qr, cfg)
         else:
             q, k, v = T._qkv(p, h, cfg)
-            if cfg.rope:
+            if rotary:
                 q, k = rope(q), rope(k)
             pool = {**pool, **write_rows(pool, k[:, 0], v[:, 0], blk, off,
                                          quant)}
-            a = (paged_flash_decode(q[:, 0], pool, bt, pos,
-                                    window=cfg.attn_window) if paged
-                 else masked_attention(q, gather_table(pool, bt), valid,
-                                       cfg))
-        x = x + T._dense(p["proj"], a.reshape(s_rows, 1, -1))
+            if paged:
+                a = paged_flash_decode(q[:, 0], pool, bt_g, at, window=window)
+            else:
+                valid = position_mask(bt_g.shape[1] * bs, at[:, None], window)
+                a = masked_attention(q, gather_table(pool, bt_g),
+                                     valid[:, None, None, None, :], cfg)
+        x = T.attn_residual(p, x, a.reshape(s_rows, 1, -1), h, cfg)
         x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
         if n is not None:
             counts.append(n)
@@ -330,14 +350,19 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
 def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
-                   cow_dst, *, cfg: T.TransformerConfig):
+                   cow_dst, base=None, *, cfg: T.TransformerConfig):
     """One chunk of a request's prefill: tokens (1, C) — C is the
     fixed chunk length, `n_tok` the traced true count (the tail is
     padding: never written, and masked out of every true row's read).
     Writes the chunk's K/V through the block table (`write_chunk`: the
     few blocks its consecutive positions touch, merged and written
     back whole, in place) and attends causally over the table
-    (earlier chunks included). Returns (f32 logits at the chunk's last
+    (earlier chunks included). `bt` is one (1, W) table a layer group
+    and `base` (groups,) where a group's table does not start at
+    position 0, as `_decode_tick` takes them; `cow_src` / `cow_dst`
+    likewise one pair a group. A window group's table never holds more
+    than its window and the chunk, so that is all its layers gather
+    and score, however long the prompt. Returns (f32 logits at the chunk's last
     true position — consumed only on the final chunk — the updated,
     donated pools, and the routed layers' assignment counts of the
     chunk's true rows as `_decode_tick` gives them).
@@ -359,35 +384,38 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     params = T.cast_params(params, cfg.compute_dtype)
     c = tokens.shape[1]
     bs = pool_block_size(pools[0])
-    w = bt.shape[1]
     quant = "k_s" in pools[0]
-    pools = [{name: leaf.at[cow_dst].set(leaf[cow_src])
-              for name, leaf in pool.items()} for pool in pools]
+    group = group_of_layer(cfg)
+    cow_src, cow_dst = jnp.atleast_1d(cow_src), jnp.atleast_1d(cow_dst)
+    pools = [{name: leaf.at[cow_dst[g]].set(leaf[cow_src[g]])
+              for name, leaf in pool.items()}
+             for pool, g in zip(pools, group)]
     pos = pos0 + jnp.arange(c)
     x = G._embed(params, tokens, pos0, cfg)                  # (1, C, d)
-    span = jnp.arange(w * bs)
-    valid = span[None, :] <= pos[:, None]                   # (C, W*bs)
-    if cfg.attn_window > 0:
-        valid = valid & (span[None, :] > pos[:, None] - cfg.attn_window)
+    tables = _group_tables(bt, base, pos0)
     live = (jnp.arange(c) < n_tok)[None, :]                 # (1, C)
     rope = lambda u: T.rope_rotate(u, pos, cfg.rope_theta)
     new_pools, counts = [], []
-    for p, pool in zip(params["blocks"], pools):
+    for p, pool, (window, rotary), g in zip(
+            params["blocks"], pools, cfg.layer_specs, group):
+        bt_g, at0 = tables[g]
+        at = at0 + jnp.arange(c)
+        valid = position_mask(bt_g.shape[1] * bs, at[:, None], window)
         h = T._norm(p["ln1"], x, cfg)
         if LATENT in pool:
             qn, qr, lat, kr = T.latent_qkv(p, h, cfg, rope)
             pool = write_chunk(pool, lat[0][:, None], kr[0][:, None],
-                               bt[0], pos0, n_tok, False)
-            a = _latent_read(p, pool, bt, qn, qr, valid[None, None], cfg)
+                               bt_g[0], at0, n_tok, False)
+            a = _latent_read(p, pool, bt_g, qn, qr, valid[None, None], cfg)
         else:
             q, k, v = T._qkv(p, h, cfg)
-            if cfg.rope:
+            if rotary:
                 q, k = rope(q), rope(k)
-            pool = {**pool, **write_chunk(pool, k[0], v[0], bt[0], pos0,
+            pool = {**pool, **write_chunk(pool, k[0], v[0], bt_g[0], at0,
                                           n_tok, quant)}
-            a = masked_attention(q, gather_table(pool, bt),
+            a = masked_attention(q, gather_table(pool, bt_g),
                                  valid[None, None, None], cfg)
-        x = x + T._dense(p["proj"], a.reshape(1, c, -1))
+        x = T.attn_residual(p, x, a.reshape(1, c, -1), h, cfg)
         x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
         if n is not None:
             counts.append(n)
@@ -403,7 +431,7 @@ class _Req:
 
     __slots__ = ("rid", "prompt", "max_new", "temp", "seed", "arrival",
                  "generated", "n_preempt", "phase", "slot", "ctx",
-                 "table", "written", "admit_seq", "admit_t",
+                 "tables", "base", "written", "admit_seq", "admit_t",
                  "queued_at", "wait_s", "first_tok_t", "last_tok",
                  "in_flight", "timeline", "track", "trace_t0", "n_drafted",
                  "n_accepted", "ctx_ids", "spec_idx",
@@ -422,7 +450,11 @@ class _Req:
         self.phase = "queued"           # queued -> prefill -> decode
         self.slot = None
         self.ctx = prompt               # prompt (+ generated on requeue)
-        self.table: list[int] = []
+        # one block table a layer group (`cache.layer_groups`), and for
+        # each the blocks already released off its front: table g's
+        # first entry holds positions from base[g] * block_size on
+        self.tables: list[list[int]] = []
+        self.base: list[int] = []
         self.written = 0                # cache positions filled
         self.admit_seq = -1
         self.admit_t = None
@@ -463,6 +495,12 @@ class _Req:
         self.skipped_tok = 0
         self.cow = None
 
+    @property
+    def table(self) -> list[int]:
+        """The first group's table (the only one, in a model with one
+        kind of layer)."""
+        return self.tables[0] if self.tables else []
+
 
 class ServingEngine:
     """Paged-cache continuous-batching decode server (module
@@ -472,7 +510,7 @@ class ServingEngine:
     lines."""
 
     def __init__(self, params, cfg: T.TransformerConfig, *,
-                 n_blocks: int = 64, block_size: int = 16,
+                 n_blocks=64, block_size: int = 16,
                  max_slots: int = 4, prefill_chunk: int = 32,
                  table_bucket: int = 4, kv_quant: str = "",
                  weight_quant: str = "", attn_impl: str = "gather",
@@ -519,7 +557,12 @@ class ServingEngine:
         # serve.py --chaos and supervisor-exported drills just work;
         # tests pass an explicit plan to fault ONE of N engines.
         self.chaos_plan = chaos_plan
-        self.pools = init_block_pool(cfg, n_blocks, block_size, kv_quant)
+        # the layers by kind of cache growth: pools sized a group
+        # (`n_blocks`: an int, or {"full": ..., "window": ...}), an
+        # allocator a group, and every request one table a group
+        self.groups = layer_groups(cfg)
+        sizes = group_blocks(cfg, n_blocks)
+        self.pools = init_block_pool(cfg, sizes, block_size, kv_quant)
         # prefix caching (round 19): a content-addressed index over
         # block-aligned prompt chunks. `_admit` probes it, finished
         # requests donate their sealed prefix blocks (refcount-zero
@@ -529,8 +572,30 @@ class ServingEngine:
         # n_free == n_usable drain invariant holds exactly as before;
         # on, the extended invariant is n_free + n_cold == n_usable at
         # drain (cold = donated, still-matchable cache).
-        self.prefix = PrefixIndex(block_size) if prefix_cache else None
-        self.alloc = BlockAllocator(n_blocks, index=self.prefix)
+        #
+        # With a window group a hit needs more than the full group's
+        # chain: an m-block hit resumes at position m * bs - 1 at the
+        # earliest, so it needs, in every window group, the blocks from
+        # `first_live_block(m * bs - 1)` to m - 1 still indexed (a
+        # finished request donates what its window still held of its
+        # prompt, under the same chain hashes, in that group's own
+        # index). `_match_prefix` takes the longest m that every group
+        # can serve, else it is a miss; a released block is in no
+        # table and no index entry outlives its block (`drop_block`),
+        # so a hit never reads one.
+        self.prefixes = [PrefixIndex(block_size) if prefix_cache else None
+                         for _ in self.groups]
+        self.allocs = [BlockAllocator(sizes[g.name], index=ix, group=g.name)
+                       for g, ix in zip(self.groups, self.prefixes)]
+        # the first group's (a model with one kind of layer has no
+        # other): what `serve.py`, the router and the drivers read
+        self.prefix, self.alloc = self.prefixes[0], self.allocs[0]
+        # a window group's table never passes its bound, so neither
+        # does its bucketed width (a whole number of `table_bucket`s)
+        ahead = max(self.prefill_chunk, self.spec_k + 1)
+        self._held_bound = [g.held_bound(self.block_size, ahead)
+                            for g in self.groups]
+        self._windowed = any(g.window for g in self.groups)
         # constant param term at the STORAGE dtypes actually served
         # (int8/fp8 values + f32 scales when weight_quant is on)
         self._p_bytes = param_read_bytes(self.params, cfg)
@@ -561,9 +626,18 @@ class ServingEngine:
                          "experts_touched": 0.0, "max_load": 0.0,
                          "latent_tokens": 0,
                          # pool blocks the decode ticks' reads walked
-                         # (every row, dead and draft rows too) and
-                         # the blocks their tables had room for
-                         "blocks_read": 0, "blocks_table": 0}
+                         # (every row, dead and draft rows too; one
+                         # layer of each group, and `blocks_read_<group>`
+                         # that group's part) and the blocks their
+                         # tables had room for
+                         "blocks_read": 0, "blocks_table": 0,
+                         # blocks handed back to a window group's free
+                         # list because they left their request's window
+                         "released": 0}
+        for g in self.groups:
+            # `<group>_blocks`: blocks the ticks' live rows held there
+            self.counters[f"blocks_read_{g.name}"] = 0
+            self.counters[f"{g.name}_blocks"] = 0
         # OOM forensics (round 20, the memory observatory): every
         # RECOVERED OutOfBlocks stamps a typed `oom` ledger line and
         # notifies these listeners with (engine, exc) — serve.py wires
@@ -610,7 +684,7 @@ class ServingEngine:
         self._admit_counter = 0
         self._win_tokens = 0            # tokens since the last log line
         self._win_t = clock()
-        self._last_touched = 0
+        self._last_touched = [0] * len(self.groups)
         self._win_drafted = 0           # spec-decode window tallies
         self._win_accepted = 0
         self._win_prefix_lookups = 0    # prefix-cache window tallies
@@ -620,7 +694,7 @@ class ServingEngine:
         # as a `table_rebucket` ledger event so attribution can book
         # the retrace instead of leaving it unexplained; revisits hit
         # the jit cache and stamp nothing
-        self._tick_widths: set[int] = set()
+        self._tick_widths: set[tuple] = set()
         self._last_width = 0
         # the decode tick in flight (`_decode_step`): (its requests,
         # their drafts, its `nxt` and routed counts, both still on the
@@ -677,12 +751,14 @@ class ServingEngine:
         # the final sampled token is never written (sample-after-decode,
         # like generate()), so the request's peak footprint is
         # tp + max_new - 1 cache positions
-        need = blocks_for(tp + max_new - 1, self.block_size)
-        if need > self.alloc.n_usable:
-            raise ValueError(
-                f"request needs {need} blocks but the pool holds "
-                f"{self.alloc.n_usable} usable — it could never be "
-                f"scheduled (raise n_blocks or shrink the request)")
+        for g, al in enumerate(self.allocs):
+            need = self._peak_blocks(g, tp + max_new - 1)
+            if need > al.n_usable:
+                raise ValueError(
+                    f"request needs {need} blocks but the {al.group!r} "
+                    f"group's pool holds {al.n_usable} usable — it could "
+                    f"never be scheduled (raise n_blocks or shrink the "
+                    f"request)")
         rid = rid if rid is not None else f"r{self.counters['submitted']}"
         if rid in self.results or any(
                 r.rid == rid for r in self._all_live()):
@@ -776,8 +852,8 @@ class ServingEngine:
             if not self.step():
                 raise RuntimeError(
                     "scheduler made no progress with requests pending "
-                    f"(queue={len(self.queue)}, "
-                    f"free_blocks={self.alloc.n_free})")
+                    f"(queue={len(self.queue)}, free_blocks="
+                    f"{[al.n_free for al in self.allocs]})")
             steps += 1
         return dict(self.results)
 
@@ -889,15 +965,36 @@ class ServingEngine:
         shed-before-evict placement signal. Uses submit()'s footprint
         model (tp + max_new - 1 cache positions), so a request's
         deficit falls as its table grows."""
-        needed = 0
-        for r in self._all_live():
-            final = blocks_for(r.prompt.shape[0] + r.max_new - 1,
-                               self.block_size)
-            needed += max(0, final - len(r.table))
-        return {"live_blocks": self.alloc.n_live,
-                "blocks_needed": needed,
-                "headroom_blocks": (self.alloc.n_free
-                                    + self.alloc.n_cold - needed)}
+        out = None
+        for g, al in enumerate(self.allocs):
+            needed = 0
+            for r in self._all_live():
+                final = self._peak_blocks(
+                    g, r.prompt.shape[0] + r.max_new - 1)
+                needed += max(0, final - (len(r.tables[g]) if r.tables
+                                          else 0))
+            room = al.n_free + al.n_cold - needed
+            # of several groups, the one that runs out first
+            if out is None or room < out["headroom_blocks"]:
+                out = {"live_blocks": al.n_live, "blocks_needed": needed,
+                       "headroom_blocks": room}
+        return out
+
+    def _peak_blocks(self, g: int, n_tokens: int) -> int:
+        """Most blocks a request of `n_tokens` cache positions holds in
+        group `g` at once: all of them, or a window group's bound."""
+        need = blocks_for(n_tokens, self.block_size)
+        bound = self._held_bound[g]
+        return min(need, bound) if bound else need
+
+    def _width(self, g: int, n_blocks: int) -> int:
+        """Table width of group `g` for `n_blocks` held: the geometric
+        bucket, which a window group's bound caps."""
+        w = table_width(n_blocks, self.table_bucket)
+        bound = self._held_bound[g]
+        if bound:
+            w = min(w, -(-bound // self.table_bucket) * self.table_bucket)
+        return w
 
     def _note_oom(self, e: OutOfBlocks) -> None:
         """Record one RECOVERED block-exhaustion event: bump the
@@ -937,6 +1034,7 @@ class ServingEngine:
 
         out = memlib.forensics(top_k)
         out["allocator"] = self.alloc.snapshot()
+        out["allocators"] = [al.snapshot() for al in self.allocs]
         out["headroom"] = self.headroom()
         out["block_tables"] = {r.rid: len(r.table)
                                for r in self.slots if r is not None}
@@ -958,48 +1056,51 @@ class ServingEngine:
             return False
         while self.queue and None in self.slots:
             req = self.queue[0]
-            need = blocks_for(len(req.ctx), self.block_size)
+            bs = self.block_size
             # prefix-cache probe: map the longest indexed aligned
-            # prefix straight into the block table and start chunked
+            # prefix straight into the block tables and start chunked
             # prefill at the divergence point. A FULLY-aligned match
             # (every block of ctx indexed) still re-prefills its last
             # token: the tail block copies-on-write into a fresh block
             # so decode can append without mutating the shared one,
             # and the final-position logits come from a real chunk.
-            matched: list[int] = []
-            if self.prefix is not None:
-                matched = self.prefix.match(req.ctx)
-                self.counters["prefix_lookups"] += 1
-                self._win_prefix_lookups += 1
-            m = len(matched)
-            full = m > 0 and m * self.block_size == len(req.ctx)
+            m, matched = self._match_prefix(req.ctx)
+            full = m > 0 and m * bs == len(req.ctx)
+            written = len(req.ctx) - 1 if full else m * bs
+            # a full group takes the whole prompt's blocks now, as
+            # ever; a window group those of the first chunk (the next
+            # ones as `_ensure_blocks` releases what left the window)
+            upto = [len(req.ctx) if not g.window
+                    else min(len(req.ctx), written + self.prefill_chunk)
+                    for g in self.groups]
+            held, tables = [], []
             try:
-                if matched:
-                    self.alloc.acquire(matched)
-                try:
-                    fresh = self.alloc.alloc(need - m + (1 if full else 0),
-                                             rid=req.rid)
-                except OutOfBlocks:
-                    if matched:          # all-or-nothing admission
-                        self.alloc.release(matched)
-                    raise
+                for al, ids, n in zip(self.allocs, matched, upto):
+                    if ids:
+                        al.acquire(ids)
+                        held.append((al, ids))
+                    fresh = al.alloc(blocks_for(n, bs) - m
+                                     + (1 if full else 0), rid=req.rid)
+                    held.append((al, fresh))
+                    tables.append(fresh)
             except OutOfBlocks as e:
+                for al, ids in held:      # all-or-nothing admission
+                    al.release(ids)
                 self._note_oom(e)
                 break                # wait for blocks to free
             self.queue.popleft()
             slot = self.slots.index(None)
             req.slot = slot
-            if full:
-                # hold the matched tail block (the CoW source) by the
-                # acquire above until the copy lands in the first
-                # prefill chunk; the table gets the fresh copy instead
-                req.cow = (matched[-1], fresh[0])
-                req.table = matched[:-1] + fresh
-                req.written = len(req.ctx) - 1
-            else:
-                req.cow = None
-                req.table = matched + fresh
-                req.written = m * self.block_size
+            req.base = [m - len(ids) for ids in matched]
+            # a fully aligned hit holds the matched tail block (the CoW
+            # source) by the acquire above until the copy lands in the
+            # first prefill chunk; the table gets the fresh copy instead
+            req.cow = [(ids[-1], fresh[0])
+                       for ids, fresh in zip(matched, tables)] \
+                if full else None
+            req.tables = [ids[:len(ids) - full] + fresh
+                          for ids, fresh in zip(matched, tables)]
+            req.written = written
             skipped = req.written
             req.phase = "prefill"
             req.admit_seq = self._admit_counter
@@ -1020,6 +1121,33 @@ class ServingEngine:
                                 tokens=int(skipped))
             did = True
         return did
+
+    def _match_prefix(self, ctx) -> tuple:
+        """(m, [the matched block ids a group]): the longest aligned
+        prefix of `ctx`, in blocks, that EVERY group's index can serve
+        — a full group all of blocks 0..m-1, a window group those from
+        `first_live_block(m * bs - 1)` on (what the first query after
+        the hit, at the earliest, still sees) — else (0, nothing)."""
+        none = 0, [[] for _ in self.groups]
+        if self.prefix is None:
+            return none
+        self.counters["prefix_lookups"] += 1
+        self._win_prefix_lookups += 1
+        bs = self.block_size
+        found = [ix.lookup(chunk_hashes(ctx, bs)) for ix in self.prefixes]
+        # run[c]: indexed blocks in a row up to and including column c
+        runs = []
+        for ids in found:
+            run, n = [], 0
+            for bid in ids:
+                n = n + 1 if bid is not None else 0
+                run.append(n)
+            runs.append(run)
+        for m in range(len(found[0]), 0, -1):
+            first = [g.first_live_block(m * bs - 1, bs) for g in self.groups]
+            if all(run[m - 1] >= m - lo for run, lo in zip(runs, first)):
+                return m, [ids[lo:m] for ids, lo in zip(found, first)]
+        return none
 
     def _prefill_step(self) -> bool:
         pre = [r for r in self.slots
@@ -1049,39 +1177,73 @@ class ServingEngine:
             attrs["max_load"] = float((counts.max(-1) / mean).mean())
         return attrs
 
-    def _blocks_walked(self, pos) -> int:
-        """Pool blocks one layer's read of a tick walks, from the
-        tick's own positions: each row those of its table that its
-        position and the window admit, a dead row (pos 0) one. (With
-        int8 pools the kernel rounds a window's first block down to a
-        whole step and reads up to a step less one more.)"""
-        bs, window = self.block_size, self.cfg.attn_window
-        first = np.maximum(pos - window + 1, 0) // bs if window > 0 else 0
-        return int((pos // bs + 1 - first).sum())
+    def _blocks_walked(self, pos) -> list[int]:
+        """Pool blocks the read of a tick walks in ONE layer of each
+        group, from the tick's own positions: each row those of its
+        table that its position and the group's window admit, a dead
+        row (pos 0) one. (With int8 pools the kernel rounds a window's
+        first block down to a whole step and reads up to a step less
+        one more.)"""
+        bs = self.block_size
+        return [int((pos // bs + 1 - (np.maximum(pos - g.window + 1, 0) // bs
+                                      if g.window else 0)).sum())
+                for g in self.groups]
+
+    def _rows_tables(self, rows, n: int, room=None) -> tuple:
+        """The programs' table arguments for `rows`, [(row of the
+        arrays, request)], of `n` rows in all: one (n, width) table a
+        group, scratch where a row holds nothing and as wide as the
+        bucket of the longest (or of `room[g]` blocks, if that is
+        more), and `base` (groups, n) — the position of each table's
+        first token — where the model has a window group (else None:
+        every table starts at 0)."""
+        bts = []
+        for g in range(len(self.groups)):
+            w = self._width(g, max([len(r.tables[g]) for _, r in rows]
+                                   + [room[g] if room else 0]))
+            bt = np.full((n, w), SCRATCH_BLOCK, np.int32)
+            for i, r in rows:
+                bt[i, :len(r.tables[g])] = r.tables[g]
+            bts.append(bt)
+        if not self._windowed:
+            return tuple(bts), None
+        base = np.zeros((len(self.groups), n), np.int32)
+        base[:, [i for i, _ in rows]] = np.transpose(
+            [r.base for _, r in rows]) * self.block_size
+        return tuple(bts), base
 
     def _prefill_chunk_of(self, req, tr, sp) -> None:
         c = self.prefill_chunk
         n_tok = min(c, len(req.ctx) - req.written)
+        released = self.counters["released"]
+        if not self._ensure_blocks(req, req.written + n_tok):
+            return                  # evicted for blocks: it prefills anew
+        sp.set(released=self.counters["released"] - released)
         self._lifecycle(req, "prefill", chunk=req.written // c,
                         tokens=int(n_tok))
         tokens = np.zeros((1, c), np.int32)
         tokens[0, :n_tok] = req.ctx[req.written:req.written + n_tok]
-        w = table_width(len(req.table), self.table_bucket)
-        bt = np.full((1, w), SCRATCH_BLOCK, np.int32)
-        bt[0, :len(req.table)] = req.table
+        # as wide as the most the prompt will hold, in every chunk: a
+        # window group's table grows to its bound over the first
+        # chunks, and each width on the way would be a program
+        bts, base = self._rows_tables(
+            [(0, req)], 1, [self._peak_blocks(g, len(req.ctx))
+                            for g in range(len(self.groups))])
         # copy-on-write rides the chunk as DATA on every call (scratch
         # self-copy when there is nothing to copy) — zero executables
-        cow = req.cow if req.cow is not None \
-            else (SCRATCH_BLOCK, SCRATCH_BLOCK)
+        cow = np.asarray(req.cow if req.cow is not None
+                         else [(SCRATCH_BLOCK, SCRATCH_BLOCK)]
+                         * len(self.groups), np.int32)
         with tr.span("prefill.dispatch"):
             logits, self.pools, counts = _prefill_chunk(
                 self.params, self.pools, tokens, np.int32(req.written),
-                np.int32(n_tok), bt, np.int32(cow[0]), np.int32(cow[1]),
-                cfg=self.cfg)
+                np.int32(n_tok), bts, cow[:, 0], cow[:, 1],
+                None if base is None else base[:, 0], cfg=self.cfg)
         if req.cow is not None:
-            # the copy landed: drop the reference that kept the shared
-            # source block alive for it
-            self.alloc.release([req.cow[0]])
+            # the copy landed: drop the references that kept the shared
+            # source blocks alive for it
+            for al, (src, _) in zip(self.allocs, req.cow):
+                al.release([src])
             req.cow = None
         req.written += n_tok
         self.counters["prefill_chunks"] += 1
@@ -1120,7 +1282,7 @@ class ServingEngine:
 
         Who lands the tick in flight: the next turn, as above (also
         when it has nothing to dispatch: the last tick of a drain);
-        `_ensure_block` when the pool runs out, before it evicts.
+        `_ensure_blocks` when the pool runs out, before it evicts.
         Draft rows (`spec_k > 0`) are proposed from the last token on
         the host, so with them every tick is landed in the turn that
         dispatched it: the same loop with nothing in flight."""
@@ -1130,8 +1292,10 @@ class ServingEngine:
             return False
         tr = tracer()
         with tr.span("decode") as sp:
+            released = self.counters["released"]
             with tr.span("decode.prep"):
                 prep = self._decode_prep()
+            released = self.counters["released"] - released
             # what is in flight NOW: prep lands it itself where it
             # ran out of blocks
             ahead = int(prep is not None and self._flight is not None)
@@ -1139,16 +1303,22 @@ class ServingEngine:
             new = None
             if prep is not None:
                 actives, drafts, rows = prep
-                pos, bt = rows[1], rows[2]
+                pos, bts = rows[1], rows[2]
                 with tr.span("decode.dispatch"):
                     nxt, self.pools, counts = _decode_tick(
                         self.params, self.pools, *rows, cfg=self.cfg,
                         top_k=self.top_k, top_p=self.top_p)
                 new = (actives, drafts, nxt, counts)
                 self.counters["ticks_ahead"] += ahead
-                read = {"blocks_read": self._blocks_walked(pos),
-                        "blocks_table": bt.size}
-                sp.set(n_active=len(actives), width=bt.shape[1], **read)
+                walked = self._blocks_walked(pos)
+                read = {"blocks_read": sum(walked),
+                        "blocks_table": sum(bt.size for bt in bts)}
+                for i, g in enumerate(self.groups):
+                    read[f"blocks_read_{g.name}"] = walked[i]
+                    read[f"{g.name}_blocks"] = sum(len(r.tables[i])
+                                                   for r in actives)
+                sp.set(n_active=len(actives), width=bts[0].shape[1],
+                       released=released, **read)
                 for name, value in read.items():
                     self.counters[name] += value
             if self.spec_k > 0:
@@ -1205,7 +1375,7 @@ class ServingEngine:
                     if r is not None and r.phase == "decode"]:
             # not evicted meanwhile, nor finished by a landing
             if req.slot is not None and owed(req):
-                self._ensure_block(req)
+                self._ensure_blocks(req, req.written + req.in_flight + 1)
         actives = [r for r in self.slots
                    if r is not None and r.phase == "decode" and owed(r)]
         if not actives:
@@ -1234,9 +1404,10 @@ class ServingEngine:
         seeds = np.zeros(s, np.uint32)
         idx = np.zeros(s, np.int32)
         ahead = np.zeros(s, np.bool_)
-        w = table_width(max(len(r.table) for r in actives),
-                        self.table_bucket)
-        bt = np.full((s, w), SCRATCH_BLOCK, np.int32)
+        rows = [(r.slot, r) for r in actives] + [
+            (row, r) for r, assigned in drafts.values()
+            for row, _ in assigned]
+        bts, base = self._rows_tables(rows, s)
         for r in actives:
             tok[r.slot] = r.last_tok
             ahead[r.slot] = r.in_flight
@@ -1244,7 +1415,6 @@ class ServingEngine:
             temp[r.slot] = r.temp
             seeds[r.slot] = r.seed
             idx[r.slot] = len(r.generated) + r.in_flight
-            bt[r.slot, :len(r.table)] = r.table
         for r, assigned in drafts.values():
             # draft row j: the j-th draft token at position written+j,
             # sampling at oracle token index len(generated)+j — the
@@ -1255,7 +1425,7 @@ class ServingEngine:
                 temp[row] = r.temp
                 seeds[row] = r.seed
                 idx[row] = len(r.generated) + j
-                bt[row, :len(r.table)] = r.table
+        w = tuple(bt.shape[1] for bt in bts)
         if w not in self._tick_widths:
             # FIRST tick at this width bucket compiles a fresh
             # executable (geometric bucketing keeps the count O(log
@@ -1267,23 +1437,24 @@ class ServingEngine:
             if self._tick_widths and self.metrics is not None:
                 self.metrics.log(event="ledger", kind="table_rebucket",
                                  count=1, prev_width=self._last_width,
-                                 width=int(w),
+                                 width=int(w[0]),
                                  tick=self.counters["ticks"])
             self._tick_widths.add(w)
-        self._last_width = w
+        self._last_width = int(w[0])
         prev = self._no_tok if self._flight is None else self._flight[2]
-        return actives, drafts, (tok, pos, bt, temp, seeds, idx, prev,
-                                 ahead)
+        return actives, drafts, (tok, pos, bts, temp, seeds, idx, prev,
+                                 ahead, base)
 
     def _decode_emit(self, actives, drafts, nxt) -> None:
         """Book the tick's tokens: counters, appends (which finish
         requests), the windowed tick line."""
         bs = self.block_size
         self.counters["ticks"] += 1
-        self._last_touched = sum(
-            blocks_for(r.written + 1
-                       + len(drafts.get(r.rid, (None, ()))[1]), bs)
-            for r in actives)
+        ends = [r.written + 1 + len(drafts.get(r.rid, (None, ()))[1])
+                for r in actives]
+        self._last_touched = [
+            sum(blocks_for(n, bs) - g.first_live_block(n - 1, bs)
+                for n in ends) for g in self.groups]
         emitted = 0
         for r in actives:
             # speculation tallies accrue BEFORE the appends: an
@@ -1377,50 +1548,76 @@ class ServingEngine:
         """Grow `req`'s table to cover its draft rows' write positions
         WITHOUT evicting anyone — drafts are opportunistic, so on pool
         pressure they trim to the blocks already held instead of
-        preempting real work (contrast `_ensure_block`)."""
-        if not d:
-            return d
-        grow = blocks_for(req.written + len(d) + 1,
-                          self.block_size) - len(req.table)
-        if grow > 0:
-            try:
-                req.table.extend(self.alloc.alloc(grow, rid=req.rid))
-            except OutOfBlocks as e:
-                self._note_oom(e)
-                cap = len(req.table) * self.block_size - 1 - req.written
-                d = d[:max(0, cap)]
+        preempting real work (contrast `_ensure_blocks`)."""
+        bs = self.block_size
+        for al, table, base in zip(self.allocs, req.tables, req.base):
+            grow = blocks_for(req.written + len(d) + 1, bs) \
+                - base - len(table)
+            if d and grow > 0:
+                try:
+                    table.extend(al.alloc(grow, rid=req.rid))
+                except OutOfBlocks as e:
+                    self._note_oom(e)
+                    cap = (base + len(table)) * bs - 1 - req.written
+                    d = d[:max(0, cap)]
         return d
 
-    def _ensure_block(self, req) -> bool:
-        """Grow `req`'s table to cover its next write position (one
-        past the host's, for a request with a token in flight),
-        evicting the newest-admitted running request on OOM (possibly
-        `req` itself). Returns whether `req` is still running.
+    def _ensure_blocks(self, req, upto: int) -> bool:
+        """Make every group's table of `req` cover the positions below
+        `upto` that its next program writes (a tick's one position, one
+        past the host's for a request with a token in flight; a prefill
+        chunk's), evicting the newest-admitted running request on OOM
+        (possibly `req` itself). Returns whether `req` is still running.
+
+        A window group first RELEASES the blocks whose last position no
+        query from the next one on (`written + in_flight`) can see: they
+        go back to the group's free list now, and may be handed to
+        another row at once, for a write that this next program or a
+        later one performs. The tick in flight may still read them; the
+        device runs the programs in the order they were dispatched, so
+        it has read them by then.
 
         Before anything is evicted the tick in flight is landed:
         `_evict` rebuilds `ctx` from `generated`, which has to hold
         every token, and a request that finishes on that tick frees
         blocks, so the allocation is tried again first."""
-        while (req.written + req.in_flight) // self.block_size \
-                >= len(req.table):
-            try:
-                with tracer().span("alloc"):
-                    req.table.extend(self.alloc.alloc(1, rid=req.rid))
-            except OutOfBlocks as e:
-                self._note_oom(e)
-                if self._land():
-                    continue
-                live = [r for r in self.slots if r is not None]
-                victim = max(live, key=lambda r: r.admit_seq)
-                if victim is req and len(live) == 1:
-                    # submit() guarantees a lone request fits — reaching
-                    # here means the accounting broke
-                    raise RuntimeError(
-                        "allocator invariant violated: a lone request "
-                        "cannot grow its table") from None
-                self._evict(victim)
-                if victim is req:
-                    return False
+        bs = self.block_size
+        query = req.written + req.in_flight
+        end = blocks_for(upto, bs)
+        for g, (grp, al) in enumerate(zip(self.groups, self.allocs)):
+            table = req.tables[g]
+            first = grp.first_live_block(query, bs)
+            if first <= req.base[g] and end <= req.base[g] + len(table):
+                continue        # most ticks: inside the last block
+            dead = min(first - req.base[g], len(table))
+            if dead > 0:
+                al.release(table[:dead])
+                del table[:dead]
+                req.base[g] += dead
+                self.counters["released"] += dead
+            if not table:
+                req.base[g] = max(req.base[g], first)
+            while (need := end - req.base[g] - len(table)) > 0:
+                try:
+                    with tracer().span("alloc"):
+                        table.extend(al.alloc(need, rid=req.rid))
+                except OutOfBlocks as e:
+                    self._note_oom(e)
+                    if self._land():
+                        continue
+                    live = [r for r in self.slots if r is not None]
+                    victim = max(live, key=lambda r: r.admit_seq)
+                    if victim is req and len(live) == 1:
+                        # submit() guarantees a lone request fits —
+                        # reaching here means the accounting broke
+                        raise RuntimeError(
+                            "allocator invariant violated: a lone request "
+                            f"cannot grow its {grp.name!r} table") from None
+                    self._evict(victim)
+                    if victim is req:
+                        return False
+            bound = self._held_bound[g]
+            assert not bound or len(table) <= bound, (grp.name, len(table))
         return True
 
     def _evict(self, req) -> None:
@@ -1431,11 +1628,7 @@ class ServingEngine:
         again) and continues its stream exactly where it stopped.
         Shared blocks other requests still reference stay live; only
         this request's references drop."""
-        if req.cow is not None:          # pending CoW source reference
-            self.alloc.release([req.cow[0]])
-            req.cow = None
-        self.alloc.release(req.table)
-        req.table = []
+        self._release_all(req)
         req.written = 0
         req.ctx = np.concatenate(
             [req.prompt, np.asarray(req.generated, np.int32)]) \
@@ -1450,6 +1643,16 @@ class ServingEngine:
         self.counters["preempted"] += 1
         self.queue.appendleft(req)
         self._lifecycle(req, "requeued")
+
+    def _release_all(self, req) -> None:
+        """Drop every block reference `req` holds, in every group (a
+        pending copy-on-write source's too)."""
+        for al, (src, _) in zip(self.allocs, req.cow or ()):
+            al.release([src])
+        req.cow = None
+        for al, table in zip(self.allocs, req.tables):
+            al.release(table)
+        req.tables, req.base = [], []
 
     def _append_token(self, req, tok: int) -> None:
         req.generated.append(tok)
@@ -1470,15 +1673,14 @@ class ServingEngine:
         # covered by PREFILL-written context are sealed — decode-
         # written positions live past len(ctx) and never land in a
         # donated block.
-        if self.prefix is not None and req.table:
+        # A window group donates what it still holds of them, under
+        # the same chain hashes (`_match_prefix`).
+        if self.prefix is not None and req.tables:
             sealed = min(req.written, len(req.ctx)) // self.block_size
-            if sealed > 0:
-                self.prefix.insert(req.ctx, req.table[:sealed])
-        if req.cow is not None:
-            self.alloc.release([req.cow[0]])
-            req.cow = None
-        self.alloc.release(req.table)
-        req.table = []
+            for ix, table, base in zip(self.prefixes, req.tables, req.base):
+                if sealed > base:
+                    ix.insert(req.ctx, table[:sealed - base], first=base)
+        self._release_all(req)
         self._lifecycle(req, "finished", tokens=len(req.generated))
         if self.lifecycle:
             # bounded retention (FIFO on dict insertion order): a
@@ -1551,7 +1753,7 @@ class ServingEngine:
             queue_depth=len(self.queue),
             active_slots=sum(1 for r in self.slots if r is not None),
             free_blocks=self.alloc.n_free,
-            blocks_touched=self._last_touched,
+            blocks_touched=sum(self._last_touched),
             bytes_per_tick=int(bpt),
             hbm_gbps=round(ticks_per_sec * bpt / 1e9, 4),
             live_blocks=hr["live_blocks"],

@@ -35,7 +35,20 @@ CFGS = {
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         n_routed_experts=4, n_shared_experts=1, moe_top_k=2, expert_d_ff=16,
         first_dense_layers=1),
+    # window and full layers, each kind with a pool of its own
+    # (`serving/cache.py:layer_groups`), and every part a block may hold;
+    # contexts pass the window of 12 early, so the window group releases
+    # blocks behind every request while a tick is in flight
+    "window": T.TransformerConfig(
+        vocab=64, d_model=32, n_heads=4, n_kv_heads=2, attn_head_dim=8,
+        n_layers=3, max_seq=128, rope=True, norm="rmsnorm", ffn="swiglu",
+        d_ff=64, layers=((12, True), (12, True), (0, False)),
+        embed_scale=32 ** 0.5),
 }
+# the window group's pool where a test does not size one: 4 slots at
+# their bound of ceil(12 / 8) + 1 + 2 blocks and one over, so a block
+# released behind one request is handed to another's write at once
+WINDOW_BLOCKS = 4 * 5 + 1 + 1
 # ring tuples (telemetry/trace.py)
 SEQ, PARENT, NAME, T0, T1, ATTRS = range(6)
 
@@ -43,7 +56,8 @@ SEQ, PARENT, NAME, T0, T1, ATTRS = range(6)
 @pytest.fixture(scope="module", params=list(CFGS))
 def model(request):
     cfg = CFGS[request.param]
-    return cfg, jax.device_put(T.init(cfg, seed=5))
+    parts = T.BLOCK_PARTS if cfg.layers else ()
+    return cfg, jax.device_put(T.init(cfg, seed=5, parts=parts))
 
 
 def toks(seed, n):
@@ -52,6 +66,8 @@ def toks(seed, n):
 
 def engine(model, spec_k=0, n_blocks=48, max_slots=4):
     cfg, params = model
+    if cfg.layers:
+        n_blocks = {"full": n_blocks, "window": min(n_blocks, WINDOW_BLOCKS)}
     return ServingEngine(params, cfg, n_blocks=n_blocks, block_size=8,
                          max_slots=max_slots, prefill_chunk=16,
                          spec_k=spec_k, lifecycle=False)
@@ -123,11 +139,15 @@ def test_streams_equal_the_oracle_and_the_parents_order(model, scenario):
     assert serial.counters["ticks_ahead"] == 0
     for eng in (ahead, serial):
         assert eng._flight is None and eng.pending() == 0
-        assert eng.alloc.n_free == eng.alloc.n_usable
+        assert all(al.n_free == al.n_usable for al in eng.allocs)
+        # a window group hands back what left the windows, both ways
+        # (the fourth scenario's contexts stay inside one)
+        assert (eng.counters["released"] > 0) == (
+            bool(eng.cfg.layers) and scenario != "max-new-1-and-2")
 
 
 def test_eviction_with_a_tick_in_flight_continues_the_stream(model):
-    """Three requests that outgrow the pool: `_ensure_block` finds no
+    """Three requests that outgrow the pool: `_ensure_blocks` finds no
     block with a tick in flight, lands it (its `decode.fetch` opens
     inside `decode.prep`), evicts the newest, and every stream is its
     oracle's all the same."""
@@ -251,7 +271,7 @@ def test_a_flagged_row_reads_its_token_from_the_tick_before(model):
         eng.step()
     eng._land()
     _, _, rows = eng._decode_prep()
-    tok, pos, bt, temp, seeds, idx, _, _ = rows
+    tok, pos, bt, temp, seeds, idx, _, _, base = rows
     s = eng.max_slots
     junk = np.full(s, 63, np.int32)
     flag = np.zeros(s, np.bool_)
@@ -262,9 +282,10 @@ def test_a_flagged_row_reads_its_token_from_the_tick_before(model):
         pools = jax.tree_util.tree_map(jnp.copy, eng.pools)
         return np.asarray(_decode_tick(
             params, pools, tok, pos, bt, temp, seeds, idx, prev, ahead,
-            cfg=cfg, top_k=0, top_p=0.0)[0])
+            base, cfg=cfg, top_k=0, top_p=0.0)[0])
 
     want = run(tok, junk, np.zeros(s, np.bool_))
     mixed = np.where(flag, junk, tok)
     np.testing.assert_array_equal(
         run(mixed, np.where(flag, tok, junk), flag), want)
+

@@ -153,3 +153,45 @@ def test_window_composes_with_fused_substrates():
         for name, eng in engines.items():
             assert eng.train_batch(tok, tgt) == pytest.approx(
                 want, rel=3e-4), (name, s)
+
+
+# ----------------------------------------------------------- serving level
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+def test_a_uniform_window_is_one_window_group_whose_cache_rolls(kv_quant):
+    """Mistral's shape at a toy size: every layer a window layer of the
+    per-layer spec (`cfg.layer_specs`), so the serving cache has ONE
+    group, of the `window` kind, and releases behind the window there
+    too: a request holds its bound of blocks however long it runs, and
+    its tokens are `generate()`'s."""
+    from shallowspeed_tpu.models.generate import generate
+    from shallowspeed_tpu.serving import ServingEngine
+    from shallowspeed_tpu.serving.cache import layer_groups
+
+    cfg = replace(CFG, attn_window=8, rope=True, n_kv_heads=2, max_seq=128)
+    assert cfg.layer_specs == ((8, True), (8, True))
+    group, = layer_groups(cfg)
+    assert (group.name, group.window, group.layers) == ("window", 8, (0, 1))
+    params = jax.device_put(T.init(cfg, seed=3))
+    bound = group.held_bound(4, 8)                  # 2 + 1 + the chunk's 2
+    # 13 usable blocks: three requests at their bound, not one whole
+    # context of 80 tokens (20 blocks)
+    eng = ServingEngine(params, cfg, n_blocks=14, block_size=4, max_slots=3,
+                        prefill_chunk=8, kv_quant=kv_quant, lifecycle=False)
+    rng = np.random.default_rng(5)
+    reqs = {f"r{i}": (rng.integers(0, 64, n).astype(np.int32), m)
+            for i, (n, m) in enumerate([(30, 50), (17, 40), (9, 60)])}
+    for rid, (p, m) in reqs.items():
+        eng.submit(p, m, rid=rid)
+    held = 0
+    while eng.pending():
+        eng.step()
+        held = max([held] + [len(r.table) for r in eng.slots if r is not None])
+    assert held <= bound and eng.counters["preempted"] == 0
+    assert eng.counters["released"] > 0
+    assert eng.alloc.n_free == eng.alloc.n_usable
+    for rid, (p, m) in reqs.items():
+        want = np.asarray(generate(params, p[None], cfg, m, temperature=0.0,
+                                   kv_quant=kv_quant))[0]
+        np.testing.assert_array_equal(eng.results[rid], want, err_msg=rid)

@@ -573,8 +573,13 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
         e[5]["tick"] for e in steps)
     assert sum(e[5]["n_admitted"] for e in ring if e[2] == "admit") == 3
     decode = next(e for e in ring if e[2] == "decode")
+    # one group of layers (`full`): what the tick read and what its rows
+    # hold are said for the model and for the group, nothing was released
     assert set(decode[5]) == {"ahead", "n_active", "width", "blocks_read",
-                              "blocks_table"}
+                              "blocks_table", "blocks_read_full",
+                              "full_blocks", "released"}
+    assert decode[5]["blocks_read_full"] == decode[5]["blocks_read"]
+    assert decode[5]["released"] == 0
     assert decode[5]["width"] == 4
     covered = sum(e[4] - e[3] for e in ring
                   if parent(e) == "engine.step")

@@ -19,7 +19,7 @@ import pytest
 
 from shallowspeed_tpu.models import transformer as T
 from shallowspeed_tpu.ops import flash_attention
-from shallowspeed_tpu.serving.cache import init_block_pool
+from shallowspeed_tpu.serving.cache import init_block_pool, layer_groups
 from shallowspeed_tpu.serving.engine import _decode_tick, _prefill_chunk
 
 
@@ -50,6 +50,14 @@ _HEADS = {
         qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=8,
         n_shared_experts=1, moe_top_k=2, expert_d_ff=256,
         first_dense_layers=1),
+    # a window layer and a full layer, each group with its own table
+    # (`serving/cache.py:layer_groups`), heads of 128 that d_model does
+    # not give, every part a block may hold, a dense and a routed layer
+    "trinity-window-full": dict(
+        d_model=2048, n_heads=32, n_kv_heads=4, attn_head_dim=128,
+        layers=((2048, True), (0, False)), embed_scale=2048 ** 0.5,
+        n_routed_experts=8, n_shared_experts=1, moe_top_k=2,
+        expert_d_ff=256, first_dense_layers=1),
 }
 # a head size no cell has: not whole lanes wide
 _NARROW = dict(_HEADS, **{"heads-of-64": dict(d_model=1024, n_heads=16,
@@ -91,21 +99,30 @@ def _compiled_text(program, one_chip, heads, kv_quant=""):
     def arr(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = spec(jax.eval_shape(
-        lambda: T.cast_params(T.init(cfg, seed=0), jnp.bfloat16)))
+    parts = T.BLOCK_PARTS if cfg.layers else ()
+    params = spec(jax.eval_shape(lambda: T.cast_params(
+        T.init(cfg, seed=0, parts=parts), jnp.bfloat16)))
     pools = spec(jax.eval_shape(
         lambda: init_block_pool(cfg, N_BLOCKS, BLOCK, kv_quant)))
     i32, f32 = jnp.int32, jnp.float32
+    # one table a layer group, and where a group's tables do not start
+    # at position 0 (a window group) their bases; one group: one table
+    groups = len(layer_groups(cfg))
+    tables = lambda rows: arr(i32, rows, WIDTH) if groups == 1 \
+        else (arr(i32, rows, WIDTH),) * groups
     if program == "decode_tick":
         traced = _decode_tick.trace(
             params, pools, arr(i32, SLOTS), arr(i32, SLOTS),
-            arr(i32, SLOTS, WIDTH), arr(f32, SLOTS), arr(i32, SLOTS),
+            tables(SLOTS), arr(f32, SLOTS), arr(i32, SLOTS),
             arr(i32, SLOTS), arr(i32, SLOTS), arr(jnp.bool_, SLOTS),
+            None if groups == 1 else arr(i32, groups, SLOTS),
             cfg=cfg, top_k=0, top_p=0.0)
     else:
+        pair = (arr(i32),) * 2 if groups == 1 else (arr(i32, groups),) * 2
         traced = _prefill_chunk.trace(
             params, pools, arr(i32, 1, CHUNK), arr(i32), arr(i32),
-            arr(i32, 1, WIDTH), arr(i32), arr(i32), cfg=cfg)
+            tables(1), *pair, None if groups == 1 else arr(i32, groups),
+            cfg=cfg)
     text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
     leaf = next(iter(pools[0].values()))
     return text, len(jax.tree_util.tree_leaves(pools)), leaf
