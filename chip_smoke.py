@@ -620,6 +620,57 @@ def paged_decode_errs(cases, d_model=512, n_heads=4, bs=16,
     return entries
 
 
+def paged_prefill_errs(cases, d_model=512, n_heads=4, bs=16, c=64,
+                       dtype=None) -> dict:
+    """The prefill chunk's paged kernel (`paged_flash_prefill`) vs the
+    same XLA reference, measured as `paged_decode_errs` measures: one
+    entry per K/V case of `paged_decode_cases` (the kernel takes no
+    int8 pool). A chunk of `c` rows, the last five of them padding,
+    that starts inside a block of a table twice as wide as what is
+    written."""
+    import jax
+    import jax.numpy as jnp
+
+    from shallowspeed_tpu.models import transformer as T
+    from shallowspeed_tpu.models.kv_cache import (masked_attention,
+                                                  position_mask)
+    from shallowspeed_tpu.ops.flash_attention import paged_flash_prefill
+    from shallowspeed_tpu.serving.cache import gather_table
+
+    rng = np.random.default_rng(13)
+    entries = {}
+    for name, kvh, quant, window in cases:
+        if quant:
+            continue
+        cfg = T.TransformerConfig(vocab=64, d_model=d_model,
+                                  n_heads=n_heads, n_kv_heads=kvh,
+                                  n_layers=1, max_seq=512,
+                                  compute_dtype=dtype)
+        n, w, at0, n_tok = 32, 16, 3 * bs + 5, c - 5
+        rand = lambda *shape: jnp.asarray(rng.normal(size=shape),
+                                          dtype or jnp.float32)
+        pool = {leaf: rand(n, cfg.kv_heads, bs, cfg.head_dim)
+                for leaf in ("k", "v")}
+        bt = jnp.asarray(rng.permutation(np.arange(1, n))[:w], jnp.int32)
+        q = rand(c, cfg.n_heads, cfg.head_dim)
+        got = paged_flash_prefill(q, pool, bt, jnp.int32(at0),
+                                  jnp.int32(n_tok), window=window)[:n_tok]
+        valid = position_mask(w * bs, (at0 + jnp.arange(c))[:, None], window)
+
+        def reference():
+            return masked_attention(
+                q[None], gather_table(pool, bt[None]),
+                valid[None, None, None], cfg)[0][:n_tok]
+
+        with jax.default_matmul_precision("highest"):
+            oracle = reference()
+        entries[name.replace("decode", "prefill")] = {
+            "flash": round(relmax(got, oracle), 7),
+            "xla_floor": round(relmax(reference(), oracle), 7),
+            "ref": "gather_table+masked_attention"}
+    return entries
+
+
 def paged_decode_pass(entries: dict, compiled: bool) -> bool:
     """Within 3x the reference's own default-precision error plus an
     allowance. Interpreted, both sides compute f32 scores and what
@@ -671,14 +722,21 @@ def kernels_phase(size: str) -> int:
             paged_decode_cases(pg["gqa_kvh"], pg["window"]),
             d_model=pg["d_model"], n_heads=pg["n_heads"]),
     }
-    for where, entries in paged.items():
-        if not paged_decode_pass(entries, compiled=not interpreted):
-            failed.append(f"paged decode out of tolerance at {where}: "
-                          f"{entries}")
+    prefill = {
+        "default_shapes": paged_prefill_errs(paged_decode_cases()),
+        "smoke_shapes": paged_prefill_errs(
+            paged_decode_cases(pg["gqa_kvh"], pg["window"]),
+            d_model=pg["d_model"], n_heads=pg["n_heads"]),
+    }
+    for what, checks in (("decode", paged), ("prefill", prefill)):
+        for where, entries in checks.items():
+            if not paged_decode_pass(entries, compiled=not interpreted):
+                failed.append(f"paged {what} out of tolerance at {where}: "
+                              f"{entries}")
 
     print(json.dumps({"ok": not failed, "failed": failed, "device": dev,
                       "interpreted": interpreted, "flash": flash,
-                      "paged": paged}))
+                      "paged": paged, "paged_prefill": prefill}))
     return 1 if failed else 0
 
 
@@ -692,7 +750,8 @@ def run_kernels_phase(size: str, out: Path, env=None) -> dict:
     result = events[-1] if events else {}
     bad = list(result.get("failed", [])) or child_failure(run, console)
     return {"ok": not bad, "failed": bad, "wall_s": run["wall_s"],
-            **{k: result.get(k) for k in ("interpreted", "flash", "paged")}}
+            **{k: result.get(k) for k in ("interpreted", "flash", "paged",
+                                          "paged_prefill")}}
 
 
 # ---------------------------------------------------------------- main

@@ -122,7 +122,10 @@ def masked_attention(q, cache_blk, valid, cfg):
     use): the form the serving tick ran until PR 29 and the reference
     of the kernel that took its place
     (`ops.flash_attention.paged_flash_decode`). A chunk of queries
-    (prefill) folds its row's pages into one head-major page first.
+    (prefill) folds its row's pages into one head-major page first:
+    the serving chunk's read of an int8 pool, and the reference of the
+    kernel that reads the others (`paged_flash_prefill`). The scores
+    are (B, Hkv, G, Tq, S) float32 in HBM, whatever part of S is live.
 
     GQA caches hold Hkv heads and are read UNREPEATED (grouped einsum):
     decode is HBM-bandwidth-bound on the cache sweep, so the group

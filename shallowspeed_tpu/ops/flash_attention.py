@@ -923,8 +923,9 @@ ring_flash_attention.defvjp(_ring_fwd_rule, _ring_bwd_rule)
 # Single-query attention for the serving runtime's paged cache: the
 # decode tick's read, for K/V pools and the latent pool alike
 # (`serving/engine._decode_tick`). The XLA reference
-# (`serving/cache.gather_table` + `kv_cache.masked_attention`, which
-# the prefill chunk still runs) first MATERIALIZES every row's table at
+# (`serving/cache.gather_table` + `kv_cache.masked_attention`; the
+# prefill chunk's read of a K/V pool is `paged_flash_prefill`, below)
+# first MATERIALIZES every row's table at
 # the bucket's width and then contracts one query over all of it, in
 # f32 on the VPU: the tick moved ~640 MB a layer for 19 MB of live
 # blocks (`olmo-1b.chat`, PERF.md PR 29). This kernel reads the pool
@@ -1137,8 +1138,8 @@ def paged_flash_decode(q, pool_blk, bt, pos, *, window: int = 0,
     step holds (default: `_STEP_BYTES` of them, in eights). Returns
     (S, H, hd) in q's dtype.
 
-    Matches `masked_attention(q, gather_table(pool, bt), valid)` — the
-    XLA reference that the prefill chunk runs — to fp-reorder noise
+    Matches `masked_attention(q, gather_table(pool, bt), valid)`, the
+    XLA reference, to fp-reorder noise
     interpreted (<= 1e-4 pinned): same score/softmax path, same
     outside-the-dot int8 scale placement, no gathered copy, and only
     the blocks a row's mask admits are read at all. GQA is native
@@ -1187,3 +1188,244 @@ def paged_flash_decode(q, pool_blk, bt, pos, *, window: int = 0,
         interpret=interpret,
     )(bt, pos, q.reshape(s, hkv, h // hkv, hd), *leaves, *scales)
     return out.reshape(s, h, -1)
+
+
+# ----------------------------------------------------- paged flash prefill
+#
+# The prefill chunk's read of a K/V pool (`serving/engine._prefill_chunk`):
+# C queries at consecutive positions of ONE request, over that request's
+# table. The XLA read it replaces gathered the table at the width of the
+# WHOLE prompt's bucket, whatever part of it was written, made the row
+# head-major and formed (Hkv, G, C, W * bs) float32 scores in HBM: 268 MB
+# a layer, three passes, for `mistral-7b-v0.1.doc-batch` (PERF.md, PR 34).
+# This kernel is the decode kernel's walk with a tile of queries in the
+# place of one: the grid runs over query tiles, each walks the table
+# columns its rows can see (from the first block of its first row's
+# window to the block of its last true row's position) in double-buffered
+# steps of `chunk` blocks, and a step is two matmuls a KV head with the
+# head's G query heads folded into the rows (`(G * tq, hd) x (hd, t)`),
+# scores and softmax state float32 in VMEM. A tile wholly past `n_tok`
+# reads nothing and gives zeros; a padding row inside a live tile repeats
+# the last true row (it may not look past what is written).
+
+
+# What the chip's timings chose (`scripts/bench_paged_prefill.py`; PERF.md,
+# PR 34). A step's cost has a part that follows its query rows alone (the
+# running max and sum of every row are read, reduced over lanes, spread
+# and written back once a head and step), so few large steps beat many
+# small ones: at `mistral-7b-v0.1`'s shape and 3,840 live keys a layer
+# took 1,164 / 879 / 496 / 320 us at 128 / 256 / 512 / 1,024 keys a step.
+_PREFILL_STEP_KEYS = 1024
+# query rows of one matmul, the G query heads of a KV head together (a
+# head's float32 scores over a step are rows x 4 KB), and query heads x
+# queries of a tile (its softmax state is 1.5 KB a head and query, in
+# VMEM for the whole tile): the chunk's K/V is read once a tile
+_PREFILL_ROWS, _PREFILL_HEAD_ROWS = 2048, 16384
+# what the kernel may hold of a v5e's 128 MiB of VMEM (the compiler's own
+# limit is 16 MiB): a tile's state, its queries and output twice (the
+# grid's pipeline), the two K/V buffers and a head's scores
+_PREFILL_VMEM = 64 << 20
+# tables of at most this many positions keep the gathered read: at
+# `olmo-1b`'s shape (16 heads) XLA scores a table of 128 positions in 36
+# us and one of 1,024 in 77, where the kernel takes 50 and 90 (its tile's
+# set-up and the two transposes of the queries); past 2,048 positions it
+# reads in a quarter to a half of XLA's time at every fill
+_PREFILL_MIN_KEYS = 1024
+
+
+def _paged_prefill_kernel(bt_ref, at_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
+                          vbuf, m_scr, l_scr, acc_scr, sem, *, scale, bs,
+                          chunk, window, tq):
+    """One query tile: `q_ref` / `o_ref` (Hkv, G * tq, hd), row g * tq + i
+    the tile's i-th query in the KV head's g-th query head; `at_ref` the
+    chunk's first position in the table's coordinates and its true
+    length; `kbuf` / `vbuf` (2, Hkv, chunk, bs, hd); the running max,
+    sum (lane-broadcast) and unnormalised output of every head's rows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    hkv, rows, hd = q_ref.shape
+    t = chunk * bs
+    unroll = min(chunk, _DMA_UNROLL)
+    prec = (None if q_ref.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    at0, n_tok = at_ref[0], at_ref[1]
+    row0 = pl.program_id(0) * tq
+
+    def dma(b0, hi, slot, start):
+        def one(i, _=None):
+            for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                cp = pltpu.make_async_copy(pool.at[bt_ref[b0 + i]],
+                                           buf.at[slot, :, i], sem.at[slot])
+                cp.start() if start else cp.wait()
+
+        def several(j, _):
+            for i in range(unroll):
+                one(j * unroll + i)
+
+        n = jnp.minimum(chunk, hi - b0)
+        groups = n // unroll if unroll > 1 else 0
+        jax.lax.fori_loop(0, groups, several, None)
+        jax.lax.fori_loop(groups * unroll, n, one, None)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _clean():
+        # a partial step leaves what the buffer held behind its last
+        # slab: masked out of the scores, but 0 * NaN is NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(row0 >= n_tok)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(row0 < n_tok)
+    def _live():
+        last = at0 + jnp.minimum(row0 + tq, n_tok) - 1
+        first = at0 + row0
+        lo = jnp.maximum(first - (window - 1), 0) // bs if window > 0 else 0
+        hi = last // bs + 1
+        steps = (hi - lo + chunk - 1) // chunk
+        # each row's position; padding rows stand on the last true one
+        at = jnp.minimum(first + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) % tq, last)
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        dma(lo, hi, 0, True)
+
+        def step(c, slot):
+            b0 = lo + c * chunk
+
+            @pl.when(c + 1 < steps)
+            def _prefetch():
+                dma(b0 + chunk, hi, 1 - slot, True)
+
+            dma(b0, hi, slot, False)
+            col = b0 * bs + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+            valid = col <= at
+            if window > 0:
+                valid = valid & (col > at - window)
+
+            def head(h, _):
+                k = kbuf[slot, h].reshape(t, hd)
+                v = vbuf[slot, h].reshape(t, hd)
+                s = jax.lax.dot_general(
+                    q_ref[h], k, (((1,), (1,)), ((), ())), precision=prec,
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(valid, s, _NEG)
+                m = m_scr[h][:, :1]
+                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                # a masked score is _NEG and its exp 0 beside any true
+                # maximum; a row that has met none yet (its window
+                # starts later) sums ones, which the alpha of its first
+                # true maximum, exp(_NEG - m), wipes to 0
+                pr = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l_scr[h] = jnp.broadcast_to(
+                    l_scr[h][:, :1] * alpha + pr.sum(axis=-1, keepdims=True),
+                    l_scr.shape[1:])
+                m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+                acc_scr[h] = acc_scr[h] * alpha + jnp.dot(
+                    pr.astype(v.dtype), v, precision=prec,
+                    preferred_element_type=jnp.float32)
+
+            jax.lax.fori_loop(0, hkv, head, None)
+            return 1 - slot
+
+        jax.lax.fori_loop(0, steps, step, jnp.int32(0))
+
+        def out(h, _):
+            o_ref[h] = (acc_scr[h] / l_scr[h][:, :1]).astype(o_ref.dtype)
+
+        jax.lax.fori_loop(0, hkv, out, None)
+
+
+def paged_prefill_addresses(pool_blk, table_blocks: int) -> bool:
+    """Whether the prefill chunk reads this pool, through a table of
+    `table_blocks` columns, with `paged_flash_prefill`: a K/V pool in
+    the compute dtype whose blocks the slab DMA can address
+    (`paged_decode_addresses`), under a table wide enough for the walk
+    to beat XLA's read of all of it (`_PREFILL_MIN_KEYS`). The latent
+    pool (its chunk read is another contraction), int8 pools and narrow
+    tables keep the gathered read."""
+    return ("k" in pool_blk and "k_s" not in pool_blk
+            and table_blocks * pool_blk["k"].shape[2] > _PREFILL_MIN_KEYS
+            and paged_decode_addresses(pool_blk))
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "chunk",
+                                             "tq", "interpret"))
+def paged_flash_prefill(q, pool_blk, bt, at0, n_tok, *, window: int = 0,
+                        scale: float | None = None,
+                        chunk: int | None = None, tq: int | None = None,
+                        interpret: bool | None = None):
+    """Causal attention of a chunk of queries through ONE request's
+    block table, fused.
+
+    q: (C, H, hd), query i at position `at0 + i` of the table's own
+    coordinates (a window group's table starts at its request's first
+    live block); pool_blk: one layer's {"k", "v": (N, Hkv, bs, hd)};
+    bt: (W,) int32, as wide as the prompt will ever need; `n_tok`: the
+    chunk's true length (rows past it are padding). Query i sees table
+    positions (at0 + i - window, at0 + i] (all of [0, at0 + i] where
+    `window` is 0), which the chunk's own keys, written before the
+    read, are part of. Only table columns from the first block of the
+    first query's window to `(at0 + n_tok - 1) // bs` are read, however
+    wide the table. `chunk`: blocks a compute step (default:
+    `_PREFILL_STEP_KEYS` positions), `tq`: queries a tile (default:
+    what `_PREFILL_ROWS` and `_PREFILL_HEAD_ROWS` allow). Returns
+    (C, H, hd) in q's dtype; rows past `n_tok` hold nothing of use.
+
+    Matches `masked_attention(q, gather_table(pool, bt), valid)`, the
+    XLA read it took the place of, on the true rows to fp-reorder noise
+    interpreted (<= 1e-4, tests/test_paged_prefill.py): the same score
+    and softmax arithmetic, float32 in VMEM where that read kept it in
+    HBM. Jitted in its own right, as `paged_flash_decode` is."""
+    if interpret is None:
+        interpret = _interpret_default()
+    from jax.experimental.pallas import tpu as pltpu
+
+    c, h, hd = q.shape
+    k, v = pool_blk["k"], pool_blk["v"]
+    hkv, bs = k.shape[1:3]
+    g = h // hkv
+    assert h == hkv * g, (h, hkv)
+    if chunk is None:
+        chunk = max(1, _PREFILL_STEP_KEYS // bs)
+    chunk = max(1, min(bt.shape[0], chunk))
+    if tq is None:
+        tq = max(16, min(_PREFILL_ROWS // g, _PREFILL_HEAD_ROWS // h))
+    tq = min(tq, -(-c // 16) * 16)
+    nq = -(-c // tq)
+    rows = g * tq
+    # (C, Hkv, G, hd) -> a tile's rows KV-head-major, query heads inside
+    qt = jnp.pad(q, ((0, nq * tq - c), (0, 0), (0, 0)))
+    qt = qt.reshape(nq, tq, hkv, g, hd).transpose(2, 0, 3, 1, 4)
+    qt = qt.reshape(hkv, nq, rows, hd)
+    kernel = functools.partial(
+        _paged_prefill_kernel, bs=bs, chunk=chunk, window=int(window),
+        scale=float(hd ** -0.5 if scale is None else scale), tq=tq)
+    tile = pl.BlockSpec((hkv, None, rows, hd), lambda i, *_: (0, i, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    f32 = jnp.float32
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nq,),
+        in_specs=[tile, hbm, hbm],
+        out_specs=tile,
+        scratch_shapes=[pltpu.VMEM((2, hkv, chunk, bs, hd), k.dtype),
+                        pltpu.VMEM((2, hkv, chunk, bs, hd), v.dtype),
+                        pltpu.VMEM((hkv, rows, _LANES), f32),
+                        pltpu.VMEM((hkv, rows, _LANES), f32),
+                        pltpu.VMEM((hkv, rows, hd), f32),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=_sds((hkv, nq, rows, hd), q.dtype, q),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM),
+        interpret=interpret,
+    )(bt, jnp.stack([at0, n_tok]).astype(jnp.int32), qt, k, v)
+    out = out.reshape(hkv, nq, g, tq, hd).transpose(1, 3, 0, 2, 4)
+    return out.reshape(nq * tq, h, hd)[:c]

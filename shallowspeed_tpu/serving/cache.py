@@ -454,10 +454,11 @@ class PrefixIndex:
 
 
 def gather_table(pool_blk, bt):
-    """Read one layer's cache through a block table: the prefill
-    chunk's read, and the XLA reference that the decode tick's kernel
-    (`ops.flash_attention.paged_flash_decode`, which gathers nothing)
-    is pinned against in the tests.
+    """Read one layer's cache through a block table: the XLA reference
+    that the decode tick's kernel and the prefill chunk's
+    (`ops.flash_attention.paged_flash_decode`, `paged_flash_prefill`,
+    which gather nothing) are pinned against in the tests, and the read
+    of the pools those do not take (`paged_prefill_addresses`).
 
     pool_blk: {"k"/"v": (N, Hkv, bs, hd)[, "k_s"/"v_s": (N, Hkv, bs, 1)]}
     bt: (rows, W) int32 block ids (padding rows/tail point at the
@@ -466,9 +467,10 @@ def gather_table(pool_blk, bt):
     gather leaves it: page w, slot s of a row IS absolute position
     w*bs + s because tables are ordered. `kv_cache.masked_attention`
     contracts over it. It is as wide as the table's bucket whatever the
-    rows hold: for one row and a chunk of queries that is amortised;
-    for every slot of a tick with one query each it was 63% of
-    `olmo-1b.chat`'s device time (PERF.md, PR 29)."""
+    rows hold: for every slot of a tick with one query each it was 63%
+    of `olmo-1b.chat`'s device time (PERF.md, PR 29), and for one row
+    and a chunk of queries, with float32 scores over all of it, 41% of
+    a chunk in `mistral-7b-v0.1.doc-batch` (PR 34)."""
     return {name: leaf[bt] for name, leaf in pool_blk.items()}
 
 

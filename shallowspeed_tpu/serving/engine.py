@@ -60,9 +60,13 @@ head-of-line behind the longest. This engine serves a STREAM:
     each row's live blocks through the table (scalar prefetch, manual
     DMA from the pool in HBM), online softmax across a row's blocks,
     int8 KV + scales read natively. No gathered table is built,
-    whatever the bucket's width; `gather_table` + `masked_attention`
-    are the prefill chunk's read and the reference the kernel is
-    pinned against (<= 1e-4). `attn_impl` selects nothing.
+    whatever the bucket's width. The prefill chunk reads a K/V pool
+    the same way where its table is wide (`paged_flash_prefill`: a
+    tile of queries in the place of one, the table walked only as far
+    as the chunk's last true position); `gather_table` +
+    `masked_attention` are the reference both kernels are pinned
+    against (<= 1e-4) and the read of the pools and tables they do not
+    take. `attn_impl` selects nothing.
   - *Speculative decoding* (`spec_k > 0`): a self-drafting n-gram
     prompt-lookup proposer (`_propose`) fills FREE rows of the
     fixed-capacity tick with up to K draft tokens per decoding
@@ -92,7 +96,7 @@ Stream parity: sampling uses the SAME per-request key schedule as
 `generate()` — token i of a request with sampling seed s draws from
 `fold_in(PRNGKey(s), i)` — and the paged attention computes what
 `kv_cache.masked_attention` computes on the contiguous path (the
-chunk through that very function, the tick's kernel pinned to it), so
+tick's kernel and the chunk's are pinned to it), so
 each request's stream reproduces its solo `generate()` stream
 token-for-token (pinned in tests/test_serving.py; see `generate`'s
 stream-stability contract for the ~1e-6 numerics caveat).
@@ -112,7 +116,9 @@ import numpy as np
 from shallowspeed_tpu import chaos
 from shallowspeed_tpu.models import generate as G
 from shallowspeed_tpu.ops.flash_attention import (paged_decode_addresses,
-                                                   paged_flash_decode)
+                                                   paged_flash_decode,
+                                                   paged_flash_prefill,
+                                                   paged_prefill_addresses)
 from shallowspeed_tpu.telemetry.trace import tracer
 from shallowspeed_tpu.telemetry.tracing import new_span_id, new_trace_id
 from shallowspeed_tpu.models import transformer as T
@@ -203,7 +209,8 @@ _sample_jit = jax.jit(_sample_rows, static_argnames=("top_k", "top_p"))
 
 def _latent_read(p, pool, bt, q_nope, q_rope, valid, cfg):
     """A latent layer's attention over its gathered table (the prefill
-    chunk's read; the tick's is `_latent_decode`), absorbed:
+    chunk's read of a latent pool; the tick's is `_latent_decode`),
+    absorbed:
     the (rows, W, 1, bs, r + dr) pages ARE (rows, W*bs, r + dr) latent
     rows in position order (one shared "head": nothing to make
     head-major), and every query head contracts with them as stored."""
@@ -360,9 +367,15 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     (earlier chunks included). `bt` is one (1, W) table a layer group
     and `base` (groups,) where a group's table does not start at
     position 0, as `_decode_tick` takes them; `cow_src` / `cow_dst`
-    likewise one pair a group. A window group's table never holds more
-    than its window and the chunk, so that is all its layers gather
-    and score, however long the prompt. Returns (f32 logits at the chunk's last
+    likewise one pair a group. A K/V pool under a wide table is read
+    where it lies (`paged_flash_prefill`: the table's columns from the
+    first query's window to the chunk's last true position, however
+    wide the table and however long the prompt;
+    `paged_prefill_addresses` says which pools and tables); a latent
+    pool, an int8 pool and a table of a thousand positions or fewer
+    are gathered at the table's width and scored under the position
+    mask, which is the quicker read there.
+    Returns (f32 logits at the chunk's last
     true position — consumed only on the final chunk — the updated,
     donated pools, and the routed layers' assignment counts of the
     chunk's true rows as `_decode_tick` gives them).
@@ -395,26 +408,35 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     tables = _group_tables(bt, base, pos0)
     live = (jnp.arange(c) < n_tok)[None, :]                 # (1, C)
     rope = lambda u: T.rope_rotate(u, pos, cfg.rope_theta)
+
+    def valid(bt_g, at0, window):       # the gathered reads' (C, W * bs)
+        return position_mask(bt_g.shape[1] * bs,
+                             (at0 + jnp.arange(c))[:, None], window)
+
     new_pools, counts = [], []
     for p, pool, (window, rotary), g in zip(
             params["blocks"], pools, cfg.layer_specs, group):
         bt_g, at0 = tables[g]
-        at = at0 + jnp.arange(c)
-        valid = position_mask(bt_g.shape[1] * bs, at[:, None], window)
         h = T._norm(p["ln1"], x, cfg)
         if LATENT in pool:
             qn, qr, lat, kr = T.latent_qkv(p, h, cfg, rope)
             pool = write_chunk(pool, lat[0][:, None], kr[0][:, None],
                                bt_g[0], at0, n_tok, False)
-            a = _latent_read(p, pool, bt_g, qn, qr, valid[None, None], cfg)
+            a = _latent_read(p, pool, bt_g, qn, qr,
+                             valid(bt_g, at0, window)[None, None], cfg)
         else:
             q, k, v = T._qkv(p, h, cfg)
             if rotary:
                 q, k = rope(q), rope(k)
             pool = {**pool, **write_chunk(pool, k[0], v[0], bt_g[0], at0,
                                           n_tok, quant)}
-            a = masked_attention(q, gather_table(pool, bt_g),
-                                 valid[None, None, None], cfg)
+            if paged_prefill_addresses(pool, bt_g.shape[1]):
+                a = paged_flash_prefill(q[0], pool, bt_g[0], at0, n_tok,
+                                        window=window)
+            else:
+                a = masked_attention(
+                    q, gather_table(pool, bt_g),
+                    valid(bt_g, at0, window)[None, None, None], cfg)
         x = T.attn_residual(p, x, a.reshape(1, c, -1), h, cfg)
         x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
         if n is not None:
@@ -520,9 +542,10 @@ class ServingEngine:
                  lifecycle: bool = True, chaos_plan=None,
                  prefix_cache: bool = False):
         # `attn_impl` selects nothing any more: the tick reads every
-        # pool through the paged kernel and the chunk through the
-        # gathered table. The two names are still accepted because the
-        # benchmark's drivers and `serve.py` pass one (ROADMAP D1).
+        # pool through the paged kernel, and the chunk the K/V pools
+        # under wide tables (`paged_prefill_addresses`). The two names
+        # are still accepted because the benchmark's drivers and
+        # `serve.py` pass one (ROADMAP D1).
         if attn_impl not in ("gather", "flash"):
             raise ValueError(
                 f"unsupported attn_impl={attn_impl!r}; expected "
@@ -631,6 +654,12 @@ class ServingEngine:
                          # that group's part) and the blocks their
                          # tables had room for
                          "blocks_read": 0, "blocks_table": 0,
+                         # the same of the prefill chunks: table columns
+                         # a chunk's read walked in one layer of each
+                         # group (all of them where the pool is gathered)
+                         # and the width of its tables
+                         "prefill_blocks_read": 0,
+                         "prefill_blocks_table": 0,
                          # blocks handed back to a window group's free
                          # list because they left their request's window
                          "released": 0}
@@ -1189,6 +1218,20 @@ class ServingEngine:
                                       if g.window else 0)).sum())
                 for g in self.groups]
 
+    def _chunk_walked(self, req, n_tok: int, bts) -> list[int]:
+        """Table columns the read of `req`'s next chunk walks in ONE
+        layer of each group, from the host's own `written`: from the
+        first block of the first query's window to the block of the
+        chunk's last true position (`paged_flash_prefill`; a query tile
+        walks its own part of them), or the table's whole width where
+        it is gathered (`paged_prefill_addresses`)."""
+        bs = self.block_size
+        at0 = [req.written - b * bs for b in req.base]
+        return [(at + n_tok - 1) // bs + 1 - g.first_live_block(at, bs)
+                if paged_prefill_addresses(self.pools[g.layers[0]], bt.size)
+                else bt.size
+                for g, at, bt in zip(self.groups, at0, bts)]
+
     def _rows_tables(self, rows, n: int, room=None) -> tuple:
         """The programs' table arguments for `rows`, [(row of the
         arrays, request)], of `n` rows in all: one (n, width) table a
@@ -1239,6 +1282,13 @@ class ServingEngine:
                 self.params, self.pools, tokens, np.int32(req.written),
                 np.int32(n_tok), bts, cow[:, 0], cow[:, 1],
                 None if base is None else base[:, 0], cfg=self.cfg)
+        walked = self._chunk_walked(req, n_tok, bts)
+        read = {"blocks_read": sum(walked),
+                "blocks_table": sum(bt.size for bt in bts)}
+        sp.set(**read, **{f"blocks_read_{g.name}": n
+                          for g, n in zip(self.groups, walked)})
+        for name, value in read.items():
+            self.counters[f"prefill_{name}"] += value
         if req.cow is not None:
             # the copy landed: drop the references that kept the shared
             # source blocks alive for it

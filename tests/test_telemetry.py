@@ -587,6 +587,26 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
     assert 0.75 * whole <= covered <= whole
 
 
+def test_prefill_span_says_what_the_chunk_read(global_tracer, tiny_serving):
+    """`blocks_read` on a `prefill` span is the table columns the
+    chunk's read took in one layer and `blocks_table` the table's
+    width: a table this narrow (4 blocks of 8) is gathered whole in
+    every chunk (tests/test_paged_prefill.py has the walk of a wide
+    one). The engine's `prefill_blocks_*` counters are their sums."""
+    eng, serve = tiny_serving
+    names = ("prefill_blocks_read", "prefill_blocks_table", "prefill_chunks")
+    before = {k: eng.counters[k] for k in names}
+    serve("chunks-")
+    chunks = [e[5] for e in global_tracer.ring() if e[2] == "prefill"]
+    assert sorted((a["chunk"], a["blocks_read"], a["blocks_table"])
+                  for a in chunks) == [(0, 4, 4)] * 3 + [(1, 4, 4)] * 3
+    assert all(a["blocks_read_full"] == a["blocks_read"] for a in chunks)
+    assert eng.counters["prefill_chunks"] - before["prefill_chunks"] == 6
+    for k in names[:2]:
+        assert eng.counters[k] - before[k] == sum(
+            a[k.removeprefix("prefill_")] for a in chunks) == 24
+
+
 def test_decode_span_says_what_the_tick_read(global_tracer, tiny_serving):
     """`blocks_read` on a `decode` span is the pool blocks its read
     walked, from the host's own positions: a decoding row at position p

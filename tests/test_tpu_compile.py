@@ -11,6 +11,7 @@ test file. Keep further compile-only tests in THIS file for the same
 reason (a second file can land on a worker that cannot load it).
 """
 
+import math
 import re
 
 import jax
@@ -64,10 +65,11 @@ _NARROW = dict(_HEADS, **{"heads-of-64": dict(d_model=1024, n_heads=16,
                                               n_kv_heads=0)})
 # pool leaves of 42 MB and more, as the cells have them (67-252 MB): a
 # leaf that fits the compiler's alternate memory (21 MB did, at 321 and
-# 641 blocks) XLA may prefetch there whole around the Mosaic call that
-# reads it, one copy in and one out, and the gate below would read that
-# as the layout copies it exists to catch
-N_BLOCKS, BLOCK, SLOTS, WIDTH, CHUNK = 1281, 16, 16, 8, 128
+# 641 blocks, and four KV heads' at 1281 around the chunk's kernel) XLA
+# may prefetch there whole around the Mosaic call that reads it, one
+# copy in and one out, and the gate below would read that as the layout
+# copies it exists to catch
+N_BLOCKS, BLOCK, SLOTS, WIDTH, CHUNK = 2561, 16, 16, 8, 128
 
 _SKIP_OPS = ("parameter", "bitcast", "get-tuple-element", "tuple",
              "constant")
@@ -79,13 +81,21 @@ def mosaic_not_interpreted(monkeypatch):
     the interpreter; what is compiled here is compiled for the chip."""
     monkeypatch.setattr(flash_attention, "_interpret_default",
                         lambda: False)
-    # the kernel's entry point is jitted and would remember either mode
-    flash_attention.paged_flash_decode.clear_cache()
+    # the kernels' entry points are jitted and would remember either mode
+    kernels = (flash_attention.paged_flash_decode,
+               flash_attention.paged_flash_prefill)
+    for kernel in kernels:
+        kernel.clear_cache()
     yield
-    flash_attention.paged_flash_decode.clear_cache()
+    for kernel in kernels:
+        kernel.clear_cache()
 
 
-def _compiled_text(program, one_chip, heads, kv_quant=""):
+def _compiled_text(program, one_chip, heads, kv_quant="", widths=None,
+                   chunk=CHUNK, n_blocks=N_BLOCKS):
+    """The optimized HLO of `program` for the chip, its number of pool
+    leaves and one of them. `widths`: the tables' widths, one a layer
+    group (default: `WIDTH` each)."""
     cfg = T.TransformerConfig(
         vocab=512, d_ff=512, n_layers=2, max_seq=2048, rope=True,
         norm="rmsnorm", ffn="swiglu", dtype=jnp.bfloat16,
@@ -103,13 +113,14 @@ def _compiled_text(program, one_chip, heads, kv_quant=""):
     params = spec(jax.eval_shape(lambda: T.cast_params(
         T.init(cfg, seed=0, parts=parts), jnp.bfloat16)))
     pools = spec(jax.eval_shape(
-        lambda: init_block_pool(cfg, N_BLOCKS, BLOCK, kv_quant)))
+        lambda: init_block_pool(cfg, n_blocks, BLOCK, kv_quant)))
     i32, f32 = jnp.int32, jnp.float32
     # one table a layer group, and where a group's tables do not start
     # at position 0 (a window group) their bases; one group: one table
     groups = len(layer_groups(cfg))
-    tables = lambda rows: arr(i32, rows, WIDTH) if groups == 1 \
-        else (arr(i32, rows, WIDTH),) * groups
+    widths = widths or (WIDTH,) * groups
+    tables = lambda rows: arr(i32, rows, widths[0]) if groups == 1 \
+        else tuple(arr(i32, rows, w) for w in widths)
     if program == "decode_tick":
         traced = _decode_tick.trace(
             params, pools, arr(i32, SLOTS), arr(i32, SLOTS),
@@ -120,7 +131,7 @@ def _compiled_text(program, one_chip, heads, kv_quant=""):
     else:
         pair = (arr(i32),) * 2 if groups == 1 else (arr(i32, groups),) * 2
         traced = _prefill_chunk.trace(
-            params, pools, arr(i32, 1, CHUNK), arr(i32), arr(i32),
+            params, pools, arr(i32, 1, chunk), arr(i32), arr(i32),
             tables(1), *pair, None if groups == 1 else arr(i32, groups),
             cfg=cfg)
     text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
@@ -156,7 +167,11 @@ def test_serving_programs_write_the_pool_in_place(one_chip, program,
     own buffer). A pool-sized `copy`, `transpose` or relayout fusion
     here is two of them per leaf per program run on the chip: 45% of
     `olmo-1b.chat`'s device time before this test existed."""
-    text, n_leaves, leaf = _compiled_text(program, one_chip, heads)
+    _assert_written_in_place(
+        program, *_compiled_text(program, one_chip, heads))
+
+
+def _assert_written_in_place(program, text, n_leaves, leaf):
     pool_elems = leaf.size
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp",
                         text, re.S).group(1)
@@ -220,8 +235,71 @@ def test_decode_tick_reads_the_pool_through_the_kernel(one_chip, heads,
     before, _, _ = _compiled_text("decode_tick", one_chip, heads)
     engine._decode_tick.clear_cache()
     assert table_ops(before) and "tpu_custom_call" not in before
-    # the chunk's read is not this PR's: it gathers its one row's table
-    chunk, _, _ = _compiled_text("prefill_chunk", one_chip, heads)
+
+
+def _results(text):
+    """(dtype, dimensions, line) of every instruction's result, in
+    every computation."""
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]", line)
+        if m:
+            dims = [int(d) for d in filter(None, m.group(2).split(","))]
+            yield m.group(1), dims, line.strip()[:160]
+
+
+@pytest.mark.parametrize("heads,widths", [
+    ("mistral-7b-gqa", (256,)),             # `doc-batch`: 4,096 keys
+    ("trinity-window-full", (768, 176)),    # `reason-batch`: 12 k, window
+], ids=["doc-batch", "reason-batch"])
+def test_prefill_chunk_reads_the_pool_through_the_kernel(one_chip, heads,
+                                                         widths, monkeypatch):
+    """The gate of PR 34: the chunk of 512 tokens compiled for the chip
+    at the cells' table widths holds one Mosaic call a layer
+    (`paged_flash_prefill`) and NO instruction, in any computation, as
+    large as a group's gathered table (W x Hkv x block x hd in the
+    pool's dtype, rows of hd values, however XLA merged the rest) or as
+    its float32 scores (H x C x W * block): 268 MB
+    a layer in `mistral-7b-v0.1.doc-batch`, three passes over them 41%
+    of a chunk. It still writes the pool in place."""
+    from shallowspeed_tpu.serving import engine
+
+    def big(text):
+        tables = {w * leaf.size // leaf.shape[0] for w in widths}
+        scores = {n_heads * 512 * w * BLOCK for w in widths}
+        return [line for dtype, dims, line in _results(text)
+                if (dtype == "bf16" and math.prod(dims) in tables
+                    and len(dims) > 2 and dims[-1] == leaf.shape[-1])
+                or (dtype == "f32" and math.prod(dims) in scores)]
+
+    # `reason-batch`'s window pools, 169 MB a leaf there: XLA took a
+    # 42 MB leaf into its alternate memory around this chunk's kernels
+    shape = dict(widths=widths, chunk=512, n_blocks=10305)
+    n_heads = _HEADS[heads]["n_heads"]
+    text, n_leaves, leaf = _compiled_text("prefill_chunk", one_chip, heads,
+                                          **shape)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not big(text), "\n".join(big(text))
+    _assert_written_in_place("prefill_chunk", text, n_leaves, leaf)
+    # what this looks for is there to be found: the same chunk through
+    # the gathered read holds both, and no kernel
+    monkeypatch.setattr(engine, "paged_prefill_addresses",
+                        lambda pool, width: False)
+    engine._prefill_chunk.clear_cache()
+    before, _, _ = _compiled_text("prefill_chunk", one_chip, heads, **shape)
+    engine._prefill_chunk.clear_cache()
+    dtypes = {line.split(" = ")[1][:3] for line in big(before)}
+    assert dtypes == {"bf1", "f32"} and "tpu_custom_call" not in before
+
+
+@pytest.mark.parametrize("heads,width", [("moonlight-16b-latent", 512),
+                                         ("olmo-1b-mha", 64)],
+                         ids=["gen-batch", "chat"])
+def test_the_other_chunks_keep_the_gathered_read(one_chip, heads, width):
+    """`moonlight-16b-a3b`'s chunk program and `olmo-1b`'s are the
+    parent's: a latent pool, and tables of at most 1,024 positions, are
+    not `paged_flash_prefill`'s (`paged_prefill_addresses`)."""
+    chunk, _, _ = _compiled_text("prefill_chunk", one_chip, heads,
+                                 widths=(width,), chunk=512)
     assert "tpu_custom_call" not in chunk
 
 
