@@ -79,6 +79,9 @@ SIZES = {
                       "window": 256},
             "paged": {"d_model": 2048, "n_heads": 16, "gqa_kvh": 4,
                       "window": 24},
+            # 20 query heads on 4 K/V heads of 128: a group of FIVE rows
+            # a K/V head's matmul, which no power-of-two model sends
+            "paged_group5": {"d_model": 2560, "n_heads": 20, "gqa_kvh": 4},
         },
         "timeout": {"train": 600, "serve": 300, "kernels": 600},
     },
@@ -96,6 +99,7 @@ SIZES = {
                       "window": 32},
             "paged": {"d_model": 64, "n_heads": 2, "gqa_kvh": 1,
                       "window": 24},
+            "paged_group5": {"d_model": 160, "n_heads": 5, "gqa_kvh": 1},
         },
         "timeout": {"train": 300, "serve": 300, "kernels": 600},
     },
@@ -715,18 +719,23 @@ def kernels_phase(size: str) -> int:
             failed.append(f"flash kernels out of tolerance at {where}: "
                           f"{errs}")
 
-    pg = spec["paged"]
+    pg, g5 = spec["paged"], spec["paged_group5"]
+    five = (("paged_decode_gqa5", g5["gqa_kvh"], False, 0),)
     paged = {
         "default_shapes": paged_decode_errs(paged_decode_cases()),
         "smoke_shapes": paged_decode_errs(
             paged_decode_cases(pg["gqa_kvh"], pg["window"]),
             d_model=pg["d_model"], n_heads=pg["n_heads"]),
+        "group_of_five": paged_decode_errs(
+            five, d_model=g5["d_model"], n_heads=g5["n_heads"]),
     }
     prefill = {
         "default_shapes": paged_prefill_errs(paged_decode_cases()),
         "smoke_shapes": paged_prefill_errs(
             paged_decode_cases(pg["gqa_kvh"], pg["window"]),
             d_model=pg["d_model"], n_heads=pg["n_heads"]),
+        "group_of_five": paged_prefill_errs(
+            five, d_model=g5["d_model"], n_heads=g5["n_heads"]),
     }
     for what, checks in (("decode", paged), ("prefill", prefill)):
         for where, entries in checks.items():

@@ -69,7 +69,9 @@ def parse_args(argv=None):
                    help="a JSON object of TransformerConfig's own fields "
                         "that the flags above do not reach (n_kv_heads, "
                         "attn_head_dim, embed_scale, layers: one [window, "
-                        "rotary] pair a layer; any other field too), "
+                        "rotary] pair a layer, a state-space mixer's "
+                        "ssm_heads / ssm_head_dim / ssm_state / ssm_groups "
+                        "/ ssm_conv; any other field too), "
                         "laid over them, and `block_parts`: what each "
                         "block holds beyond the plain one (qk_norm, "
                         "attn_gate, post_norm)")

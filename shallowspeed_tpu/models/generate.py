@@ -59,6 +59,9 @@ def _block_decode(p, x, cfg: T.TransformerConfig, cache_blk, pos,
     cache_blk = _cache_write(cache_blk, k, v, pos)
     a = _cached_attention(q, cache_blk, pos, cfg, window).reshape(b, 1, -1)
     x = T.attn_residual(p, x, a, h, cfg)
+    if "mixer" in p:    # the same normed input, its own carried state
+        y, left = T.mixer(p["mixer"], h, cfg, cache_blk)
+        x, cache_blk = x + y, {**cache_blk, **left}
     h = T._norm(p["ln2"], x, cfg)
     x, _aux = T._ffn(p, x, cfg, h)
     return x, cache_blk
@@ -110,10 +113,13 @@ def prefill(params, tokens, cfg: T.TransformerConfig, cache,
     pos = jnp.arange(tp)
     for i, blk in enumerate(params["blocks"]):
         window, rotary = cfg.layer_specs[i]
-        x, _aux, (k, v) = T._block(
+        # a block with a mixer also leaves the mixer's state after the
+        # prompt's last TRUE token (the bucket's padding changes none)
+        x, _aux, (k, v, *left) = T._block(
             blk, x, cfg, partial(fn, causal=True, window=window),
-            with_kv=True, pos=pos, rotary=rotary)
-        cache[i] = _cache_write(cache[i], k, v, 0)
+            with_kv=True, pos=pos, rotary=rotary,
+            n_tok=None if last_idx is None else last_idx + 1)
+        cache[i] = {**_cache_write(cache[i], k, v, 0), **dict(*left)}
     x = T._norm(params["ln_f"], x, cfg)
     if last_idx is None:
         x_last = x[:, tp - 1]

@@ -61,15 +61,30 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
             f"{KV_QUANT_MODES} ('' = cache in the compute dtype)")
     dt = cfg.compute_dtype or cfg.dtype
     shape = (batch, cfg.kv_heads, cache_len or cfg.max_seq, cfg.head_dim)
+    # a block with a state-space mixer also carries the mixer's state,
+    # of a fixed size whatever `cache_len` is
+    state = mixer_state(cfg, batch) if cfg.mixer else {}
     if kv_quant:
         sshape = shape[:3] + (1,)
         return [{"k": jnp.zeros(shape, jnp.int8),
                  "k_s": jnp.zeros(sshape, jnp.float32),
                  "v": jnp.zeros(shape, jnp.int8),
-                 "v_s": jnp.zeros(sshape, jnp.float32)}
+                 "v_s": jnp.zeros(sshape, jnp.float32), **state}
                 for _ in range(cfg.n_layers)]
-    return [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    return [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt), **state}
             for _ in range(cfg.n_layers)]
+
+
+STATE_LEAVES = ("conv", "ssm")
+
+
+def mixer_state(cfg: T.TransformerConfig, rows: int) -> dict:
+    """The zero state of `rows` sequences in one mixer layer: `conv` the
+    convolution's last inputs in the compute dtype, `ssm` the heads'
+    matrices in float32 (`ops/ssm.py:zero_state`)."""
+    from shallowspeed_tpu.ops.ssm import zero_state
+
+    return zero_state(cfg, rows, cfg.compute_dtype or cfg.dtype)
 
 
 def quantize_kv(x):

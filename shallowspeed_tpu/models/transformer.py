@@ -30,6 +30,7 @@ from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 from shallowspeed_tpu.ops.attention import attention
 from shallowspeed_tpu.ops.latent_attention import latent_attention
+from shallowspeed_tpu.ops import ssm
 from shallowspeed_tpu.ops.moe import moe_ffn, routed_experts_ffn
 
 
@@ -218,6 +219,20 @@ class TransformerConfig:
     # The token embedding is multiplied by this on its way in (a muP
     # model's sqrt(d_model)); 1 = as gathered.
     embed_scale: float = 1.0
+    # A state-space mixer BESIDE the attention heads of every block
+    # (`ops/ssm.py`; 0 heads = none): both read the block's one normed
+    # input and their outputs are summed onto the residual stream. Its
+    # inner stream is `ssm_heads` x `ssm_head_dim` wide, each head keeps
+    # a (`ssm_head_dim`, `ssm_state`) float32 matrix from token to token,
+    # the heads of one of `ssm_groups` groups share their B and C, and
+    # a causal depthwise convolution of width `ssm_conv` runs ahead of
+    # the recurrence. Serving only: the training engines refuse it
+    # (`trainable`), no backward of the scan is measured.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
 
     def __post_init__(self):
         assert self.norm in ("layernorm", "rmsnorm"), self.norm
@@ -252,6 +267,14 @@ class TransformerConfig:
             assert all(w >= 0 for w, _ in self.layers) and len(
                 {w for w, _ in self.layers if w}) <= 1, (
                     f"windowed layers share one window size: {self.layers}")
+        if self.ssm_heads:
+            assert self.ssm_head_dim > 0 and self.ssm_state > 0 \
+                and self.ssm_conv > 1 \
+                and self.ssm_heads % self.ssm_groups == 0 \
+                and not self.latent, (
+                    "a mixer's head size and state size are set, its "
+                    "groups divide its heads, and its block's attention "
+                    "is K/V heads")
         if self.n_routed_experts:
             assert not self.n_experts, (
                 "n_routed_experts (dropless) and n_experts (capacity "
@@ -296,6 +319,21 @@ class TransformerConfig:
         return self.n_routed_experts > 0 and i >= self.first_dense_layers
 
     @property
+    def mixer(self) -> bool:
+        """Whether every block holds a state-space mixer."""
+        return self.ssm_heads > 0
+
+    @property
+    def trainable(self) -> "TransformerConfig":
+        """This config, for code that builds a train step (`loss`, and
+        the engines that build theirs without it, where they take their
+        config): refuses a model with no measured backward pass."""
+        assert not self.mixer, (
+            "a model with a state-space mixer is served only: the "
+            "training engines have no backward pass of its scan")
+        return self
+
+    @property
     def layer_specs(self) -> tuple:
         """(window, rotary) of every layer: THE per-layer spec."""
         return tuple((int(w), bool(r)) for w, r in self.layers) \
@@ -317,6 +355,29 @@ def _dense_init(rng, in_d, out_d, dtype):
 
 
 BLOCK_PARTS = ("qk_norm", "attn_gate", "post_norm")
+
+
+def _mixer_init(rng, cfg: "TransformerConfig", dtype):
+    """A block's mixer as Mamba-2 initialises it: `A_log` the log of
+    U(1, 16), `dt_bias` the inverse softplus of step sizes log-uniform
+    in [1e-3, 1e-1], `d_skip` 1, the convolution U(-1/2, 1/2) a tap
+    (1 / sqrt(width)) with no bias to start from; these set how fast a
+    state forgets, and zeros or ones would make the recurrence trivial.
+    The three per-head vectors stay float32 whatever `dtype` is."""
+    h, k = cfg.ssm_heads, cfg.ssm_conv
+    step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), h))
+    bound = 1.0 / np.sqrt(k)
+    return {
+        "in_proj": _dense_init(rng, cfg.d_model, ssm.proj_dim(cfg), dtype),
+        "conv_w": rng.uniform(-bound, bound,
+                              (k, ssm.conv_dim(cfg))).astype(dtype),
+        "conv_b": np.zeros((ssm.conv_dim(cfg),), dtype),
+        "A_log": np.log(rng.uniform(1.0, 16.0, h)).astype(np.float32),
+        "dt_bias": (step + np.log(-np.expm1(-step))).astype(np.float32),
+        "d_skip": np.ones((h,), np.float32),
+        "mixer_norm": {"g": np.ones((ssm.d_ssm(cfg),), dtype)},
+        "out_proj": _dense_init(rng, ssm.d_ssm(cfg), cfg.d_model, dtype),
+    }
 
 
 def init(cfg: TransformerConfig, seed: int = 0, parts=()):
@@ -395,6 +456,8 @@ def init(cfg: TransformerConfig, seed: int = 0, parts=()):
         if "post_norm" in parts:
             for name in ("ln1_post", "ln2_post"):
                 blk[name] = {"g": np.ones((d,), dt), "b": np.zeros((d,), dt)}
+        if cfg.mixer:
+            blk["mixer"] = _mixer_init(rng, cfg, dt)
         blocks.append(blk)
     out = {
         "tok_emb": rng.normal(0.0, 0.02, (cfg.vocab, d)).astype(dt),
@@ -412,8 +475,10 @@ def init(cfg: TransformerConfig, seed: int = 0, parts=()):
 # / `ln2_post` a sub-layer's output's) and the routed layer's selection
 # bias, which only ever meets float32 scores
 _NORM_KEYS = {"ln1", "ln2", "ln_f", "kv_norm", "q_norm", "k_norm",
-              "ln1_post", "ln2_post"}
-_MASTER_KEYS = _NORM_KEYS | {"route_bias"}
+              "ln1_post", "ln2_post", "mixer_norm"}
+# ... and a mixer's per-head decay, step bias and skip, which only meet
+# the float32 recurrence
+_MASTER_KEYS = _NORM_KEYS | {"route_bias", "A_log", "dt_bias", "d_skip"}
 
 # Quantized weight-storage leaves (see `quantize_weights`): "Wq" is the
 # int8/fp8 value tensor, "Ws" the per-out-channel f32 scales. Both stay
@@ -758,6 +823,39 @@ def ffn_residual(p, x, y, cfg: TransformerConfig, key=None):
     return x + _dropout(y, cfg.dropout, key)
 
 
+def mixer(m, h, cfg: TransformerConfig, state=None, n_tok=None):
+    """The state-space mixer `m` (a block's `mixer` params) on the
+    block's norm output h (B, T, d): (its output (B, T, d), the state
+    it leaves: {"conv": (B, K - 1, C), "ssm": (B, H, P, N) float32}).
+    `state` is what the sequences' earlier tokens left (None: they start
+    here, from zeros), `n_tok` the traced count of true rows where the
+    tail of T is padding, which then changes neither output nor state.
+    One token a row (T = 1, the decode tick) advances the state
+    elementwise; a chunk goes through the blocked scan (`ops/ssm.py`).
+    One function for the forward pass without a cache, `generate()`
+    and the serving engine's two programs."""
+    b, t, _ = h.shape
+    if state is None:
+        state = ssm.zero_state(cfg, b, h.dtype)
+    z, xbc, dt = ssm.split_projection(
+        _dense(m["in_proj"], h, cfg.fp8_dense), cfg)
+    xbc, tail = ssm.causal_conv(m["conv_w"], m["conv_b"], xbc,
+                                state["conv"], n_tok)
+    x, bm, cm = ssm.split_conv(xbc, cfg)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + m["dt_bias"])
+    a = -jnp.exp(m["A_log"].astype(jnp.float32))
+    if t == 1:
+        y, s = ssm.ssm_step(x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
+                            m["d_skip"], state["ssm"])
+        y = y[:, None]
+    else:
+        y, s = ssm.ssm_scan(x, dt, a, bm, cm, m["d_skip"], state["ssm"],
+                            n_tok)
+    y = ssm.gated_norm(m["mixer_norm"]["g"], y.reshape(b, t, -1), z,
+                       cfg.ssm_groups)
+    return _dense(m["out_proj"], y, cfg.fp8_dense), {"conv": tail, "ssm": s}
+
+
 def latent_qkv(p, h, cfg: TransformerConfig, rotate):
     """A latent block's projections of the norm output h (B, T, d):
     (q_nope (B,T,H,dn), q_rope (B,T,H,dr), c (B,T,r), k_rope (B,T,dr)).
@@ -851,7 +949,7 @@ def _ffn(p, x, cfg: TransformerConfig, h, key=None):
 
 
 def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
-           pos=None, key=None, rotary=None):
+           pos=None, key=None, rotary=None, n_tok=None):
     """One pre-LN block; returns (x, aux) where aux is the MoE
     load-balancing loss (0.0 for dense blocks). With `with_kv` also
     returns this block's (k, v) — the decode prefill
@@ -860,7 +958,10 @@ def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
     outputs there. `pos` (global positions) is required when cfg.rope.
     `key` (training only) seeds this block's attention/FFN dropout.
     `rotary` is this layer's half of its spec (`cfg.layer_specs`; None
-    = the model's `cfg.rope`), its window the caller's `attn_fn`."""
+    = the model's `cfg.rope`), its window the caller's `attn_fn`.
+    A block that holds a `mixer` adds its output beside the attention's
+    (both read the one normed input), from a zero state; `with_kv` then
+    gives (k, v, the mixer's state after `n_tok` true rows)."""
     b, t, d = x.shape
     if rotary is None:
         rotary = cfg.rope
@@ -917,6 +1018,10 @@ def _block(p, x, cfg: TransformerConfig, attn_fn, with_kv: bool = False,
     # (no-op outside a policied jax.checkpoint)
     a = _checkpoint_name(a, "attn_out")
     x = attn_residual(p, x, a, h, cfg, k_attn)
+    if "mixer" in p:
+        y, left = mixer(p["mixer"], h, cfg, None, n_tok)
+        x = x + y
+        kv_cacheable += (left,)
     h = _norm(p["ln2"], x, cfg)
     x, aux = _ffn(p, x, cfg, h, k_ffn)
     if with_kv:
@@ -1013,6 +1118,7 @@ def loss(params, tokens, targets, cfg: TransformerConfig,
     the caller averages across shards (`lax.pmean`) — exact because all
     blocks have equal size.
     """
+    cfg = cfg.trainable
     if cfg.xent_chunk > 0:
         hid, (aux, z) = forward_with_aux(params, tokens, cfg, attn_fn,
                                          pos_offset, dropout_key,

@@ -83,7 +83,7 @@ class ContextParallelEngine:
         self.last_health = None
         self.overlap = overlap  # parallel.overlap.OverlapConfig | None
         self.accum = accum
-        self.cfg = cfg
+        self.cfg = cfg = cfg.trainable
         self.mesh = mesh
         self.dp, self.sp = mesh.devices.shape
         self.optimizer = optimizer

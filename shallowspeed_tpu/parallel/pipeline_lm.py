@@ -200,7 +200,7 @@ class PipelineLMEngine:
                         "ulysses-flash"), attn
         self.schedule = schedule
         self.attn = attn
-        self.cfg = cfg
+        self.cfg = cfg = cfg.trainable
         self.mesh = mesh
         self.dp, self.pp = mesh.devices.shape[:2]
         self.has_tp = mesh.axis_names[2:] == ("tp",)

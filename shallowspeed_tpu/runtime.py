@@ -12,6 +12,7 @@ and print `device_stamp()` at start, so every log says what it ran on.
 from __future__ import annotations
 
 import os
+import re
 from pathlib import Path
 
 # The one in-checkout cache location (git-ignored). Fixed, because a
@@ -46,6 +47,15 @@ def enable_compile_cache() -> str:
     # is stored by one run and not by the next, and a warm run keeps
     # adding entries for shapes that did not change
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # a program's cache key must not follow the checkout's path. JAX
+    # strips source locations from a module before it hashes it, but a
+    # Pallas kernel rides in its custom call as serialized MLIR WITH its
+    # locations, file names and all, which that pass does not reach: so
+    # every program with a kernel in it compiled anew from each new
+    # checkout path (PERF.md section 6, PR 36). File names lose the
+    # checkout's root before they become locations.
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(str(CACHE_DIR.parent) + os.sep))
     return compile_cache_dir()
 
 

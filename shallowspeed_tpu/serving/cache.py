@@ -50,18 +50,35 @@ with `base`, the position of its first entry's first token. Relative to
 position in its values, a mask compares differences), so the kernel and
 the writes are the same for both kinds. A model with one kind of layer
 has one group and one table, as before there were groups.
+
+A layer with a state-space mixer beside its attention heads
+(`cfg.mixer`) holds a SECOND kind of state next to its K/V pool, in the
+same per-layer dict: the leaves `STATE_LEAVES`, one row a SLOT and not a
+table of blocks, of a fixed size whatever the context is (`conv`, the
+convolution's last inputs, and `ssm`, the heads' float32 matrices). A
+request keeps its slot from admission to its finish or eviction, so the
+tick's row index is the slab's row. Every token rewrites its row whole:
+the tick passes over the whole slab once, elementwise, a row that does
+not decode keeping what it held (so the slabs need no scratch row, as
+block 0 is the pools': nothing is steered anywhere); the chunk takes
+its request's row and puts it back, indexed on the leading dimension
+alone. Both run in place on the donated buffers like the pools' writes. The block helpers
+here (`pool_block_size`, the kernels' address checks, copy-on-write)
+are for the K/V leaves: `kv_leaves` is a layer's dict without its slabs.
 """
 
 from __future__ import annotations
 
 import hashlib
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import jax.numpy as jnp
 import numpy as np
 
 from shallowspeed_tpu.models import transformer as T
-from shallowspeed_tpu.models.kv_cache import KV_QUANT_MODES, quantize_kv
+from shallowspeed_tpu.models.kv_cache import (KV_QUANT_MODES, STATE_LEAVES,
+                                              mixer_state, quantize_kv)
+from shallowspeed_tpu.ops.ssm import state_shapes
 
 SCRATCH_BLOCK = 0
 
@@ -102,9 +119,30 @@ LATENT = "ckr"      # a latent layer's one leaf: [c | k_rope | 0] a token
 LANES = 128
 
 
+def kv_leaves(pool_blk) -> dict:
+    """A layer's block pool alone: its dict without a mixer's slabs."""
+    return {name: leaf for name, leaf in pool_blk.items()
+            if name not in STATE_LEAVES}
+
+
+def state_leaves(pool_blk) -> dict:
+    """A layer's per-slot slabs ({} where the layer has no mixer)."""
+    return {name: pool_blk[name] for name in STATE_LEAVES
+            if name in pool_blk}
+
+
+def state_row_bytes(cfg: T.TransformerConfig) -> int:
+    """Bytes ONE slot's state takes in ONE mixer layer (0: no mixer)."""
+    if not cfg.mixer:
+        return 0
+    shapes = state_shapes(cfg, 1)       # no array is made to count it
+    return int(np.prod(shapes["ssm"])) * 4 + int(np.prod(shapes["conv"])) \
+        * np.dtype(cfg.compute_dtype or cfg.dtype).itemsize
+
+
 def pool_block_size(pool_blk) -> int:
     """Positions a block holds, whatever kind of pool it is."""
-    return next(iter(pool_blk.values())).shape[2]
+    return next(iter(kv_leaves(pool_blk).values())).shape[2]
 
 
 @dataclass(frozen=True)
@@ -166,7 +204,7 @@ def group_blocks(cfg: T.TransformerConfig, n_blocks) -> dict:
 
 
 def init_block_pool(cfg: T.TransformerConfig, n_blocks,
-                    block_size: int, kv_quant: str = ""):
+                    block_size: int, kv_quant: str = "", slots: int = 0):
     """Per-layer paged pools, zero-filled, each of its layer's kind and
     of its group's size (`n_blocks`: an int, or {group name: blocks}).
 
@@ -185,7 +223,15 @@ def init_block_pool(cfg: T.TransformerConfig, n_blocks,
     28: two pool-sized copies a layer at 576, none at 512 or 640; the
     tiled layout pads 576 to 640 lanes either way).
     Every kind shares the block ids: one allocator, one table a
-    request."""
+    request. A model with a mixer (`cfg.mixer`) also gets its slabs in
+    every layer's dict, `slots` rows."""
+    if cfg.mixer:
+        if slots < 1:
+            raise ValueError("a model with a state-space mixer keeps one "
+                             "row of state a slot: pass slots >= 1")
+        pools = init_block_pool(replace(cfg, ssm_heads=0), n_blocks,
+                                block_size, kv_quant)
+        return [{**pool, **mixer_state(cfg, slots)} for pool in pools]
     if kv_quant not in KV_QUANT_MODES:
         raise ValueError(
             f"unsupported kv_quant={kv_quant!r}; expected one of "
@@ -605,8 +651,9 @@ def param_read_bytes(params, cfg: T.TransformerConfig) -> int:
 def paged_read_bytes_per_tick(params, cfg: T.TransformerConfig,
                               blocks_touched, block_size: int,
                               n_rows: int, kv_quant: str = "",
-                              p_bytes: int | None = None) -> int:
-    """HBM READ bytes one decode tick usefully moves: every param leaf
+                              p_bytes: int | None = None,
+                              state_rows: int = 0) -> int:
+    """HBM bytes one decode tick usefully moves: every param leaf
     (at its ACTUAL post-cast dtype — int8/fp8 weights and f32 scales
     included, see `param_read_bytes`) + the K/V bytes of the live
     blocks the tick's active requests attend over (+ int8 scale
@@ -616,6 +663,8 @@ def paged_read_bytes_per_tick(params, cfg: T.TransformerConfig,
     (`layer_groups(cfg)`'s order), where a window group's rows count
     the blocks their windows reach and no more. Pass a precomputed `p_bytes`
     (`param_read_bytes`) on hot paths — the param term never changes.
+    `state_rows`: the rows whose mixer state the tick advances, each
+    read AND written whole in every layer (`state_row_bytes`).
 
     This is the byte model behind the fast-decode gates: the
     int8-weight tick must price at <= 0.55x its bf16 baseline (pinned
@@ -637,4 +686,5 @@ def paged_read_bytes_per_tick(params, cfg: T.TransformerConfig,
         blocks_touched = [blocks_touched] * len(groups)
     layer_blocks = sum(len(g.layers) * int(b)
                        for g, b in zip(groups, blocks_touched, strict=True))
-    return p_bytes + layer_blocks * per_block + n_rows * 4
+    return p_bytes + layer_blocks * per_block + n_rows * 4 \
+        + 2 * int(state_rows) * cfg.n_layers * state_row_bytes(cfg)

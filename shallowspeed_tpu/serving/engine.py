@@ -92,6 +92,20 @@ head-of-line behind the longest. This engine serves a STREAM:
   while the device runs N+1. The host's view trails the device by one
   tick; the tokens are the same.
 
+- **A second kind of state** (`cfg.mixer`). A block with a state-space
+  mixer beside its attention heads keeps, next to its K/V pool, one
+  row of state a SLOT (`serving/cache.py`: the slabs), rewritten whole
+  at every token. The tick advances the rows that decode and writes
+  every other row back as it was: a tick runs between two chunks of
+  one prompt, and one tick more runs on a slot whose request has just
+  finished, so a row that is not decoding must leave its slab alone.
+  A prefill chunk starts from zeros at position 0 and from its slot's
+  row otherwise, and leaves the state after its last true token. An
+  evicted request re-prefills prompt + generated, which rebuilds its
+  state. A prefix-cache hit would need a snapshot of the state at the
+  matched block and a rejected draft a roll-back: `prefix_cache` and
+  `spec_k` are refused for such a model.
+
 Stream parity: sampling uses the SAME per-request key schedule as
 `generate()` — token i of a request with sampling seed s draws from
 `fold_in(PRNGKey(s), i)` — and the paged attention computes what
@@ -131,10 +145,12 @@ from shallowspeed_tpu.serving.cache import (LATENT, SCRATCH_BLOCK,
                                             PrefixIndex, blocks_for,
                                             chunk_hashes, gather_table,
                                             group_blocks, group_of_layer,
-                                            init_block_pool, layer_groups,
+                                            init_block_pool, kv_leaves,
+                                            layer_groups,
                                             paged_read_bytes_per_tick,
                                             param_read_bytes,
-                                            pool_block_size, write_chunk,
+                                            pool_block_size, state_leaves,
+                                            state_row_bytes, write_chunk,
                                             write_rows)
 
 
@@ -299,6 +315,11 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
     copies per leaf per run for it
     (tests/test_tpu_compile.py holds the compiled programs to this).
 
+    A layer with a mixer (`cfg.mixer`) also carries its slabs in its
+    pool's dict, one row a slot: the tick's row IS the slot, every row
+    is advanced, and a row that is not live keeps what it held (one
+    elementwise pass over the donated slab, in place).
+
     Draft rows (speculative decoding) are ordinary rows at consecutive
     positions of a shared table: the pool write happens before the
     read, so row j's attention sees rows i < j of the same tick — the
@@ -322,11 +343,12 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
     # heads that are not whole lanes wide (no published size; toy
     # configurations on the chip) are beyond the kernel's DMA when
     # compiled and keep the gathered read
-    paged = paged_decode_addresses(pools[0])
+    paged = paged_decode_addresses(kv_leaves(pools[0]))
     new_pools, counts = [], []
     for p, pool, (window, rotary), g in zip(
             params["blocks"], pools, cfg.layer_specs, group_of_layer(cfg)):
         bt_g, at, blk = tables[g]
+        pool, slabs = kv_leaves(pool), state_leaves(pool)
         h = T._norm(p["ln1"], x, cfg)
         if LATENT in pool:
             qn, qr, c, kr = T.latent_qkv(p, h, cfg, rope)
@@ -345,6 +367,19 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
                 a = masked_attention(q, gather_table(pool, bt_g),
                                      valid[:, None, None, None, :], cfg)
         x = T.attn_residual(p, x, a.reshape(s_rows, 1, -1), h, cfg)
+        if slabs:
+            # the row is the slot; a row that does not decode (an empty
+            # slot, a prompt between two chunks, a request just
+            # finished) keeps what its row held. One elementwise pass
+            # over the whole slab, which XLA:TPU fuses with the
+            # readout's reduction and runs on the donated buffer: a
+            # scatter of the new rows (to a scratch row for the others)
+            # has to be handed them in HBM first, two passes more
+            y, left = T.mixer(p["mixer"], h, cfg, slabs)
+            x = x + y
+            pool = {**pool, **{
+                n: jnp.where(live.reshape((-1,) + (1,) * (slab.ndim - 1)),
+                             left[n], slab) for n, slab in slabs.items()}}
         x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
         if n is not None:
             counts.append(n)
@@ -357,7 +392,8 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
 def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
-                   cow_dst, base=None, *, cfg: T.TransformerConfig):
+                   cow_dst, base=None, slot=None, *,
+                   cfg: T.TransformerConfig):
     """One chunk of a request's prefill: tokens (1, C) — C is the
     fixed chunk length, `n_tok` the traced true count (the tail is
     padding: never written, and masked out of every true row's read).
@@ -393,15 +429,19 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     scratch->scratch self-copy that is a no-op by construction (nothing
     reads scratch). Riding the copy inside this one jitted program (as
     data, every call) keeps `executable_counts()` flat: cache hits
-    change block-table *data*, never the compiled-program set."""
+    change block-table *data*, never the compiled-program set.
+
+    `slot` is the request's row of a mixer's slabs (None: the model has
+    none): the chunk starts from zeros where `pos0` is 0 and from that
+    row otherwise, and writes the state after its `n_tok` true rows."""
     params = T.cast_params(params, cfg.compute_dtype)
     c = tokens.shape[1]
     bs = pool_block_size(pools[0])
     quant = "k_s" in pools[0]
     group = group_of_layer(cfg)
     cow_src, cow_dst = jnp.atleast_1d(cow_src), jnp.atleast_1d(cow_dst)
-    pools = [{name: leaf.at[cow_dst[g]].set(leaf[cow_src[g]])
-              for name, leaf in pool.items()}
+    pools = [{**pool, **{name: leaf.at[cow_dst[g]].set(leaf[cow_src[g]])
+                         for name, leaf in kv_leaves(pool).items()}}
              for pool, g in zip(pools, group)]
     pos = pos0 + jnp.arange(c)
     x = G._embed(params, tokens, pos0, cfg)                  # (1, C, d)
@@ -417,6 +457,7 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     for p, pool, (window, rotary), g in zip(
             params["blocks"], pools, cfg.layer_specs, group):
         bt_g, at0 = tables[g]
+        pool, slabs = kv_leaves(pool), state_leaves(pool)
         h = T._norm(p["ln1"], x, cfg)
         if LATENT in pool:
             qn, qr, lat, kr = T.latent_qkv(p, h, cfg, rope)
@@ -438,6 +479,14 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
                     q, gather_table(pool, bt_g),
                     valid(bt_g, at0, window)[None, None, None], cfg)
         x = T.attn_residual(p, x, a.reshape(1, c, -1), h, cfg)
+        if slabs:
+            y, left = T.mixer(
+                p["mixer"], h, cfg,
+                {n: jnp.where(pos0 == 0, 0, slab[slot][None]).astype(
+                    slab.dtype) for n, slab in slabs.items()}, n_tok)
+            x = x + y
+            pool = {**pool, **{n: slab.at[slot].set(left[n][0])
+                               for n, slab in slabs.items()}}
         x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
         if n is not None:
             counts.append(n)
@@ -550,6 +599,15 @@ class ServingEngine:
             raise ValueError(
                 f"unsupported attn_impl={attn_impl!r}; expected "
                 f"'gather' or 'flash' (both name the same programs)")
+        if cfg.mixer and (prefix_cache or spec_k > 0):
+            # a hit would need a snapshot of the mixer's state at the
+            # matched block, a rejected draft a roll-back of it: neither
+            # exists (ROADMAP R4)
+            raise ValueError(
+                "a model with a state-space mixer keeps one state a slot "
+                "and no snapshot of it: prefix_cache and spec_k > 0 are "
+                f"not served for it (got prefix_cache={prefix_cache}, "
+                f"spec_k={spec_k})")
         # quantize ONCE at init (host-side, idempotent): every tick
         # then reads 1-byte weights through the fused-dequant matmul
         self.params = T.quantize_weights(params, weight_quant)
@@ -585,7 +643,11 @@ class ServingEngine:
         # allocator a group, and every request one table a group
         self.groups = layer_groups(cfg)
         sizes = group_blocks(cfg, n_blocks)
-        self.pools = init_block_pool(cfg, sizes, block_size, kv_quant)
+        self.pools = init_block_pool(cfg, sizes, block_size, kv_quant,
+                                     slots=self.max_slots)
+        # slab bytes one decoding row's state is, all layers, read plus
+        # written: what a tick moves for it (0: the model has no mixer)
+        self._state_bytes = 2 * cfg.n_layers * state_row_bytes(cfg)
         # prefix caching (round 19): a content-addressed index over
         # block-aligned prompt chunks. `_admit` probes it, finished
         # requests donate their sealed prefix blocks (refcount-zero
@@ -663,6 +725,12 @@ class ServingEngine:
                          # blocks handed back to a window group's free
                          # list because they left their request's window
                          "released": 0}
+        if cfg.mixer:
+            # rows whose mixer state the decode ticks advanced, the
+            # slab bytes they read plus wrote for it, and the prefill
+            # chunks that started from a carried state
+            self.counters.update(state_rows=0, state_bytes=0,
+                                 state_carried=0)
         for g in self.groups:
             # `<group>_blocks`: blocks the ticks' live rows held there
             self.counters[f"blocks_read_{g.name}"] = 0
@@ -714,6 +782,7 @@ class ServingEngine:
         self._win_tokens = 0            # tokens since the last log line
         self._win_t = clock()
         self._last_touched = [0] * len(self.groups)
+        self._last_state_rows = 0       # rows the last tick's mixers advanced
         self._win_drafted = 0           # spec-decode window tallies
         self._win_accepted = 0
         self._win_prefix_lookups = 0    # prefix-cache window tallies
@@ -1007,6 +1076,12 @@ class ServingEngine:
             if out is None or room < out["headroom_blocks"]:
                 out = {"live_blocks": al.n_live, "blocks_needed": needed,
                        "headroom_blocks": room}
+        if self._state_bytes:
+            # the second kind of state: a slab row a slot, held from
+            # admission on whatever the context grows to
+            held = sum(1 for r in self.slots if r is not None)
+            out["state_rows"] = held
+            out["state_bytes"] = held * self._state_bytes // 2
         return out
 
     def _peak_blocks(self, g: int, n_tokens: int) -> int:
@@ -1228,7 +1303,8 @@ class ServingEngine:
         bs = self.block_size
         at0 = [req.written - b * bs for b in req.base]
         return [(at + n_tok - 1) // bs + 1 - g.first_live_block(at, bs)
-                if paged_prefill_addresses(self.pools[g.layers[0]], bt.size)
+                if paged_prefill_addresses(kv_leaves(self.pools[g.layers[0]]),
+                                           bt.size)
                 else bt.size
                 for g, at, bt in zip(self.groups, at0, bts)]
 
@@ -1277,11 +1353,17 @@ class ServingEngine:
         cow = np.asarray(req.cow if req.cow is not None
                          else [(SCRATCH_BLOCK, SCRATCH_BLOCK)]
                          * len(self.groups), np.int32)
+        if self._state_bytes:
+            carried = int(req.written > 0)
+            sp.set(state_carried=carried)
+            self.counters["state_carried"] += carried
         with tr.span("prefill.dispatch"):
             logits, self.pools, counts = _prefill_chunk(
                 self.params, self.pools, tokens, np.int32(req.written),
                 np.int32(n_tok), bts, cow[:, 0], cow[:, 1],
-                None if base is None else base[:, 0], cfg=self.cfg)
+                None if base is None else base[:, 0],
+                np.int32(req.slot) if self._state_bytes else None,
+                cfg=self.cfg)
         walked = self._chunk_walked(req, n_tok, bts)
         read = {"blocks_read": sum(walked),
                 "blocks_table": sum(bt.size for bt in bts)}
@@ -1367,6 +1449,9 @@ class ServingEngine:
                     read[f"blocks_read_{g.name}"] = walked[i]
                     read[f"{g.name}_blocks"] = sum(len(r.tables[i])
                                                    for r in actives)
+                if self._state_bytes:
+                    read["state_rows"] = len(actives)
+                    read["state_bytes"] = len(actives) * self._state_bytes
                 sp.set(n_active=len(actives), width=bts[0].shape[1],
                        released=released, **read)
                 for name, value in read.items():
@@ -1505,6 +1590,7 @@ class ServingEngine:
         self._last_touched = [
             sum(blocks_for(n, bs) - g.first_live_block(n - 1, bs)
                 for n in ends) for g in self.groups]
+        self._last_state_rows = len(actives) if self._state_bytes else 0
         emitted = 0
         for r in actives:
             # speculation tallies accrue BEFORE the appends: an
@@ -1780,7 +1866,8 @@ class ServingEngine:
         dt = max(now - self._win_t, 1e-9)
         bpt = paged_read_bytes_per_tick(
             self.params, self.cfg, self._last_touched, self.block_size,
-            self.max_slots, self.kv_quant, p_bytes=self._p_bytes)
+            self.max_slots, self.kv_quant, p_bytes=self._p_bytes,
+            state_rows=self._last_state_rows)
         ticks_per_sec = self.log_every / dt
         extra = {}
         if self.spec_k > 0:  # schema v9: windowed speculation telemetry

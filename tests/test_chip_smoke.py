@@ -176,6 +176,7 @@ def test_compile_cache_helper_respects_the_environment(monkeypatch):
 
     before = jax.config.jax_compilation_cache_dir
     min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    regex = jax.config.jax_hlo_source_file_canonicalization_regex
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
         assert runtime.enable_compile_cache() == "/somewhere/else"
@@ -187,7 +188,14 @@ def test_compile_cache_helper_respects_the_environment(monkeypatch):
         assert runtime.enable_compile_cache() == fixed
         assert jax.config.jax_compilation_cache_dir == fixed
         assert runtime.compile_cache_dir() == fixed
+        # a source location names a file from the checkout's root down,
+        # so a kernel's serialized locations do not carry the
+        # checkout's path into the cache key
+        import re
+        assert re.sub(jax.config.jax_hlo_source_file_canonicalization_regex,
+                      "", runtime.__file__) == "shallowspeed_tpu/runtime.py"
     finally:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", regex)
         jax.config.update("jax_compilation_cache_dir", before)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           min_secs)

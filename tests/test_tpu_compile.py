@@ -171,11 +171,13 @@ def test_serving_programs_write_the_pool_in_place(one_chip, program,
         program, *_compiled_text(program, one_chip, heads))
 
 
-def _assert_written_in_place(program, text, n_leaves, leaf):
+def _assert_written_in_place(program, text, n_leaves, leaf, n_donated=None):
+    """`n_leaves` pool leaves like `leaf`; `n_donated` where the program
+    donates more than those (a mixer's slabs)."""
     pool_elems = leaf.size
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp",
                         text, re.S).group(1)
-    assert aliased.count("alias)") == n_leaves, aliased
+    assert aliased.count("alias)") == (n_donated or n_leaves), aliased
     comps = _computations(text)
     offenders, in_place = [], 0
     for line in comps["ENTRY"]:
@@ -317,3 +319,94 @@ def test_the_other_pools_compile_for_the_chip(one_chip, heads, kv_quant,
     text, _, _ = _compiled_text("decode_tick", one_chip, heads, kv_quant)
     assert text.count('custom_call_target="tpu_custom_call"') \
         == (2 if kernel else 0)
+
+
+# ------------------------------------------------- the mixer's slabs
+#
+# `falcon-h1-34b-instruct.long-gen`'s shapes: 48 slots, 15,361 blocks,
+# 20 query heads on 4 K/V heads of 128, the mixer at its published
+# sizes (32 heads x 128 x 256 float32 a slot: a slab of 201 MB a layer);
+# the FFN and the vocabulary cut so the compile stays seconds.
+_MIXER = dict(d_model=5120, n_heads=20, n_kv_heads=4, attn_head_dim=128,
+              embed_scale=5.656854, rope_theta=1e11, ssm_heads=32,
+              ssm_head_dim=128, ssm_state=256, ssm_groups=2, ssm_conv=4)
+_MIXER_SLOTS, _MIXER_BLOCKS = 48, 15361
+
+
+def _mixer_program(program, one_chip, width):
+    cfg = T.TransformerConfig(
+        vocab=512, d_ff=512, n_layers=2, max_seq=8192, rope=True,
+        norm="rmsnorm", ffn="swiglu", dtype=jnp.bfloat16,
+        compute_dtype=jnp.bfloat16, **_MIXER)
+
+    def spec(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = spec(jax.eval_shape(lambda: T.cast_params(
+        T.init(cfg, seed=0), jnp.bfloat16)))
+    pools = spec(jax.eval_shape(lambda: init_block_pool(
+        cfg, _MIXER_BLOCKS, BLOCK, slots=_MIXER_SLOTS)))
+    i32, f32, s = jnp.int32, jnp.float32, _MIXER_SLOTS
+    if program == "decode_tick":
+        traced = _decode_tick.trace(
+            params, pools, arr(i32, s), arr(i32, s), arr(i32, s, width),
+            arr(f32, s), arr(i32, s), arr(i32, s), arr(i32, s),
+            arr(jnp.bool_, s), None, cfg=cfg, top_k=0, top_p=0.0)
+    else:
+        traced = _prefill_chunk.trace(
+            params, pools, arr(i32, 1, 512), arr(i32), arr(i32),
+            arr(i32, 1, width), arr(i32), arr(i32), None, arr(i32), cfg=cfg)
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    return compiled, pools
+
+
+@pytest.mark.parametrize("program,width,kernels", [
+    ("decode_tick", 256, 2),
+    ("prefill_chunk", 64, 0),      # prompts of 1,024 at most: gathered
+    ("prefill_chunk", 128, 2),     # a longer prompt's table: the kernel
+])
+def test_the_mixers_slabs_are_updated_in_place(one_chip, program, width,
+                                               kernels):
+    """PR 27's gate extended to the second kind of state: at the cell's
+    shapes every donated leaf, slabs included, is aliased to its output;
+    the ONLY instructions whose result is as large as a slab are one
+    fusion a layer that takes the donated slab itself (the tick's one
+    elementwise pass, fused with the readout's reduction: its new state
+    never lies in HBM beside the old; the chunk's dynamic-update-slice of
+    its slot's row), nothing as large as a slab is a temporary, and the
+    pools are still written in place, through the kernels (20 query heads
+    on 4 K/V heads: a group of 5)."""
+    compiled, pools = _mixer_program(program, one_chip, width)
+    text = compiled.as_text()
+    slab, conv = pools[0]["ssm"], pools[0]["conv"]
+    assert slab.shape == (48, 32, 128, 256) and slab.dtype == jnp.float32
+    assert conv.shape == (48, 3, 5120)
+    shape = "f32[" + ",".join(map(str, slab.shape)) + "]"
+    comps = _computations(text)
+    writes = []
+    for line in comps["ENTRY"]:
+        # `name = type op(...)`, the type one array or a tuple of them
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if m and shape in m.group(1) and m.group(2) not in _SKIP_OPS:
+            writes.append(line.strip())
+    assert len(writes) == 2, "\n".join(w[:200] for w in writes)
+    for w in writes:
+        assert " fusion(" in w and "pools_" in w and "ssm" in w, w[:300]
+        if program == "prefill_chunk":
+            root = next(l for l in comps[re.search(
+                r"calls=%?([\w.\-]+)", w).group(1)] if "ROOT" in l)
+            assert " dynamic-update-slice(" in root, root[:200]
+    # no copy of a slab in any computation, and no slab-sized scratch
+    assert not [l for l in text.splitlines()
+                if re.search(r" = " + re.escape(shape) + r"\S* copy", l)]
+    assert compiled.memory_analysis().temp_size_in_bytes < slab.size * 4
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    kv = pools[0]["k"]
+    _assert_written_in_place(
+        program, text, 4, kv,
+        n_donated=len(jax.tree_util.tree_leaves(pools)))
