@@ -5,7 +5,9 @@ The serving counterpart of `train_lm.py` — builds a transformer LM
 checkpoint via `--ckpt`), then serves a stream of requests through
 `shallowspeed_tpu.serving.ServingEngine`: requests join and leave the
 running decode batch between ticks (no recompiles after warmup), long
-prompts prefill in chunks interleaved with decode ticks, and every
+prompts prefill in chunks, one a step, with that step's decode tick
+riding in the chunk's program (one program a step; a request's first
+token reaches the host one step after its prompt's last chunk), and every
 completion stamps a schema-v6 `"request"` SLO record (ttft/tpot/queue
 depth/preemptions) into the metrics JSONL that
 `python -m shallowspeed_tpu.telemetry --goodput` reduces to p50/p95.

@@ -1100,6 +1100,15 @@ def _scale_table(plane, bt, chunk):
     return jnp.pad(g, ((0, 0),) * 4 + ((0, -chunk * bs % _LANES),))
 
 
+# The widest block table `paged_flash_decode` takes, rows x columns x 4
+# bytes. The table is a scalar-prefetch operand and lies whole in the
+# chip's scalar memory: 1 MiB on a v5e, which a table of exactly that
+# overflows by the kernel's other scalars (compiled for the described
+# v5e, PR 37: 64 x 2048 goes, 64 x 4096 does not). Widths double, so
+# half of it is the widest that goes.
+PAGED_TABLE_BYTES = 512 * 1024
+
+
 def paged_decode_addresses(pool_blk) -> bool:
     """Whether `paged_flash_decode` can be compiled for this pool.
     Mosaic slices no HBM operand whose minor dimension is not whole

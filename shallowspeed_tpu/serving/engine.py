@@ -14,11 +14,16 @@ head-of-line behind the longest. This engine serves a STREAM:
   _once`. Empty slots still execute (their cache writes are steered to
   the reserved scratch block) — occupancy is DATA, not shape.
 - **Chunked prefill.** Prompts prefill `prefill_chunk` tokens per
-  engine step, interleaved with decode ticks — an 8k prompt admitted
-  mid-run delays in-flight decodes by at most one chunk per tick
-  instead of one full prefill. Chunks are padded to the fixed chunk
-  length with the true length traced (the `prompt_bucket_len` idea at
-  chunk granularity), pad positions are never written.
+  engine step — an 8k prompt admitted mid-run delays in-flight decodes
+  by at most one chunk per tick instead of one full prefill. Chunks
+  are padded to the fixed chunk length with the true length traced
+  (the `prompt_bucket_len` idea at chunk granularity), pad positions
+  are never written. The step's decode tick RIDES IN THE CHUNK'S
+  PROGRAM (`_prefill_chunk`): a chunk is compute-bound at hundreds of
+  rows and a tick bound by the bytes of the same weights, so one
+  program a step streams them once and the tick's rows cost their
+  cache reads and little else. Whether the tick rides follows from
+  what the step holds, a chunk or none; nothing chooses it.
 - **Admission + preemption.** A request is admitted when a slot and
   its prompt's blocks are free; a decode append that finds the pool
   empty EVICTS the newest-admitted running request (its blocks free
@@ -85,12 +90,16 @@ head-of-line behind the longest. This engine serves a STREAM:
     mask can admit them (the prefill-padding argument). Zero new
     executables: drafts are data in rows that already executed empty.
 
-- **One tick in flight.** The decode loop dispatches tick N+1 before
-  it fetches tick N's tokens (`ServingEngine._decode_step`): the host
-  knows N+1's rows without them, each row's input token is read from
-  N's output on the device, and the fetch and the bookkeeping of N run
-  while the device runs N+1. The host's view trails the device by one
-  tick; the tokens are the same.
+- **One program in flight.** The decode loop dispatches program N+1
+  before it fetches program N's tokens (`ServingEngine._decode_step`):
+  the host knows N+1's rows without them, each row's input token is
+  read from N's output on the device, and the fetch and the
+  bookkeeping of N run while the device runs N+1. A prompt's first
+  token travels the same way: its last chunk's program samples it into
+  the request's own row of `nxt`, the next tick reads it from there,
+  and the host learns it at the landing, one step after the dispatch.
+  No fetch blocks before a step's dispatch. The host's view trails the
+  device by one program; the tokens are the same.
 
 - **A second kind of state** (`cfg.mixer`). A block with a state-space
   mixer beside its attention heads keeps, next to its K/V pool, one
@@ -129,7 +138,8 @@ import numpy as np
 
 from shallowspeed_tpu import chaos
 from shallowspeed_tpu.models import generate as G
-from shallowspeed_tpu.ops.flash_attention import (paged_decode_addresses,
+from shallowspeed_tpu.ops.flash_attention import (PAGED_TABLE_BYTES,
+                                                   paged_decode_addresses,
                                                    paged_flash_decode,
                                                    paged_flash_prefill,
                                                    paged_prefill_addresses)
@@ -220,9 +230,6 @@ def _sample_rows(logits, temp, seeds, idx, top_k: int, top_p: float):
     return jnp.where(temp <= 0.0, greedy, sampled)
 
 
-_sample_jit = jax.jit(_sample_rows, static_argnames=("top_k", "top_p"))
-
-
 def _latent_read(p, pool, bt, q_nope, q_rope, valid, cfg):
     """A latent layer's attention over its gathered table (the prefill
     chunk's read of a latent pool; the tick's is `_latent_decode`),
@@ -248,17 +255,19 @@ def _latent_decode(p, pool, bt, pos, q_nope, q_rope, cfg):
                            ).astype(q_nope.dtype)
 
 
-def _ffn_counted(p, x, cfg, h, live):
-    """`T._ffn` for the tick and the chunk. A routed block also gives
-    the (E,) int32 count of the assignments its LIVE rows made (`live`
-    has h's leading shape; padding rows and empty slots run the layer
-    too, their choices are not the traffic's); else None."""
+def _ffn_counted(p, x, cfg, h, lives):
+    """`T._ffn` for the tick and the chunk. A routed block also gives,
+    for each mask of `lives`, the (E,) int32 count of the assignments
+    the rows it marks made (a mask has h's leading shape; padding rows
+    and empty slots run the layer too, their choices are not the
+    traffic's, and a chunk's rows are counted apart from the rows of a
+    tick that rides with it); else None."""
     if "experts" not in p:
         return T._ffn(p, x, cfg, h)[0], None
     y, idx = T.routed_ffn(p, h, cfg)
     hot = jax.nn.one_hot(idx, cfg.n_routed_experts, dtype=jnp.int32)
     return (T.ffn_residual(p, x, y, cfg),
-            (hot * live[..., None, None]).sum((0, 1, 2)))
+            [(hot * live[..., None, None]).sum((0, 1, 2)) for live in lives])
 
 
 def _group_tables(bt, base, pos):
@@ -274,12 +283,148 @@ def _group_tables(bt, base, pos):
             for g, b in enumerate(bts)]
 
 
+def _project(p, h, pool, rotary, rope, cfg):
+    """A layer's projections of its norm output h (B, T, d), rotated at
+    the rows' own positions (`rope`): a latent layer's (q_nope, q_rope,
+    c, k_rope), else (q, k, v). Which follows from the pool."""
+    if LATENT in pool:
+        return T.latent_qkv(p, h, cfg, rope)
+    q, k, v = T._qkv(p, h, cfg)
+    if rotary:
+        q, k = rope(q), rope(k)
+    return q, k, v
+
+
+def _rows_tick(bt, base, pos, bs):
+    """The tick's rows' addresses: for each layer group (table,
+    position in the table's coordinates, the block each row writes),
+    the offset in that block (a base is a whole number of blocks), and
+    which rows are live (S,): a row that holds nothing has a table of
+    scratch."""
+    rows = jnp.arange(pos.shape[0])
+    tables = [(b, at, b[rows, at // bs])
+              for b, at in _group_tables(bt, base, pos)]
+    return tables, pos % bs, tables[0][0][:, 0] != SCRATCH_BLOCK
+
+
+def _tick_attend(p, pool, proj, table, off, window, cfg):
+    """The tick's side of a layer's cache: every row's new entry
+    written at (its block, `off`), then each row's read of the blocks
+    its position (and `window`) admit, where they lie
+    (`paged_flash_decode`, `_latent_decode`). `proj`: `_project` of the
+    rows, (S, 1, ...) each; `table`: the layer's group of `_rows_tick`.
+    Returns (the pool's leaves as written, the heads' output (S, H * hd))."""
+    bt_g, at, blk = table
+    if LATENT in pool:
+        qn, qr, c, kr = proj
+        pool = write_rows(pool, c, kr, blk, off, False)
+        a = _latent_decode(p, pool, bt_g, at, qn, qr, cfg)
+    else:
+        q, k, v = proj
+        pool = {**pool, **write_rows(pool, k[:, 0], v[:, 0], blk, off,
+                                     "k_s" in pool)}
+        # heads that are not whole lanes wide (no published size; toy
+        # configurations on the chip) are beyond the kernel's DMA when
+        # compiled and keep the gathered read
+        if paged_decode_addresses(pool):
+            a = paged_flash_decode(q[:, 0], pool, bt_g, at, window=window)
+        else:
+            valid = position_mask(bt_g.shape[1] * pool_block_size(pool),
+                                  at[:, None], window)
+            a = masked_attention(q, gather_table(pool, bt_g),
+                                 valid[:, None, None, None, :], cfg)
+    return pool, a.reshape(a.shape[0], -1)
+
+
+def _chunk_attend(p, pool, proj, table, n_tok, window, cfg):
+    """The chunk's side of a layer's cache: its `n_tok` true rows
+    written through the request's table (`write_chunk`), then the
+    causal read over the table, earlier chunks included: where it lies
+    (`paged_flash_prefill`) or gathered at the table's width under the
+    position mask, as `paged_prefill_addresses` says. `proj`:
+    `_project` of the chunk, (1, C, ...) each; `table`: (the (1, W)
+    table, the chunk's first position in its coordinates). Returns
+    (the pool's leaves as written, the heads' output (1, C, H * hd))."""
+    bt_g, at0 = table
+    c = proj[0].shape[1]
+
+    def valid():                        # the gathered reads' (C, W * bs)
+        return position_mask(bt_g.shape[1] * pool_block_size(pool),
+                             (at0 + jnp.arange(c))[:, None], window)
+
+    if LATENT in pool:
+        qn, qr, lat, kr = proj
+        pool = write_chunk(pool, lat[0][:, None], kr[0][:, None],
+                           bt_g[0], at0, n_tok, False)
+        a = _latent_read(p, pool, bt_g, qn, qr, valid()[None, None], cfg)
+    else:
+        q, k, v = proj
+        pool = {**pool, **write_chunk(pool, k[0], v[0], bt_g[0], at0,
+                                      n_tok, "k_s" in pool)}
+        if paged_prefill_addresses(pool, bt_g.shape[1]):
+            a = paged_flash_prefill(q[0], pool, bt_g[0], at0, n_tok,
+                                    window=window)
+        else:
+            a = masked_attention(q, gather_table(pool, bt_g),
+                                 valid()[None, None, None], cfg)
+    return pool, a.reshape(1, c, -1)
+
+
+def _advance_rows(slabs, left, live):
+    """A tick's write of a mixer's slabs: the row is the slot, a live
+    row takes the state its token left and a row that does not decode
+    (an empty slot, a prompt between two chunks, a request just
+    finished) keeps what its row held. One elementwise pass over the
+    whole slab, which XLA:TPU fuses with the readout's reduction and
+    runs on the donated buffer: a scatter of the new rows (to a
+    scratch row for the others) has to be handed them in HBM first,
+    two passes more."""
+    return {n: jnp.where(live.reshape((-1,) + (1,) * (slab.ndim - 1)),
+                         left[n], slab) for n, slab in slabs.items()}
+
+
+# A block of either program is a jitted function, inlined where it is
+# called: a model's layers of one kind are traced to a jaxpr ONCE a
+# program, and the loop re-binds that jaxpr's equations a layer where,
+# written out, it ran the model's Python a layer. That is host time of
+# every program's set-up, which no compilation cache holds (the chip's
+# host needs ~1 s to trace and lower a 16-layer tick), and it is what
+# keeps a chunk's program, which carries the tick's rows too, as quick
+# to set up as the chunk alone was. `inline=True` because a real call
+# a layer costs device time: XLA:TPU does not fuse across it (the
+# matmul that ends a block with the next block's norm), and a tick of
+# `olmo-1b` took 4.16 ms where it takes 3.97 (PERF.md, PR 37). Inlined,
+# the programs are the loop's own, operation for operation.
+
+
+@partial(jax.jit, static_argnames=("cfg", "window", "rotary"),
+         inline=True)
+def _tick_layer(p, pool, x, pos, table, off, live, *, cfg, window, rotary):
+    """One block of `_decode_tick` on the rows' x (S, 1, d): `table`
+    is the layer's group of `_rows_tick`, `live` (S, 1). Returns (x,
+    the layer's pool as written, the routed counts or None)."""
+    pool, slabs = kv_leaves(pool), state_leaves(pool)
+    rope = lambda u: _rope_rows(u, pos, cfg.rope_theta)
+    h = T._norm(p["ln1"], x, cfg)
+    pool, a = _tick_attend(p, pool, _project(p, h, pool, rotary, rope, cfg),
+                           table, off, window, cfg)
+    x = T.attn_residual(p, x, a[:, None], h, cfg)
+    if slabs:
+        y, left = T.mixer(p["mixer"], h, cfg, slabs)
+        x = x + y
+        pool = {**pool, **_advance_rows(slabs, left, live)}
+    x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), (live,))
+    return x, pool, n
+
+
 @partial(jax.jit, static_argnames=("cfg", "top_k", "top_p"),
          donate_argnums=(1,))
 def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
                  ahead, base=None, *, cfg: T.TransformerConfig, top_k: int,
                  top_p: float):
-    """One compiled decode tick over the whole slot batch.
+    """One compiled decode tick over the whole slot batch: the program
+    of a step that holds no prefill chunk (in a step that holds one the
+    same rows ride in the chunk's program, `_prefill_chunk`).
 
     tok/pos/temp/seeds/idx: (S,) per-slot last token, write position,
     sampling state; bt: (S, W) block tables (W is the bucketed width —
@@ -287,14 +432,15 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
     (`cache.layer_groups`; a tuple of them, or the one table of a model
     with one group), and `base` (groups, S) where a group's tables do
     not start at position 0 (`_group_tables`). The decode loop keeps
-    one tick in flight (`ServingEngine._decode_step`), so the host may
-    not have a row's last token yet: `prev` is the previous tick's
-    `nxt` as it left that program (a device array the host never
-    fetched for this), and a row whose `ahead` flag is set reads
-    `prev[row]` where the others read the host's `tok[row]` (a request
-    whose prefill just sampled its first token, every row when nothing
-    is in flight). A request keeps its slot, so the row is the same in
-    both ticks. Each slot writes its
+    one program in flight (`ServingEngine._decode_step`), so the host
+    may not have a row's last token yet: `prev` is the previous
+    program's `nxt` as it left that program (a device array the host
+    never fetched for this), and a row whose `ahead` flag is set reads
+    `prev[row]` where the others read the host's `tok[row]` (every row
+    when nothing is in flight). A request keeps its slot, so the row
+    is the same in both programs: a decoding row's last token, and the
+    first token of a request whose prompt's last chunk was the program
+    before. Each slot writes its
     token's K/V at (bt[pos // bs], pos % bs) and attends over the
     blocks of its table that its position (and window) admit, read
     from the pool where they lie (`paged_flash_decode`: no gathered
@@ -317,8 +463,8 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
 
     A layer with a mixer (`cfg.mixer`) also carries its slabs in its
     pool's dict, one row a slot: the tick's row IS the slot, every row
-    is advanced, and a row that is not live keeps what it held (one
-    elementwise pass over the donated slab, in place).
+    is advanced, and a row that is not live keeps what it held
+    (`_advance_rows`).
 
     Draft rows (speculative decoding) are ordinary rows at consecutive
     positions of a shared table: the pool write happens before the
@@ -326,63 +472,20 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
     single-pass verify."""
     params = T.cast_params(params, cfg.compute_dtype)
     tok = jnp.where(ahead, prev, tok)
-    s_rows = tok.shape[0]
-    bs = pool_block_size(pools[0])
-    quant = "k_s" in pools[0]
     x = T.embed_tokens(params, tok, cfg)[:, None, :]        # (S, 1, d)
     if not cfg.rope:
         x = x + params["pos_emb"][pos][:, None, :]
     if cfg.compute_dtype is not None:
         x = x.astype(cfg.compute_dtype)
-    rows = jnp.arange(s_rows)
-    tables = [(b, at, b[rows, at // bs])
-              for b, at in _group_tables(bt, base, pos)]
-    off = pos % bs          # a base is a whole number of blocks
-    live = (tables[0][0][:, 0] != SCRATCH_BLOCK)[:, None]   # (S, 1)
-    rope = lambda u: _rope_rows(u, pos, cfg.rope_theta)
-    # heads that are not whole lanes wide (no published size; toy
-    # configurations on the chip) are beyond the kernel's DMA when
-    # compiled and keep the gathered read
-    paged = paged_decode_addresses(kv_leaves(pools[0]))
+    tables, off, live = _rows_tick(bt, base, pos, pool_block_size(pools[0]))
+    live = live[:, None]                                    # (S, 1)
     new_pools, counts = [], []
     for p, pool, (window, rotary), g in zip(
             params["blocks"], pools, cfg.layer_specs, group_of_layer(cfg)):
-        bt_g, at, blk = tables[g]
-        pool, slabs = kv_leaves(pool), state_leaves(pool)
-        h = T._norm(p["ln1"], x, cfg)
-        if LATENT in pool:
-            qn, qr, c, kr = T.latent_qkv(p, h, cfg, rope)
-            pool = write_rows(pool, c, kr, blk, off, False)
-            a = _latent_decode(p, pool, bt_g, at, qn, qr, cfg)
-        else:
-            q, k, v = T._qkv(p, h, cfg)
-            if rotary:
-                q, k = rope(q), rope(k)
-            pool = {**pool, **write_rows(pool, k[:, 0], v[:, 0], blk, off,
-                                         quant)}
-            if paged:
-                a = paged_flash_decode(q[:, 0], pool, bt_g, at, window=window)
-            else:
-                valid = position_mask(bt_g.shape[1] * bs, at[:, None], window)
-                a = masked_attention(q, gather_table(pool, bt_g),
-                                     valid[:, None, None, None, :], cfg)
-        x = T.attn_residual(p, x, a.reshape(s_rows, 1, -1), h, cfg)
-        if slabs:
-            # the row is the slot; a row that does not decode (an empty
-            # slot, a prompt between two chunks, a request just
-            # finished) keeps what its row held. One elementwise pass
-            # over the whole slab, which XLA:TPU fuses with the
-            # readout's reduction and runs on the donated buffer: a
-            # scatter of the new rows (to a scratch row for the others)
-            # has to be handed them in HBM first, two passes more
-            y, left = T.mixer(p["mixer"], h, cfg, slabs)
-            x = x + y
-            pool = {**pool, **{
-                n: jnp.where(live.reshape((-1,) + (1,) * (slab.ndim - 1)),
-                             left[n], slab) for n, slab in slabs.items()}}
-        x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
+        x, pool, n = _tick_layer(p, pool, x, pos, tables[g], off, live,
+                                 cfg=cfg, window=window, rotary=rotary)
         if n is not None:
-            counts.append(n)
+            counts += n
         new_pools.append(pool)
     x = T._norm(params["ln_f"], x, cfg)
     logits = T.head_logits(params, x[:, 0], cfg).astype(jnp.float32)
@@ -390,11 +493,68 @@ def _decode_tick(params, pools, tok, pos, bt, temp, seeds, idx, prev,
     return nxt, new_pools, jnp.stack(counts) if counts else None
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
+@partial(jax.jit, static_argnames=("cfg", "window", "rotary"),
+         inline=True)
+def _chunk_layer(p, pool, x, pos, table, n_tok, pos0, slot, tick, lives, *,
+                 cfg, window, rotary):
+    """One block of `_prefill_chunk` on x (1, C + S, d), the chunk's C
+    rows and then the S rows of the tick that rides with it (S = 0 and
+    `tick` None: the chunk alone). `pos`: every row's position;
+    `table`: the layer's group of `_group_tables`; `tick`: (the
+    layer's group of `_rows_tick`, the rows' offsets, which are live
+    (S,)); `lives`: the masks the routed counts are taken under, (1,
+    C + S) each. Returns (x, the layer's pool as written, the routed
+    counts, one a mask, or None)."""
+    c = x.shape[1] - (0 if tick is None else tick[1].shape[0])
+    pool, slabs = kv_leaves(pool), state_leaves(pool)
+    rope = lambda u: T.rope_rotate(u, pos, cfg.rope_theta)
+    # the tick's rows of a (1, C + S, ...) array as the tick has them
+    ride = lambda u: jnp.swapaxes(u[:, c:], 0, 1)           # (S, 1, ...)
+    h = T._norm(p["ln1"], x, cfg)
+    proj = _project(p, h, pool, rotary, rope, cfg)
+    pool, a = _chunk_attend(p, pool, [u[:, :c] for u in proj], table, n_tok,
+                            window, cfg)
+    if tick is not None:
+        t_table, off, t_live = tick
+        pool, at = _tick_attend(p, pool, [ride(u) for u in proj], t_table,
+                                off, window, cfg)
+        a = jnp.concatenate([a, at[None]], 1)
+    x = T.attn_residual(p, x, a, h, cfg)
+    if slabs:
+        y, left = T.mixer(
+            p["mixer"], h[:, :c], cfg,
+            {n: jnp.where(pos0 == 0, 0, slab[slot][None]).astype(slab.dtype)
+             for n, slab in slabs.items()}, n_tok)
+        slabs = {n: slab.at[slot].set(left[n][0])
+                 for n, slab in slabs.items()}
+        if tick is not None:
+            yt, left = T.mixer(p["mixer"], ride(h), cfg, slabs)
+            y = jnp.concatenate([y, jnp.swapaxes(yt, 0, 1)], 1)
+            slabs = _advance_rows(slabs, left, t_live)
+        x = x + y
+        pool = {**pool, **slabs}
+    x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), lives)
+    return x, pool, n
+
+
+@partial(jax.jit, static_argnames=("cfg", "top_k", "top_p"),
+         donate_argnums=(1,))
 def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
-                   cow_dst, base=None, slot=None, *,
-                   cfg: T.TransformerConfig):
-    """One chunk of a request's prefill: tokens (1, C) — C is the
+                   cow_dst, base=None, slot=None, tick=None, *,
+                   cfg: T.TransformerConfig, top_k: int = 0,
+                   top_p: float = 0.0):
+    """One chunk of a request's prefill and, riding in the same
+    program, the step's decode tick: the program of a step that holds
+    a chunk. Everything row-wise (the norms, the projections, the FFN,
+    the experts) runs ONCE over the chunk's C rows and the tick's S
+    rows together, so a step streams each weight once; everything that
+    addresses a cache keeps the two reads and the two writes the two
+    programs have (`_chunk_attend`, `_tick_attend`). The chunk is
+    compute-bound at hundreds of rows and the tick bound by the same
+    weights' bytes, so the rows that ride cost their cache reads and
+    little else.
+
+    tokens (1, C) — C is the
     fixed chunk length, `n_tok` the traced true count (the tail is
     padding: never written, and masked out of every true row's read).
     Writes the chunk's K/V through the block table (`write_chunk`: the
@@ -411,10 +571,28 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     pool, an int8 pool and a table of a thousand positions or fewer
     are gathered at the table's width and scored under the position
     mask, which is the quicker read there.
-    Returns (f32 logits at the chunk's last
-    true position — consumed only on the final chunk — the updated,
-    donated pools, and the routed layers' assignment counts of the
-    chunk's true rows as `_decode_tick` gives them).
+
+    `tick` is `_decode_tick`'s per-row arguments in its own order
+    (tok, pos, bt, temp, seeds, idx, prev, ahead, base), which the
+    engine always passes: rows that decode are advanced as the tick
+    advances them, a row that holds nothing (all of them in a step in
+    which nobody decodes) reads one block. The prefilling request's own
+    row `slot` is such a row, and carries the chunk's sample: the
+    chunk's last true position takes that row's place under the head,
+    sampled with `temp[slot]`, `seeds[slot]`, `idx[slot]`, so
+    `nxt[slot]` is the request's next token (token index
+    len(generated), exactly like `generate()`'s post-prefill sample)
+    where this chunk is its prompt's last, and means nothing before.
+    The tick's table is as wide as the engine says, ONE width whatever
+    the rows hold (`ServingEngine._ride_blocks`), so the programs stay
+    one a chunk-table width. Returns (`nxt` (S,), the updated, donated
+    pools, the routed layers' (layers, E) assignment counts of the
+    chunk's true rows, those of the tick's live rows; None for a model
+    without routed layers).
+
+    Without `tick` (callers that lower or run the chunk alone) the
+    program is the chunk's part and returns (f32 logits at the chunk's
+    last true position, the pools, the chunk's counts).
 
     PREFIX-CACHE ALIGNMENT CONTRACT: cache hits are granular to WHOLE
     blocks — `pos0` on a hit is the matched aligned token count, so the
@@ -431,13 +609,17 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     data, every call) keeps `executable_counts()` flat: cache hits
     change block-table *data*, never the compiled-program set.
 
-    `slot` is the request's row of a mixer's slabs (None: the model has
-    none): the chunk starts from zeros where `pos0` is 0 and from that
-    row otherwise, and writes the state after its `n_tok` true rows."""
+    `slot` is also the request's row of a mixer's slabs: the chunk
+    starts from zeros where `pos0` is 0 and from that row otherwise,
+    and writes the state after its `n_tok` true rows. A mixer keeps
+    its two calls here, the chunk's scan and the tick's one-step
+    update, each with its own read of the mixer's projections: no cell
+    prefills a hybrid model inside its window (PERF.md §7h), so sharing
+    them is not worth the code now. The prefilling request's row is
+    not live in the tick, so the two writes never meet."""
     params = T.cast_params(params, cfg.compute_dtype)
     c = tokens.shape[1]
     bs = pool_block_size(pools[0])
-    quant = "k_s" in pools[0]
     group = group_of_layer(cfg)
     cow_src, cow_dst = jnp.atleast_1d(cow_src), jnp.atleast_1d(cow_dst)
     pools = [{**pool, **{name: leaf.at[cow_dst[g]].set(leaf[cow_src[g]])
@@ -446,55 +628,49 @@ def _prefill_chunk(params, pools, tokens, pos0, n_tok, bt, cow_src,
     pos = pos0 + jnp.arange(c)
     x = G._embed(params, tokens, pos0, cfg)                  # (1, C, d)
     tables = _group_tables(bt, base, pos0)
-    live = (jnp.arange(c) < n_tok)[None, :]                 # (1, C)
-    rope = lambda u: T.rope_rotate(u, pos, cfg.rope_theta)
-
-    def valid(bt_g, at0, window):       # the gathered reads' (C, W * bs)
-        return position_mask(bt_g.shape[1] * bs,
-                             (at0 + jnp.arange(c))[:, None], window)
-
+    lives = [jnp.arange(c) < n_tok]
+    if tick is not None:
+        tok, t_pos, t_bt, temp, seeds, idx, prev, ahead, t_base = tick
+        xt = T.embed_tokens(params, jnp.where(ahead, prev, tok), cfg)
+        if not cfg.rope:
+            xt = xt + params["pos_emb"][t_pos]
+        x = jnp.concatenate([x, xt.astype(x.dtype)[None]], 1)  # (1, C+S, d)
+        t_tables, off, t_live = _rows_tick(t_bt, t_base, t_pos, bs)
+        pos = jnp.concatenate([pos, t_pos])
+        # each side's rows among all of them, for the routed counts
+        lives = [jnp.pad(lives[0], (0, t_live.shape[0])),
+                 jnp.pad(t_live, (c, 0))]
+    lives = [live[None, :] for live in lives]               # (1, rows)
     new_pools, counts = [], []
     for p, pool, (window, rotary), g in zip(
             params["blocks"], pools, cfg.layer_specs, group):
-        bt_g, at0 = tables[g]
-        pool, slabs = kv_leaves(pool), state_leaves(pool)
-        h = T._norm(p["ln1"], x, cfg)
-        if LATENT in pool:
-            qn, qr, lat, kr = T.latent_qkv(p, h, cfg, rope)
-            pool = write_chunk(pool, lat[0][:, None], kr[0][:, None],
-                               bt_g[0], at0, n_tok, False)
-            a = _latent_read(p, pool, bt_g, qn, qr,
-                             valid(bt_g, at0, window)[None, None], cfg)
-        else:
-            q, k, v = T._qkv(p, h, cfg)
-            if rotary:
-                q, k = rope(q), rope(k)
-            pool = {**pool, **write_chunk(pool, k[0], v[0], bt_g[0], at0,
-                                          n_tok, quant)}
-            if paged_prefill_addresses(pool, bt_g.shape[1]):
-                a = paged_flash_prefill(q[0], pool, bt_g[0], at0, n_tok,
-                                        window=window)
-            else:
-                a = masked_attention(
-                    q, gather_table(pool, bt_g),
-                    valid(bt_g, at0, window)[None, None, None], cfg)
-        x = T.attn_residual(p, x, a.reshape(1, c, -1), h, cfg)
-        if slabs:
-            y, left = T.mixer(
-                p["mixer"], h, cfg,
-                {n: jnp.where(pos0 == 0, 0, slab[slot][None]).astype(
-                    slab.dtype) for n, slab in slabs.items()}, n_tok)
-            x = x + y
-            pool = {**pool, **{n: slab.at[slot].set(left[n][0])
-                               for n, slab in slabs.items()}}
-        x, n = _ffn_counted(p, x, cfg, T._norm(p["ln2"], x, cfg), live)
+        x, pool, n = _chunk_layer(
+            p, pool, x, pos, tables[g], n_tok, pos0, slot,
+            None if tick is None else (t_tables[g], off, t_live), lives,
+            cfg=cfg, window=window, rotary=rotary)
         if n is not None:
             counts.append(n)
         new_pools.append(pool)
-    x = T._norm(params["ln_f"], x, cfg)
-    x_last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 1, False)
-    logits = T.head_logits(params, x_last, cfg).astype(jnp.float32)
-    return logits, new_pools, jnp.stack(counts) if counts else None
+    # a side's (layers, E): the chunk's counts, then the tick's
+    counts = [jnp.stack(side) for side in zip(*counts)] \
+        or [None] * len(lives)
+    last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 1, False)   # (1, d)
+    if tick is None:
+        logits = T.head_logits(params, T._norm(params["ln_f"], last, cfg),
+                               cfg).astype(jnp.float32)
+        return logits, new_pools, counts[0]
+    x = T._norm(params["ln_f"], x[0, c:].at[slot].set(last[0]), cfg)
+    logits = T.head_logits(params, x, cfg).astype(jnp.float32)
+    nxt = _sample_rows(logits, temp, seeds, idx, top_k, top_p)
+    return nxt, new_pools, *counts
+
+
+def clear_program_caches() -> None:
+    """Forget every traced serving program, the blocks' own traces
+    included: for a test that swaps a kernel for its reference, or the
+    interpreter for the compiler, under programs already traced."""
+    for program in (_decode_tick, _prefill_chunk, _tick_layer, _chunk_layer):
+        program.clear_cache()
 
 
 class _Req:
@@ -533,9 +709,11 @@ class _Req:
         self.wait_s = 0.0               # queue time over every stint
         self.first_tok_t = None
         self.last_tok = 0
-        # tokens of this request still on the device: 1 while it has a
-        # row in the tick in flight, whose token the host has not
-        # fetched yet (`ServingEngine._decode_step`), else 0
+        # tokens of this request still on the device: 1 while the
+        # program in flight holds a row of it, or its prompt's last
+        # chunk, whose token the host has not fetched yet
+        # (`ServingEngine._decode_step`), else 0. The position that
+        # program wrote last is booked in `written` with the token
         self.in_flight = 0
         # lifecycle tracing (schema v8): the host-side phase timeline,
         # plus this request's named Chrome-trace track
@@ -681,6 +859,22 @@ class ServingEngine:
         self._held_bound = [g.held_bound(self.block_size, ahead)
                             for g in self.groups]
         self._windowed = any(g.window for g in self.groups)
+        # the tick's tables inside the chunk's program (`_prefill_chunk`)
+        # have ONE width a group, whatever the rows hold, so that the
+        # width is no compile key there and a chunk program warmed with
+        # every row dead is the program of every later step: room for
+        # the most blocks a request can come to hold (`max_seq`
+        # positions, or the whole pool), halved until the paged
+        # kernel's scalar memory takes a table of `max_slots` such
+        # rows (a tick wider than that compiles nowhere)
+        cap = max(self.table_bucket,
+                  PAGED_TABLE_BYTES // (4 * self.max_slots))
+        self._ride_blocks = []
+        for g, al in enumerate(self.allocs):
+            room = min(self._peak_blocks(g, cfg.max_seq), al.n_usable)
+            while self._width(g, room) > cap:
+                room //= 2
+            self._ride_blocks.append(room)
         # constant param term at the STORAGE dtypes actually served
         # (int8/fp8 values + f32 scales when weight_quant is on)
         self._p_bytes = param_read_bytes(self.params, cfg)
@@ -697,7 +891,11 @@ class ServingEngine:
                          # ticks dispatched while the tick before
                          # them was still in flight (its tokens on
                          # the device, not yet on the host)
-                         "ticks_ahead": 0, "prefill_chunks": 0,
+                         "ticks_ahead": 0,
+                         # ticks that rode in a prefill chunk's program
+                         # (a step that holds a chunk dispatches one
+                         # program, not two)
+                         "ticks_fused": 0, "prefill_chunks": 0,
                          "shed_toggles": 0, "spec_drafted": 0,
                          "spec_accepted": 0, "prefix_lookups": 0,
                          "prefix_hits": 0, "prefix_skipped_tokens": 0,
@@ -794,11 +992,12 @@ class ServingEngine:
         # the jit cache and stamp nothing
         self._tick_widths: set[tuple] = set()
         self._last_width = 0
-        # the decode tick in flight (`_decode_step`): (its requests,
+        # the program in flight (`_decode_step`): (the tick's requests,
         # their drafts, its `nxt` and routed counts, both still on the
-        # device), or None. `_no_tok` stands in for `nxt` in a tick
-        # dispatched with nothing in flight, whose rows all read the
-        # host's tokens.
+        # device, and the request whose prompt's last chunk the program
+        # held and whose first token `nxt` carries, if any), or None.
+        # `_no_tok` stands in for `nxt` in a program dispatched with
+        # nothing in flight, whose rows all read the host's tokens.
         self._flight = None
         self._no_tok = jnp.zeros((self.max_slots,), jnp.int32)
 
@@ -899,20 +1098,26 @@ class ServingEngine:
                                      if s is not None)
 
     def step(self) -> bool:
-        """One scheduler tick: admissions, ONE prefill chunk (FIFO
-        across prefilling requests), one decode tick over every
-        decoding slot. Returns whether any work ran — decodes advance
-        every step even while a long prompt prefills, which is the
-        chunked-prefill no-stall contract.
+        """One scheduler tick: admissions, then ONE program: the next
+        prefill chunk (FIFO across prefilling requests) with the decode
+        tick over every decoding slot riding in it (`_prefill_chunk`),
+        or, in a step that holds no chunk, the decode tick alone
+        (`_decode_tick`). Returns whether any work ran — decodes
+        advance every step even while a long prompt prefills, which is
+        the chunked-prefill no-stall contract, and a step that holds a
+        chunk streams the weights once, not twice.
 
-        The decode tick a step dispatches stays IN FLIGHT when the
-        step returns: its tokens are fetched and booked by the next
-        step, after that step has dispatched its own tick
-        (`_decode_step`). So the host's view (`poll`, the records, a
-        freed slot) trails the device by one tick, and a step that
-        finds a tick in flight and nothing left to dispatch lands it
-        and counts as work: `run()` and a `drain()` loop step until
-        `pending()` is 0 and so deliver every token."""
+        The program a step dispatches stays IN FLIGHT when the step
+        returns: its tokens are fetched and booked by the next step,
+        after that step has dispatched its own program
+        (`_decode_step`). That holds for a prompt's FIRST token too: it
+        is sampled by the program of the prompt's last chunk and
+        reaches the host (`poll`, `first_tok_t`) one step later, with
+        the tick's. So the host's view (`poll`, the records, a freed
+        slot) trails the device by one program, and a step that finds
+        one in flight and nothing left to dispatch lands it and counts
+        as work: `run()` and a `drain()` loop step until `pending()` is
+        0 and so deliver every token."""
         plan = self.chaos_plan if self.chaos_plan is not None \
             else chaos.active()
         # the scheduler's host phases as spans (telemetry/trace.py):
@@ -936,8 +1141,11 @@ class ServingEngine:
                 before = self._admit_counter
                 did = self._admit()
                 sp.set(n_admitted=self._admit_counter - before)
-            did = self._prefill_step() or did
-            did = self._decode_step() or did
+            pre = [r for r in self.slots
+                   if r is not None and r.phase == "prefill"]
+            did = self._decode_step(
+                min(pre, key=lambda r: r.admit_seq)         # FIFO
+                if pre else None) or did
         return did
 
     def run(self, max_steps: int | None = None) -> dict:
@@ -970,10 +1178,12 @@ class ServingEngine:
         """Live jit-cache sizes of the serving entrypoints — the
         compile-count pin (`fn._cache_size`, the same counter the
         analysis retrace rule and RunTelemetry read). After warmup
-        these must NOT grow as requests churn."""
+        these must NOT grow as requests churn. `sample` was the first
+        token's own program until that token came to be sampled inside
+        `_prefill_chunk`; the key stays for those who sum the three."""
         return {"decode_tick": int(_decode_tick._cache_size()),
                 "prefill_chunk": int(_prefill_chunk._cache_size()),
-                "sample": int(_sample_jit._cache_size())}
+                "sample": 0}
 
     # ------------------------------------------------------- lifecycle
 
@@ -1253,18 +1463,6 @@ class ServingEngine:
                 return m, [ids[lo:m] for ids, lo in zip(found, first)]
         return none
 
-    def _prefill_step(self) -> bool:
-        pre = [r for r in self.slots
-               if r is not None and r.phase == "prefill"]
-        if not pre:
-            return False
-        req = min(pre, key=lambda r: r.admit_seq)     # FIFO
-        tr = tracer()
-        with tr.span("prefill", rid=req.rid,
-                     chunk=req.written // self.prefill_chunk) as sp:
-            self._prefill_chunk_of(req, tr, sp)
-        return True
-
     def _layer_attrs(self, counts, rows_read: int) -> dict:
         """What a program run's span says of the layers that differ by
         kind: `latent_tokens` (cache rows its latent layers each read),
@@ -1327,138 +1525,175 @@ class ServingEngine:
         if not self._windowed:
             return tuple(bts), None
         base = np.zeros((len(self.groups), n), np.int32)
-        base[:, [i for i, _ in rows]] = np.transpose(
-            [r.base for _, r in rows]) * self.block_size
+        if rows:
+            base[:, [i for i, _ in rows]] = np.transpose(
+                [r.base for _, r in rows]) * self.block_size
         return tuple(bts), base
 
-    def _prefill_chunk_of(self, req, tr, sp) -> None:
+    def _prefill_chunk_of(self, req, n_tok: int, rows, rode: int,
+                          released: int, tr) -> tuple:
+        """Dispatch `req`'s next chunk of `n_tok` tokens with the step's
+        tick riding in its program: `rows` are the tick's per-row
+        arguments (`_decode_prep`), `rode` of them live; `released`
+        is what making room for the chunk handed back. Returns (`nxt`,
+        the tick's routed counts, and `req` where this chunk is its
+        prompt's last, else None): what the step puts in flight.
+
+        Nothing here waits for the device. After its prompt's last
+        chunk the request is a decoder whose first token is in flight,
+        like any decoder's next token: the chunk's last position is
+        booked with that token when the flight lands (`written` stays
+        one short of the prompt until then, `in_flight` is 1), so the
+        next tick gives it a row at position `written + in_flight` and
+        sampling index `len(generated) + in_flight`, reading its input
+        token from `prev[slot]` on the device."""
         c = self.prefill_chunk
-        n_tok = min(c, len(req.ctx) - req.written)
-        released = self.counters["released"]
-        if not self._ensure_blocks(req, req.written + n_tok):
-            return                  # evicted for blocks: it prefills anew
-        sp.set(released=self.counters["released"] - released)
-        self._lifecycle(req, "prefill", chunk=req.written // c,
-                        tokens=int(n_tok))
-        tokens = np.zeros((1, c), np.int32)
-        tokens[0, :n_tok] = req.ctx[req.written:req.written + n_tok]
-        # as wide as the most the prompt will hold, in every chunk: a
-        # window group's table grows to its bound over the first
-        # chunks, and each width on the way would be a program
-        bts, base = self._rows_tables(
-            [(0, req)], 1, [self._peak_blocks(g, len(req.ctx))
-                            for g in range(len(self.groups))])
-        # copy-on-write rides the chunk as DATA on every call (scratch
-        # self-copy when there is nothing to copy) — zero executables
-        cow = np.asarray(req.cow if req.cow is not None
-                         else [(SCRATCH_BLOCK, SCRATCH_BLOCK)]
-                         * len(self.groups), np.int32)
-        if self._state_bytes:
-            carried = int(req.written > 0)
-            sp.set(state_carried=carried)
-            self.counters["state_carried"] += carried
-        with tr.span("prefill.dispatch"):
-            logits, self.pools, counts = _prefill_chunk(
-                self.params, self.pools, tokens, np.int32(req.written),
-                np.int32(n_tok), bts, cow[:, 0], cow[:, 1],
-                None if base is None else base[:, 0],
-                np.int32(req.slot) if self._state_bytes else None,
-                cfg=self.cfg)
-        walked = self._chunk_walked(req, n_tok, bts)
-        read = {"blocks_read": sum(walked),
-                "blocks_table": sum(bt.size for bt in bts)}
-        sp.set(**read, **{f"blocks_read_{g.name}": n
-                          for g, n in zip(self.groups, walked)})
-        for name, value in read.items():
-            self.counters[f"prefill_{name}"] += value
-        if req.cow is not None:
-            # the copy landed: drop the references that kept the shared
-            # source blocks alive for it
-            for al, (src, _) in zip(self.allocs, req.cow):
-                al.release([src])
-            req.cow = None
-        req.written += n_tok
-        self.counters["prefill_chunks"] += 1
-        if req.written == len(req.ctx):
-            # prompt complete: sample this request's next token (token
-            # index len(generated) — 0 for a fresh request, the
-            # continuation index after a preemption) from the last
-            # true position's logits, exactly like generate()'s
-            # post-prefill sample
-            with tr.span("prefill.sample"):
-                tok = _sample_jit(
-                    logits, np.asarray([req.temp], np.float32),
-                    np.asarray([req.seed], np.uint32),
-                    np.asarray([len(req.generated)], np.int32),
+        with tr.span("prefill", rid=req.rid, chunk=req.written // c,
+                     released=released, rows_rode=rode) as sp:
+            self._lifecycle(req, "prefill", chunk=req.written // c,
+                            tokens=int(n_tok))
+            tokens = np.zeros((1, c), np.int32)
+            tokens[0, :n_tok] = req.ctx[req.written:req.written + n_tok]
+            # as wide as the most the prompt will hold, in every chunk:
+            # a window group's table grows to its bound over the first
+            # chunks, and each width on the way would be a program
+            bts, base = self._rows_tables(
+                [(0, req)], 1, [self._peak_blocks(g, len(req.ctx))
+                                for g in range(len(self.groups))])
+            # copy-on-write rides the chunk as DATA on every call
+            # (scratch self-copy when there is nothing to copy) — zero
+            # executables
+            cow = np.asarray(req.cow if req.cow is not None
+                             else [(SCRATCH_BLOCK, SCRATCH_BLOCK)]
+                             * len(self.groups), np.int32)
+            if self._state_bytes:
+                carried = int(req.written > 0)
+                sp.set(state_carried=carried)
+                self.counters["state_carried"] += carried
+            with tr.span("prefill.dispatch"):
+                nxt, self.pools, _, counts = _prefill_chunk(
+                    self.params, self.pools, tokens, np.int32(req.written),
+                    np.int32(n_tok), bts, cow[:, 0], cow[:, 1],
+                    None if base is None else base[:, 0],
+                    np.int32(req.slot), rows, cfg=self.cfg,
                     top_k=self.top_k, top_p=self.top_p)
-            with tr.span("prefill.fetch"):
-                tok, counts = jax.device_get((tok, counts))
-                tok = int(tok[0])
-            sp.set(**self._layer_attrs(counts, req.written))
+            walked = self._chunk_walked(req, n_tok, bts)
+            read = {"blocks_read": sum(walked),
+                    "blocks_table": sum(bt.size for bt in bts)}
+            sp.set(**read, **{f"blocks_read_{g.name}": n
+                              for g, n in zip(self.groups, walked)})
+            for name, value in read.items():
+                self.counters[f"prefill_{name}"] += value
+            if req.cow is not None:
+                # the copy is dispatched: drop the references that kept
+                # the shared source blocks alive for it
+                for al, (src, _) in zip(self.allocs, req.cow):
+                    al.release([src])
+                req.cow = None
+            req.written += n_tok
+            self.counters["prefill_chunks"] += 1
+            if req.written < len(req.ctx):
+                return nxt, counts, None
+            # prompt complete: `nxt[slot]` is this request's next token
+            # (index len(generated) — 0 for a fresh request, the
+            # continuation index after a preemption), in flight
+            req.written -= 1
+            req.in_flight = 1
             req.phase = "decode"
             self._lifecycle(req, "decoding")
-            self._append_token(req, tok)
+            return nxt, counts, req
 
-    def _decode_step(self) -> bool:
-        """One turn of the decode loop, which keeps ONE tick in flight.
+    def _decode_step(self, chunk=None) -> bool:
+        """One turn of the decode loop, which keeps ONE program in
+        flight; `chunk` is the request whose next prefill chunk this
+        step holds (None: nobody is prefilling).
 
-        Tick N+1 is prepared and dispatched BEFORE tick N's tokens are
-        fetched: the host knows which rows N+1 has without them
-        (positions advance by one, a request finishes by count, tables
-        grow from positions), and each row's input token is taken from
-        N's `nxt` on the device (`_decode_tick`'s `prev` / `ahead`).
-        Then N is landed, fetched (`decode.fetch`, which waits for the
-        tick BEFORE the one this span dispatched) and emitted, while
-        the device runs N+1. Nothing is speculated: only when the host
-        learns a token changes, never which token it is.
+        Program N+1 is prepared and dispatched BEFORE program N's
+        tokens are fetched: the host knows which rows N+1 has without
+        them (positions advance by one, a request finishes by count,
+        tables grow from positions), and each row's input token is
+        taken from N's `nxt` on the device (`_decode_tick`'s `prev` /
+        `ahead`). Then N is landed, fetched (`decode.fetch`, which
+        waits for the program BEFORE the one this span dispatched) and
+        emitted, while the device runs N+1. Nothing is speculated: only
+        when the host learns a token changes, never which token it is.
 
-        Who lands the tick in flight: the next turn, as above (also
+        With a chunk the step's one program is the chunk's, and the
+        tick's rows ride in it (`_prefill_chunk_of`; all of them dead
+        where nobody decodes, and then a chunk that is not its prompt's
+        last leaves nothing to land). The chunk's blocks are made sure
+        of first, then the rows'; where either evicts the prefilling
+        request (the newest admitted goes first) the step is the tick
+        alone. The `prefill` span lies inside this turn's `decode`
+        span, around the dispatch.
+
+        Who lands the program in flight: the next turn, as above (also
         when it has nothing to dispatch: the last tick of a drain);
         `_ensure_blocks` when the pool runs out, before it evicts.
         Draft rows (`spec_k > 0`) are proposed from the last token on
-        the host, so with them every tick is landed in the turn that
+        the host, so with them every program is landed in the turn that
         dispatched it: the same loop with nothing in flight."""
         had = self._flight is not None
-        if not had and not any(
+        if chunk is None and not had and not any(
                 r is not None and r.phase == "decode" for r in self.slots):
             return False
         tr = tracer()
         with tr.span("decode") as sp:
             released = self.counters["released"]
             with tr.span("decode.prep"):
-                prep = self._decode_prep()
-            released = self.counters["released"] - released
-            # what is in flight NOW: prep lands it itself where it
-            # ran out of blocks
-            ahead = int(prep is not None and self._flight is not None)
+                n_tok = 0
+                if chunk is not None:
+                    n_tok = min(self.prefill_chunk,
+                                len(chunk.ctx) - chunk.written)
+                    if not self._ensure_blocks(chunk, chunk.written + n_tok):
+                        chunk = None    # evicted for blocks: it prefills anew
+                for_chunk = self.counters["released"] - released
+                prep = self._decode_prep(chunk)
+                if chunk is not None and chunk.slot is None:
+                    chunk = None        # evicted for the rows' blocks
+            released = self.counters["released"] - released - for_chunk
+            # what is in flight NOW: prep lands it itself where it ran
+            # out of blocks
+            ahead = int(prep is not None and bool(prep[0])
+                        and self._flight is not None)
             sp.set(ahead=ahead)
             new = None
             if prep is not None:
                 actives, drafts, rows = prep
                 pos, bts = rows[1], rows[2]
-                with tr.span("decode.dispatch"):
-                    nxt, self.pools, counts = _decode_tick(
-                        self.params, self.pools, *rows, cfg=self.cfg,
-                        top_k=self.top_k, top_p=self.top_p)
-                new = (actives, drafts, nxt, counts)
-                self.counters["ticks_ahead"] += ahead
-                walked = self._blocks_walked(pos)
-                read = {"blocks_read": sum(walked),
-                        "blocks_table": sum(bt.size for bt in bts)}
-                for i, g in enumerate(self.groups):
-                    read[f"blocks_read_{g.name}"] = walked[i]
-                    read[f"{g.name}_blocks"] = sum(len(r.tables[i])
-                                                   for r in actives)
-                if self._state_bytes:
-                    read["state_rows"] = len(actives)
-                    read["state_bytes"] = len(actives) * self._state_bytes
-                sp.set(n_active=len(actives), width=bts[0].shape[1],
-                       released=released, **read)
-                for name, value in read.items():
-                    self.counters[name] += value
+                if chunk is not None:
+                    nxt, counts, first = self._prefill_chunk_of(
+                        chunk, n_tok, rows, len(actives), for_chunk, tr)
+                else:
+                    with tr.span("decode.dispatch"):
+                        nxt, self.pools, counts = _decode_tick(
+                            self.params, self.pools, *rows, cfg=self.cfg,
+                            top_k=self.top_k, top_p=self.top_p)
+                    first = None
+                if actives or first is not None:
+                    new = (actives, drafts, nxt, counts, first)
+                if actives:
+                    fused = int(chunk is not None)
+                    self.counters["ticks_ahead"] += ahead
+                    self.counters["ticks_fused"] += fused
+                    walked = self._blocks_walked(pos)
+                    read = {"blocks_read": sum(walked),
+                            "blocks_table": sum(bt.size for bt in bts)}
+                    for i, g in enumerate(self.groups):
+                        read[f"blocks_read_{g.name}"] = walked[i]
+                        read[f"{g.name}_blocks"] = sum(len(r.tables[i])
+                                                       for r in actives)
+                    if self._state_bytes:
+                        read["state_rows"] = len(actives)
+                        read["state_bytes"] = len(actives) * self._state_bytes
+                    sp.set(n_active=len(actives), width=bts[0].shape[1],
+                           released=released, fused=fused, **read)
+                    for name, value in read.items():
+                        self.counters[name] += value
             if self.spec_k > 0:
-                # the next drafts need this tick's tokens on the host:
-                # it is the one to land, and nothing stays in flight
+                # the next drafts need this program's tokens on the
+                # host: it is the one to land, and nothing stays in
+                # flight
                 self._flight, new = new, None
             self._land(sp)
             if new is not None:
@@ -1466,45 +1701,55 @@ class ServingEngine:
                 for r in new[0]:
                     r.in_flight = 1
         # no work only where every decoder was evicted for blocks and
-        # prep landed no tick on the way
+        # prep landed nothing on the way
         return had or prep is not None
 
     def _land(self, sp=None) -> bool:
-        """Fetch and book the tick in flight, if there is one: its
-        tokens and routed counts leave the device in one wait, the
-        layers' attrs of THAT tick go on `sp` (the `decode` span open
-        now, which may have dispatched the tick after it) and into the
-        counters, `_decode_emit` appends. Returns whether a tick was
-        landed."""
+        """Fetch and book the program in flight, if there is one: its
+        tokens and the tick's routed counts leave the device in one
+        wait, the layers' attrs of THAT tick go on `sp` (the `decode`
+        span open now, which may have dispatched the program after it)
+        and into the counters, `_decode_emit` appends. Returns whether
+        one was landed."""
         if self._flight is None:
             return False
-        (actives, drafts, nxt, counts), self._flight = self._flight, None
+        (actives, drafts, nxt, counts, first), self._flight = \
+            self._flight, None
         tr = tracer()
         with tr.span("decode.fetch"):
             # one wait for both: the counts leave the device beside
             # the tokens, not in a second round trip after them
             nxt, counts = jax.device_get((nxt, counts))
-        attrs = self._layer_attrs(
-            counts, sum(r.written + 1 for r in actives))
-        if sp is not None:
-            sp.set(**attrs)
-        for name, value in attrs.items():
-            self.counters[name] += value
+        if actives:
+            attrs = self._layer_attrs(
+                counts, sum(r.written + 1 for r in actives))
+            if sp is not None:
+                sp.set(**attrs)
+            for name, value in attrs.items():
+                self.counters[name] += value
         with tr.span("decode.emit"):
-            self._decode_emit(actives, drafts, nxt)
+            self._decode_emit(actives, drafts, nxt, first)
         return True
 
-    def _decode_prep(self):
+    def _decode_prep(self, chunk=None):
         """The next tick's host-side inputs: (its requests, their
         speculative drafts, `_decode_tick`'s per-row arguments in its
-        own order), or None when no request has a row to take.
+        own order), or None when no request has a row to take and the
+        step holds no chunk. With `chunk`, the request whose prefill
+        chunk the rows will ride with (`_prefill_chunk`'s `tick`), the
+        rows are made even where all of them are dead, the tables are
+        of the one width the chunk's program takes (`_ride_blocks`),
+        and the chunk's sampling state stands in the request's own row,
+        which is dead in the tick. Making sure of the rows' blocks may
+        evict that request: the rows are then the tick's alone.
 
         Works from the host's positions PLUS what is in flight: a
-        request with a row in the tick in flight (`in_flight`) writes
-        one position further and samples one index further than the
-        host has booked, and reads its token from the device; one whose
-        token in flight is its last takes no row. Which rows are live
-        is exact either way."""
+        request with a token in flight (`in_flight`: a row in the tick
+        in flight, or its prompt's last chunk) writes one position
+        further and samples one index further than the host has
+        booked, and reads its token from the device; one whose token
+        in flight is its last takes no row. Which rows are live is
+        exact either way."""
         owed = lambda r: len(r.generated) + r.in_flight < r.max_new
         for req in [r for r in self.slots
                     if r is not None and r.phase == "decode"]:
@@ -1513,16 +1758,21 @@ class ServingEngine:
                 self._ensure_blocks(req, req.written + req.in_flight + 1)
         actives = [r for r in self.slots
                    if r is not None and r.phase == "decode" and owed(r)]
-        if not actives:
+        if chunk is not None and chunk.slot is None:
+            chunk = None
+        if not actives and chunk is None:
             return None
         s = self.max_slots
         # speculative drafts claim the tick's FREE rows (empty slots
-        # and prefilling requests' idle rows) — occupancy is data, so
+        # and prefilling requests' idle rows, but for the row that
+        # carries a chunk's sample) — occupancy is data, so
         # drafting costs zero executables and zero extra tick time
         drafts: dict[str, tuple] = {}
         if self.spec_k > 0:
-            free = [i for i in range(s)
-                    if i not in {r.slot for r in actives}]
+            taken = {r.slot for r in actives}
+            if chunk is not None:
+                taken.add(chunk.slot)
+            free = [i for i in range(s) if i not in taken]
             for r in sorted(actives, key=lambda r: r.admit_seq):
                 if not free:
                     break
@@ -1542,7 +1792,8 @@ class ServingEngine:
         rows = [(r.slot, r) for r in actives] + [
             (row, r) for r, assigned in drafts.values()
             for row, _ in assigned]
-        bts, base = self._rows_tables(rows, s)
+        bts, base = self._rows_tables(
+            rows, s, None if chunk is None else self._ride_blocks)
         for r in actives:
             tok[r.slot] = r.last_tok
             ahead[r.slot] = r.in_flight
@@ -1560,8 +1811,12 @@ class ServingEngine:
                 temp[row] = r.temp
                 seeds[row] = r.seed
                 idx[row] = len(r.generated) + j
+        if chunk is not None:
+            temp[chunk.slot] = chunk.temp
+            seeds[chunk.slot] = chunk.seed
+            idx[chunk.slot] = len(chunk.generated)
         w = tuple(bt.shape[1] for bt in bts)
-        if w not in self._tick_widths:
+        if chunk is None and w not in self._tick_widths:
             # FIRST tick at this width bucket compiles a fresh
             # executable (geometric bucketing keeps the count O(log
             # max_len)); later returns to the width hit the jit cache,
@@ -1575,24 +1830,29 @@ class ServingEngine:
                                  width=int(w[0]),
                                  tick=self.counters["ticks"])
             self._tick_widths.add(w)
-        self._last_width = int(w[0])
+        if chunk is None:
+            self._last_width = int(w[0])
         prev = self._no_tok if self._flight is None else self._flight[2]
         return actives, drafts, (tok, pos, bts, temp, seeds, idx, prev,
                                  ahead, base)
 
-    def _decode_emit(self, actives, drafts, nxt) -> None:
-        """Book the tick's tokens: counters, appends (which finish
-        requests), the windowed tick line."""
+    def _decode_emit(self, actives, drafts, nxt, first=None) -> None:
+        """Book a landed program's tokens: counters, appends (which
+        finish requests), the windowed tick line. `first` is the
+        request whose prompt's last chunk the program held: its first
+        token is `nxt[slot]` like a row's, and the position it books
+        is the chunk's last."""
         bs = self.block_size
-        self.counters["ticks"] += 1
-        ends = [r.written + 1 + len(drafts.get(r.rid, (None, ()))[1])
-                for r in actives]
-        self._last_touched = [
-            sum(blocks_for(n, bs) - g.first_live_block(n - 1, bs)
-                for n in ends) for g in self.groups]
-        self._last_state_rows = len(actives) if self._state_bytes else 0
+        if actives:
+            self.counters["ticks"] += 1
+            ends = [r.written + 1 + len(drafts.get(r.rid, (None, ()))[1])
+                    for r in actives]
+            self._last_touched = [
+                sum(blocks_for(n, bs) - g.first_live_block(n - 1, bs)
+                    for n in ends) for g in self.groups]
+            self._last_state_rows = len(actives) if self._state_bytes else 0
         emitted = 0
-        for r in actives:
+        for r in actives + ([first] if first is not None else []):
             # speculation tallies accrue BEFORE the appends: an
             # accepted final draft can finish the request, and the
             # "request" record stamped at that instant must already
@@ -1621,7 +1881,8 @@ class ServingEngine:
                 self._append_token(r, tok_next)
                 emitted += 1
         self._win_tokens += emitted
-        self._maybe_log()
+        if actives:
+            self._maybe_log()
 
     # ------------------------------------------------- spec decoding
 

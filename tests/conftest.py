@@ -101,8 +101,8 @@ def gathered_tick(monkeypatch):
     reference. The tick is traced anew on entry and on exit."""
     from shallowspeed_tpu.serving import engine
 
-    engine._decode_tick.clear_cache()
+    engine.clear_program_caches()
     monkeypatch.setattr(engine, "paged_flash_decode", gathered_read)
     yield
     monkeypatch.undo()
-    engine._decode_tick.clear_cache()
+    engine.clear_program_caches()

@@ -1,6 +1,10 @@
-"""The decode loop keeps one tick in flight (`ServingEngine._decode_step`):
-tick N+1 is dispatched before tick N's tokens are fetched, each row's
-input token taken from N's `nxt` on the device.
+"""The decode loop keeps one program in flight
+(`ServingEngine._decode_step`): program N+1 is dispatched before
+program N's tokens are fetched, each row's input token taken from N's
+`nxt` on the device. A program is a decode tick or, in a step that
+holds a prefill chunk, the chunk's with the tick's rows riding in it
+(tests/test_serving_fused.py), whose `nxt` also carries the first token
+of a prompt that the chunk completes.
 
 What has to hold, on a K/V pool and on a latent pool:
 
@@ -200,48 +204,66 @@ def test_the_last_tick_in_flight_is_delivered(model, how):
 
 @pytest.mark.parametrize("spec_k", [0, 2], ids=["ahead", "drafts"])
 def test_dispatch_closes_before_the_fetch_of_the_tick_before(model, spec_k):
-    """From the tracer's ring: tick N+1's `decode.dispatch` closes
-    before tick N's `decode.fetch` opens, except after a drain (a tick
-    with none before it in flight); with drafts every tick is fetched
-    in the turn that dispatched it."""
+    """From the tracer's ring: program N+1's dispatch closes before
+    program N's `decode.fetch` opens, except after a drain (a program
+    with none before it in flight); with drafts every program is
+    fetched in the turn that dispatched it. A request's programs are
+    its prompt's one chunk (`prefill.dispatch`, which samples the first
+    token and blocks on nothing) and seven ticks (`decode.dispatch`)."""
     eng = engine(model, spec_k=spec_k)
     first = tracer().event_count
     for rid, n in (("x", 9), ("y", 14)):       # two runs, each drained
         eng.submit(toks(n, n), 8, rid=rid)
         eng.run()
     ring = tracer().ring()[-(tracer().event_count - first):]
-    turns = {e[SEQ]: e for e in ring if e[NAME] == "decode"}
-    dispatches = [e for e in ring if e[NAME] == "decode.dispatch"]
+    by_seq = {e[SEQ]: e for e in ring}
+
+    def turn(e):                # the `decode` span a span lies in
+        while e[NAME] != "decode":
+            e = by_seq[e[PARENT]]
+        return e
+
+    dispatches = [e for e in ring
+                  if e[NAME] in ("decode.dispatch", "prefill.dispatch")]
     fetches = [e for e in ring if e[NAME] == "decode.fetch"]
     ticks = eng.counters["ticks"]
-    assert len(dispatches) == len(fetches) == ticks
-    flags = [turns[d[PARENT]][ATTRS]["ahead"] for d in dispatches]
+    kinds = [d[NAME].split(".")[0] for d in dispatches]
+    # (accepted drafts make a request's ticks fewer)
+    assert kinds == (["prefill"] + ["decode"] * 7) * 2 or spec_k
+    assert kinds.count("decode") == ticks and kinds[0] == "prefill"
+    assert len(fetches) == len(dispatches) == ticks + 2
+    flags = [turn(d)[ATTRS]["ahead"] for d in dispatches]
     assert sum(flags) == eng.counters["ticks_ahead"]
+    assert eng.counters["ticks_fused"] == 0     # nobody decoded beside a chunk
     if spec_k:
-        assert flags == [0] * ticks
-        assert all(d[T1] <= f[T0] and d[PARENT] == f[PARENT]
+        assert flags == [0] * (ticks + 2)
+        assert all(d[T1] <= f[T0] and turn(d) is turn(f)
                    for d, f in zip(dispatches, fetches))
         return
-    # 7 ticks a request, less the two that followed a drain
-    assert flags == ([0] + [1] * 6) * 2
-    for n in range(ticks - 1):
+    # every tick found a program in flight: the first one its prompt's
+    # chunk, whose own turn followed a drain
+    assert flags == ([0] + [1] * 7) * 2
+    for n in range(ticks + 1):
         if flags[n + 1]:
             assert dispatches[n + 1][T1] <= fetches[n][T0]
-            assert dispatches[n + 1][PARENT] == fetches[n][PARENT]
+            assert turn(dispatches[n + 1]) is turn(fetches[n])
         else:
             assert fetches[n][T1] <= dispatches[n + 1][T0]
     # a turn carries the routed / latent attrs of the tick it LANDED
-    # and the read's of the tick it dispatched
-    for f in fetches:
-        attrs = turns[f[PARENT]][ATTRS]
-        assert ("latent_tokens" in attrs) == bool(eng.cfg.latent)
-    assert all("blocks_read" in turns[d[PARENT]][ATTRS] for d in dispatches)
+    # (a chunk's landing brings a first token and no tick) and the
+    # read's of the tick it dispatched
+    for d, f in zip(dispatches, fetches):
+        attrs = turn(f)[ATTRS]
+        assert ("latent_tokens" in attrs) == (
+            bool(eng.cfg.latent) and d[NAME] == "decode.dispatch")
+        assert ("blocks_read" in turn(d)[ATTRS]) == (
+            d[NAME] == "decode.dispatch")
 
 
 def test_executables_grow_by_one_a_width():
     """One program a width, whether the tick reads its tokens from the
     host (nothing in flight: `_no_tok` stands in for `nxt`) or from the
-    tick before it: the two are one signature."""
+    program before it, a tick's `nxt` or a chunk's: one signature."""
     cfg = T.TransformerConfig(vocab=72, d_model=32, n_heads=4, n_layers=1,
                               max_seq=128)          # this test's alone
     eng = engine((cfg, jax.device_put(T.init(cfg, seed=0))), max_slots=2)
@@ -251,7 +273,8 @@ def test_executables_grow_by_one_a_width():
     eng.submit(toks(1, 4) % 72, 13, rid="grow")
     eng.run()
     assert int(_decode_tick._cache_size()) - before == 2
-    assert eng.counters["ticks_ahead"] == eng.counters["ticks"] - 1
+    # the first tick found the prompt's chunk in flight
+    assert eng.counters["ticks_ahead"] == eng.counters["ticks"] == 12
     warm = eng.executable_counts()
     for i in range(3):                  # after a drain, and joining
         eng.submit(toks(2 + i, 3 + i) % 72, 9 - i, rid=f"r{i}")

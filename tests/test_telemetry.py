@@ -560,24 +560,31 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
     parent = lambda e: by_seq[e[1]][2] if e[1] is not None else None
     names = {e[2] for e in ring}
     assert {"engine.step", "admit", "prefill", "prefill.dispatch",
-            "prefill.sample", "prefill.fetch", "decode", "decode.prep",
-            "decode.dispatch", "decode.fetch", "decode.emit"} <= names
-    assert "compile" not in names
+            "decode", "decode.prep", "decode.dispatch", "decode.fetch",
+            "decode.emit"} <= names
+    # the first token is sampled inside the chunk's program and fetched
+    # with the tick's: the spans of its own sample and fetch are gone
+    assert not {"prefill.sample", "prefill.fetch", "compile"} & names
     for e in ring:
+        # a step is one turn of the decode loop; the chunk it holds is
+        # dispatched inside that turn
         want = {"engine.step": None, "admit": "engine.step",
-                "prefill": "engine.step", "decode": "engine.step",
+                "prefill": "decode", "decode": "engine.step",
                 "alloc": "decode.prep"}.get(e[2], e[2].split(".")[0])
         assert parent(e) == want, (e[2], parent(e))
     steps = [e for e in ring if e[2] == "engine.step"]
     assert [e[5]["tick"] for e in steps] == sorted(
         e[5]["tick"] for e in steps)
     assert sum(e[5]["n_admitted"] for e in ring if e[2] == "admit") == 3
-    decode = next(e for e in ring if e[2] == "decode")
+    # the first turn holds the first prompt's first chunk and no tick
+    assert set(next(e for e in ring if e[2] == "decode")[5]) == {"ahead"}
+    decode = next(e for e in ring if e[2] == "decode" and not
+                  e[5].get("fused", 1))
     # one group of layers (`full`): what the tick read and what its rows
     # hold are said for the model and for the group, nothing was released
     assert set(decode[5]) == {"ahead", "n_active", "width", "blocks_read",
                               "blocks_table", "blocks_read_full",
-                              "full_blocks", "released"}
+                              "full_blocks", "released", "fused"}
     assert decode[5]["blocks_read_full"] == decode[5]["blocks_read"]
     assert decode[5]["released"] == 0
     assert decode[5]["width"] == 4
@@ -585,6 +592,50 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
                   if parent(e) == "engine.step")
     whole = sum(e[4] - e[3] for e in steps)
     assert 0.75 * whole <= covered <= whole
+
+
+def test_a_fused_step_says_so_in_its_spans_and_counters(global_tracer,
+                                                        tiny_serving):
+    """A step that holds a chunk dispatches one program: its spans are
+    `decode` > `decode.prep`, `prefill` > `prefill.dispatch`, then
+    `decode.fetch` and `decode.emit` of the program before it, and no
+    `decode.dispatch`. `fused` on the `decode` span is 1 where a tick
+    rode in a chunk's program, `rows_rode` on the `prefill` span the
+    tick's live rows in it (0: nobody was decoding), and
+    `counters["ticks_fused"]` beside `["ticks"]` their count."""
+    eng, serve = tiny_serving
+    before = {k: eng.counters[k] for k in ("ticks", "ticks_fused",
+                                           "prefill_chunks")}
+    serve("fused-")
+    ring = global_tracer.ring()
+    kids = {}
+    for e in ring:
+        kids.setdefault(e[1], []).append(e[2])
+    turns = [e for e in ring if e[2] == "decode"]
+    chunks = {e[1]: e for e in ring if e[2] == "prefill"}
+    assert len(chunks) == eng.counters["prefill_chunks"] \
+        - before["prefill_chunks"] == 6
+    for t in turns:
+        held = chunks.get(t[0])
+        assert ("prefill" in kids[t[0]]) == (held is not None)
+        assert ("decode.dispatch" in kids[t[0]]) == (
+            held is None and "n_active" in t[5])
+        if held is None:
+            assert t[5].get("fused", 0) == 0
+            continue
+        assert kids[held[0]] == ["prefill.dispatch"]
+        assert kids[t[0]][:2] == ["decode.prep", "prefill"]
+        assert set(kids[t[0]][2:]) <= {"decode.fetch", "decode.emit"}
+        assert held[5]["rows_rode"] == t[5].get("n_active", 0)
+        assert t[5].get("fused", 0) == (held[5]["rows_rode"] > 0)
+        # the one width of a tick's table inside a chunk's program
+        assert t[5].get("width", 16) == 16      # 96 positions
+    fused = sum(t[5].get("fused", 0) for t in turns)
+    # two slots, three prompts of two chunks: the second and the third
+    # prompt's chunks each ride with a decoder
+    assert fused == eng.counters["ticks_fused"] - before["ticks_fused"] > 0
+    assert fused < eng.counters["ticks"] - before["ticks"]
+    assert sum(c[5]["rows_rode"] > 0 for c in chunks.values()) == fused
 
 
 def test_prefill_span_says_what_the_chunk_read(global_tracer, tiny_serving):

@@ -81,21 +81,28 @@ def mosaic_not_interpreted(monkeypatch):
     the interpreter; what is compiled here is compiled for the chip."""
     monkeypatch.setattr(flash_attention, "_interpret_default",
                         lambda: False)
-    # the kernels' entry points are jitted and would remember either mode
+    # the kernels' entry points are jitted, and so are the programs'
+    # blocks that call them: each would remember either mode
+    from shallowspeed_tpu.serving import engine
+
     kernels = (flash_attention.paged_flash_decode,
                flash_attention.paged_flash_prefill)
-    for kernel in kernels:
-        kernel.clear_cache()
+    for forget in [k.clear_cache for k in kernels] \
+            + [engine.clear_program_caches]:
+        forget()
     yield
-    for kernel in kernels:
-        kernel.clear_cache()
+    for forget in [k.clear_cache for k in kernels] \
+            + [engine.clear_program_caches]:
+        forget()
 
 
 def _compiled_text(program, one_chip, heads, kv_quant="", widths=None,
-                   chunk=CHUNK, n_blocks=N_BLOCKS):
+                   chunk=CHUNK, n_blocks=N_BLOCKS, slots=SLOTS, ride=None):
     """The optimized HLO of `program` for the chip, its number of pool
     leaves and one of them. `widths`: the tables' widths, one a layer
-    group (default: `WIDTH` each)."""
+    group (default: `WIDTH` each). `fused_chunk` is `_prefill_chunk`
+    with the tick's `slots` rows riding in it, their tables `ride`
+    wide (default: as `widths`)."""
     cfg = T.TransformerConfig(
         vocab=512, d_ff=512, n_layers=2, max_seq=2048, rope=True,
         norm="rmsnorm", ffn="swiglu", dtype=jnp.bfloat16,
@@ -119,21 +126,25 @@ def _compiled_text(program, one_chip, heads, kv_quant="", widths=None,
     # at position 0 (a window group) their bases; one group: one table
     groups = len(layer_groups(cfg))
     widths = widths or (WIDTH,) * groups
-    tables = lambda rows: arr(i32, rows, widths[0]) if groups == 1 \
-        else tuple(arr(i32, rows, w) for w in widths)
+    tables = lambda rows, widths=widths: arr(i32, rows, widths[0]) \
+        if groups == 1 else tuple(arr(i32, rows, w) for w in widths)
+    # `_decode_tick`'s per-row arguments, in its order
+    tick = lambda widths: (
+        arr(i32, slots), arr(i32, slots), tables(slots, widths),
+        arr(f32, slots), arr(i32, slots), arr(i32, slots), arr(i32, slots),
+        arr(jnp.bool_, slots),
+        None if groups == 1 else arr(i32, groups, slots))
     if program == "decode_tick":
-        traced = _decode_tick.trace(
-            params, pools, arr(i32, SLOTS), arr(i32, SLOTS),
-            tables(SLOTS), arr(f32, SLOTS), arr(i32, SLOTS),
-            arr(i32, SLOTS), arr(i32, SLOTS), arr(jnp.bool_, SLOTS),
-            None if groups == 1 else arr(i32, groups, SLOTS),
-            cfg=cfg, top_k=0, top_p=0.0)
+        traced = _decode_tick.trace(params, pools, *tick(widths), cfg=cfg,
+                                    top_k=0, top_p=0.0)
     else:
         pair = (arr(i32),) * 2 if groups == 1 else (arr(i32, groups),) * 2
+        rode = () if program == "prefill_chunk" else (
+            arr(i32), tick(ride or widths))
         traced = _prefill_chunk.trace(
             params, pools, arr(i32, 1, chunk), arr(i32), arr(i32),
             tables(1), *pair, None if groups == 1 else arr(i32, groups),
-            cfg=cfg)
+            *rode, cfg=cfg)
     text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
     leaf = next(iter(pools[0].values()))
     return text, len(jax.tree_util.tree_leaves(pools)), leaf
@@ -199,9 +210,10 @@ def _assert_written_in_place(program, text, n_leaves, leaf, n_donated=None):
         else:
             offenders.append(line.strip()[:160])
     assert not offenders, "\n".join(offenders)
-    # each leaf is written once a layer (and copied-on-write once more
-    # in the prefill chunk)
-    assert in_place == n_leaves * (2 if program == "prefill_chunk" else 1)
+    # each leaf is written once a layer by the tick's rows, and in the
+    # prefill chunk copied-on-write and written by the chunk's
+    assert in_place == n_leaves * {"decode_tick": 1, "prefill_chunk": 2,
+                                   "fused_chunk": 3}[program]
 
 
 @pytest.mark.parametrize("heads", list(_HEADS))
@@ -233,9 +245,9 @@ def test_decode_tick_reads_the_pool_through_the_kernel(one_chip, heads,
     # what this looks for is there to be found: the same tick reading
     # through the gathered table holds it, and no kernel
     monkeypatch.setattr(engine, "paged_flash_decode", gathered_read)
-    engine._decode_tick.clear_cache()
+    engine.clear_program_caches()
     before, _, _ = _compiled_text("decode_tick", one_chip, heads)
-    engine._decode_tick.clear_cache()
+    engine.clear_program_caches()
     assert table_ops(before) and "tpu_custom_call" not in before
 
 
@@ -286,11 +298,50 @@ def test_prefill_chunk_reads_the_pool_through_the_kernel(one_chip, heads,
     # the gathered read holds both, and no kernel
     monkeypatch.setattr(engine, "paged_prefill_addresses",
                         lambda pool, width: False)
-    engine._prefill_chunk.clear_cache()
+    engine.clear_program_caches()
     before, _, _ = _compiled_text("prefill_chunk", one_chip, heads, **shape)
-    engine._prefill_chunk.clear_cache()
+    engine.clear_program_caches()
     dtypes = {line.split(" = ")[1][:3] for line in big(before)}
     assert dtypes == {"bf1", "f32"} and "tpu_custom_call" not in before
+
+
+@pytest.mark.parametrize("heads,slots,n_blocks,widths,ride,kernels", [
+    # 8 slots, 32 k cache tokens, documents of up to 4,096 positions
+    ("mistral-7b-gqa", 8, 2049, (256,), (256,), 4),
+    # 32 slots, 196,608 cache tokens, prompts of up to 4,096 tokens
+    # under a tick's table of `max_seq` 8,192
+    ("moonlight-16b-latent", 32, 12289, (256,), (512,), 2),
+], ids=["doc-batch", "gen-batch"])
+def test_the_fused_chunk_keeps_both_programs_gates(one_chip, heads, slots,
+                                                   n_blocks, widths, ride,
+                                                   kernels):
+    """The program of a step that holds a chunk (`_prefill_chunk` with
+    the tick's rows riding in it), compiled for the chip at the two
+    cells' shapes, is held to what the two programs it replaces are:
+    every pool leaf written in place, three times a layer (the
+    copy-on-write block, the chunk's blocks, the tick's rows); the
+    tick's read through the kernel, so NO instruction of the gathered
+    table's shape, (slots, width, Hkv, block, hd), slots and width
+    merged or not, or the latent read's (slots, width * block, hd); the
+    chunk's read of a K/V pool through its kernel too, so nothing as
+    large as its gathered table or its float32 scores (a latent pool's
+    chunk keeps the gathered read of ITS one row's table, as before)."""
+    text, n_leaves, leaf = _compiled_text(
+        "fused_chunk", one_chip, heads, widths=widths, chunk=512,
+        n_blocks=n_blocks, slots=slots, ride=ride)
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    _assert_written_in_place("fused_chunk", text, n_leaves, leaf)
+    hkv, _, tail = leaf.shape[1:]
+    n_heads, w = _HEADS[heads]["n_heads"], ride[0]
+    rows = {slots * w * hkv * BLOCK * tail}
+    if kernels == 4:            # the chunk's table and scores, too
+        rows |= {widths[0] * hkv * BLOCK * tail}
+    scores = {n_heads * 512 * widths[0] * BLOCK} if kernels == 4 else set()
+    big = [line for dtype, dims, line in _results(text)
+           if (dtype == "bf16" and math.prod(dims) in rows
+               and dims[-1] == tail and len(dims) > 2)
+           or (dtype == "f32" and math.prod(dims) in scores)]
+    assert not big, "\n".join(big)
 
 
 @pytest.mark.parametrize("heads,width", [("moonlight-16b-latent", 512),
