@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from shallowspeed_tpu.models import transformer as T
 from shallowspeed_tpu.ops.attention import (attention, ring_attention,
                                             ulysses_attention)
+from shallowspeed_tpu.telemetry.trace import spanned, tracer
 from shallowspeed_tpu.utils import pvary_over
 
 tree_map = jax.tree_util.tree_map
@@ -69,6 +70,7 @@ class ContextParallelEngine:
     # checkpoint layout; placement is not structure (checkpoint.py)
     canonical_opt_identity = True
 
+    @spanned("build", engine="ContextParallelEngine")
     def __init__(self, cfg: T.TransformerConfig, optimizer, mesh: Mesh,
                  seed: int = 0, attn: str = "ring", zero1: bool = False,
                  zero2: bool = False, accum: int = 1,
@@ -91,8 +93,13 @@ class ContextParallelEngine:
         self.rep = NamedSharding(mesh, P())
         self.tile = NamedSharding(mesh, P("dp", "sp"))
 
-        self.params = jax.device_put(T.init(cfg, seed), self.rep)
-        self.opt_state = jax.device_put(optimizer.init(self.params), self.rep)
+        with tracer().span("build.init"):
+            host_params = T.init(cfg, seed)
+        with tracer().span("build.place"):
+            self.params = jax.device_put(host_params, self.rep)
+            del host_params
+            self.opt_state = jax.device_put(optimizer.init(self.params),
+                                            self.rep)
 
         opt = optimizer
         # Sliding windows compose with EVERY substrate: all of them take
@@ -546,8 +553,6 @@ class ContextParallelEngine:
     def train_batch_async(self, tokens, targets) -> jax.Array:
         """One optimizer step; loss as a lazy device scalar (no host sync —
         `float()` it only at log points; see `data/prefetch.py`)."""
-        from shallowspeed_tpu.telemetry import tracer
-
         step = np.uint32(self._step_count)
         self._step_count += 1
         monitored = self.health != "off"

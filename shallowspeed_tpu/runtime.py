@@ -61,10 +61,15 @@ def enable_compile_cache() -> str:
 
 def device_stamp() -> dict:
     """The device as JAX reports it: platform, kind and count. Touches
-    the backend — call it only from a process meant to hold the chip."""
+    the backend — call it only from a process meant to hold the chip
+    (the first call is the backend's start: its `backend.init` span is
+    that, a later one's a few microseconds)."""
     import jax
 
-    devs = jax.devices()
+    from shallowspeed_tpu.telemetry import tracer
+
+    with tracer().span("backend.init"):
+        devs = jax.devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}
 

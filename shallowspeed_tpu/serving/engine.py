@@ -143,7 +143,7 @@ from shallowspeed_tpu.ops.flash_attention import (PAGED_TABLE_BYTES,
                                                    paged_flash_decode,
                                                    paged_flash_prefill,
                                                    paged_prefill_addresses)
-from shallowspeed_tpu.telemetry.trace import tracer
+from shallowspeed_tpu.telemetry.trace import spanned, tracer
 from shallowspeed_tpu.telemetry.tracing import new_span_id, new_trace_id
 from shallowspeed_tpu.models import transformer as T
 from shallowspeed_tpu.models.kv_cache import masked_attention, position_mask
@@ -758,6 +758,7 @@ class ServingEngine:
     the schema-v6 `"request"` events and periodic `"generate"` tick
     lines."""
 
+    @spanned("build", engine="ServingEngine")
     def __init__(self, params, cfg: T.TransformerConfig, *,
                  n_blocks=64, block_size: int = 16,
                  max_slots: int = 4, prefill_chunk: int = 32,

@@ -7,8 +7,10 @@ makes the *inside* of a step visible without xprof:
 
 - `trace`        the one span recorder (`tracer().span("fwd", step=s)`):
                  a bounded in-memory ring at every level, `ss:<name>`
-                 annotations in a live `jax.profiler` trace, JAX's
-                 compiles as spans; host wall-clock and, at the `spans`
+                 annotations in a live `jax.profiler` trace; JAX's
+                 tracing, lowering and compiles, the process's start
+                 and long garbage collections as entries of the same
+                 ring; host wall-clock and, at the `spans`
                  level, device time via `block_until_ready` fences at
                  phase boundaries; exports JSONL and
                  Chrome-trace/Perfetto.
@@ -67,7 +69,7 @@ the documented measurement mode).
 # lazily so `python -m shallowspeed_tpu.telemetry --validate` — the
 # pre-commit hook — stays a millisecond stdlib-only run.
 from shallowspeed_tpu.telemetry.trace import (  # noqa: F401
-    Tracer, configure, tracer)
+    Tracer, configure, spanned, tracer)
 
 _LAZY = {
     "static_bubble": "bubble", "trace_bubble": "bubble",

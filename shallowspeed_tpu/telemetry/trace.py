@@ -32,29 +32,54 @@ innermost span open on the same thread at that moment (None at the
 top), and the ring is ordered by CLOSE, so a parent follows its
 children. `depth` is the nesting depth, the span's Chrome track.
 
-JAX's compiles land in the same ring: `watch_compiles()` registers
-`jax.monitoring` listeners once a process has JAX, and every backend
-compile becomes a `compile` span under whatever span was open (which
-step recompiled), every program the persistent cache did not hold a
-zero-length `cache_miss` entry beside it.
+Time that something else measured lands in the same ring, as entries
+recorded after the fact (`_record`) under whatever span the calling
+thread had open. Once a process has JAX the watch registers
+`jax.monitoring` listeners: every Python tracing of a jitted function
+becomes a `trace` entry, every conversion of a jaxpr to MLIR (a Pallas
+kernel's own lowering included) a `lower` entry, every backend compile
+a `compile` entry (which step recompiled), each with the function's
+name in `fun`, and every program the persistent cache did not hold a
+zero-length `cache_miss` entry. A jitted function traced inside
+another's trace reports inside its caller's interval, so a reader
+takes the UNION of the `trace` intervals, never their sum. The watch
+hooks `gc.callbacks` too: a collection that took `GC_SPAN_MIN_S` or
+more becomes a `gc` entry with its `generation` and what it
+`collected` (a shorter one leaves nothing), and while a profiler
+session is live it stands in the trace as `ss:gc` like any span, so an
+idle gap of the device under it is named for it.
+
+`watch_compiles()`, which a driver calls before its first compile
+(`runtime.enable_compile_cache()`), also records `startup`, once a
+process and at the top level whatever span is open: from the process's
+start as the kernel has it (`process_start()`: `/proc/self/stat` and
+`/proc/uptime`; where `/proc` is absent, from this module's import,
+which leaves out the interpreter and whatever was imported before the
+package) to that call, which is the interpreter and the imports of JAX
+and of the package. A tracer that `configure()` installs later holds
+that one entry again, as its first: it is a fact of the process, as
+the listeners serve whichever tracer is global.
 
 Export: one `spans.jsonl` line per closed span (append-streamed, so a
 killed run keeps its trace) and a Chrome-trace `trace.json`
 (`ph: "X"` complete events, microsecond timebase) written by `close()`
 — loadable in Perfetto / chrome://tracing with zero TPU tooling.
-Instant events, counter samples, track names and the request
-lifecycle's `complete()` phases stream to the file and the subscribers
-only; the ring holds the spans that code opened, and JAX's compiles.
+Track names and the request lifecycle's `complete()` phases stream to
+the file and the subscribers only; the ring holds the spans that code
+opened and the entries recorded after the fact.
 
 This module imports nothing but the standard library: a process that
 never imports JAX (the `--validate` pre-commit hook, a launch-only
-router) annotates nothing and watches no compiles.
+router) annotates nothing, watches no compiles and no collections.
 """
 
 from __future__ import annotations
 
+import functools
+import gc
 import itertools
 import json
+import os
 import sys
 import threading
 import time
@@ -93,6 +118,10 @@ _FIRST_TRACK_TID = 1000
 # the hot path is one module-global read per span.
 PHASE_HOOKS = None
 
+# a collection shorter than this leaves no `gc` entry: the young
+# generations' take tens of microseconds and would be most of the ring
+GC_SPAN_MIN_S = 1e-3
+
 # (TraceAnnotation, StepTraceAnnotation) once this process has JAX
 _NOTES = None
 _WATCHING = False
@@ -101,7 +130,7 @@ _WATCHING = False
 def _resolve_notes():
     """The profiler's annotation classes, once JAX is in the process
     (never imported from here: see the module docstring). The first
-    resolution also starts the compile watch."""
+    resolution also starts the watch (`_watch`)."""
     global _NOTES
     if "jax" not in sys.modules:
         return None
@@ -111,7 +140,7 @@ def _resolve_notes():
         return None
     _NOTES = (TraceAnnotation, StepTraceAnnotation,
               TraceAnnotation.is_enabled)
-    watch_compiles()
+    _watch()
     return _NOTES
 
 
@@ -200,7 +229,9 @@ class Tracer:
     The engines dispatch from one Python thread; background threads
     (prefetch, async save, whoever compiles) may emit spans too, each
     with its own nesting stack. The lock guards the ring's counter, the
-    JSONL append and the subscribers.
+    JSONL append and the subscribers; it is re-entrant because a
+    collection can start between two bytecodes of a thread that holds
+    it, and its `gc` entry is recorded from that thread.
     """
 
     def __init__(self, trace_dir=None, level: str = "off",
@@ -216,7 +247,7 @@ class Tracer:
         self._n_closed = 0               # spans ever put into the ring
         # named tracks (round 13): one per serving request
         self._next_tid = _FIRST_TRACK_TID
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._jsonl = None
         # span-event subscribers (round 12): the live monitor's
         # flight recorder rides here so the incident ring holds the
@@ -244,14 +275,6 @@ class Tracer:
         """Open a span; use as a context manager. Real at every level
         (see the module docstring for what `off` does with it)."""
         return Span(self, name, attrs)
-
-    def event(self, name: str, **attrs) -> None:
-        """Zero-duration instant event (e.g. 'recompile', 'ckpt')."""
-        if self.level == "off":
-            return
-        self._emit({"name": name, "ph": "i",
-                    "ts": round((self._clock() - self._epoch) * 1e6, 1),
-                    "args": attrs})
 
     def now(self) -> float:
         """This tracer's clock (perf_counter by default) — callers that
@@ -291,14 +314,6 @@ class Tracer:
         if tid is not None:
             ev["tid"] = tid
         self._emit(ev)
-
-    def counter(self, name: str, value) -> None:
-        """Monotonic/telemetry counter sample (recompiles, HBM bytes)."""
-        if self.level == "off":
-            return
-        self._emit({"name": name, "ph": "C",
-                    "ts": round((self._clock() - self._epoch) * 1e6, 1),
-                    "args": {"value": value}})
 
     def _close(self, entry: tuple) -> None:
         with self._lock:
@@ -344,7 +359,8 @@ class Tracer:
     @property
     def events(self) -> list[dict]:
         """The buffered spans as dicts in the export's shape (the full
-        stream, instants and counters included, lives in spans.jsonl)."""
+        stream, track names and lifecycle phases included, lives in
+        spans.jsonl)."""
         return [self._as_dict(e) for e in self.ring()]
 
     def events_since(self, seq: int) -> list[dict]:
@@ -412,10 +428,13 @@ _TRACER = Tracer(level="off")
 def configure(trace_dir=None, level: str = "off") -> Tracer:
     """Install (and return) the process-global tracer the engines emit
     into. Drivers call this once from the CLI flags; tests swap it
-    freely (the previous tracer is closed)."""
+    freely (the previous tracer is closed). Its first entry is the
+    process's `startup`, once the watch below has recorded one."""
     global _TRACER
     _TRACER.close()
     _TRACER = Tracer(trace_dir=trace_dir, level=level)
+    if _READY_AT is not None:
+        _record_startup()
     return _TRACER
 
 
@@ -424,26 +443,70 @@ def tracer() -> Tracer:
     return _TRACER
 
 
-# ------------------------------------------------------- compile watch
+def spanned(name: str, **attrs):
+    """Decorator: the whole call is one span of the global tracer (an
+    engine's constructor is its `build`)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inside(*args, **kwargs):
+            with _TRACER.span(name, **attrs):
+                return fn(*args, **kwargs)
+        return inside
+    return wrap
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# ------------------------------- what something else timed: the watch
+
+_IMPORTED_AT = time.perf_counter()
+_PROCESS_START = None
+_READY_AT = None        # end of `startup`: the first `watch_compiles()`
+_SPAN_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
+def process_start() -> float:
+    """When this process started, on `time.perf_counter` (the global
+    tracer's clock): the kernel's record of it, or this module's import
+    where there is no `/proc`. The start of `startup`, and what a
+    time-to-ready is counted from."""
+    global _PROCESS_START
+    if _PROCESS_START is None:
+        try:
+            with open("/proc/self/stat") as f:
+                ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+            with open("/proc/uptime") as f:
+                age = float(f.read().split()[0]) \
+                    - ticks / os.sysconf("SC_CLK_TCK")
+            _PROCESS_START = time.perf_counter() - age
+        except (OSError, ValueError, IndexError):
+            _PROCESS_START = _IMPORTED_AT
+    return _PROCESS_START
+
+
 def _record(name: str, t0: float, t1: float, attrs: dict) -> None:
-    """A span that JAX reported after the fact, into the global
-    tracer's ring under the calling thread's innermost open span."""
+    """A span that something else timed, into the global tracer's ring
+    under the calling thread's innermost open span."""
     tr = _TRACER
     stack = tr._thread_stack()
     tr._close((next(tr._ids), stack[-1].seq if stack else None, name,
                t0, t1, attrs, len(stack)))
 
 
+def _record_startup() -> None:
+    tr = _TRACER
+    tr._close((next(tr._ids), None, "startup", process_start(), _READY_AT,
+               {}, 0))
+
+
 def _on_duration(event: str, seconds: float, **kw) -> None:
-    if event == _COMPILE_EVENT:
+    name = _SPAN_OF_EVENT.get(event)
+    if name is not None:
         t1 = _TRACER._clock()
-        _record("compile", t1 - seconds, t1,
-                {"fun": kw.get("fun_name", "")})
+        _record(name, t1 - seconds, t1, {"fun": kw.get("fun_name", "")})
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -452,12 +515,33 @@ def _on_event(event: str, **_kw) -> None:
         _record("cache_miss", t, t, {})
 
 
-def watch_compiles() -> None:
-    """Register the `jax.monitoring` listeners, once a process (JAX
-    offers no way to take one back, so they serve whichever tracer is
-    global at the time). Called by `runtime.enable_compile_cache()`,
-    before a driver's first compile, and by the first span opened after
-    JAX was imported."""
+_GC_T0 = 0.0
+_GC_NOTE = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks`: collections do not nest, so one stamp serves."""
+    global _GC_T0, _GC_NOTE
+    if phase == "start":
+        if _NOTES is not None and _NOTES[2]():
+            _GC_NOTE = _NOTES[0](ANNOTATION_PREFIX + "gc",
+                                 generation=info["generation"])
+            _GC_NOTE.__enter__()
+        _GC_T0 = _TRACER._clock()
+        return
+    t1 = _TRACER._clock()
+    if _GC_NOTE is not None:
+        _GC_NOTE.__exit__(None, None, None)
+        _GC_NOTE = None
+    if t1 - _GC_T0 >= GC_SPAN_MIN_S:
+        _record("gc", _GC_T0, t1, {"generation": info["generation"],
+                                   "collected": info["collected"]})
+
+
+def _watch() -> None:
+    """Register the `jax.monitoring` listeners and hook `gc.callbacks`,
+    once a process (JAX offers no way to take a listener back, so they
+    serve whichever tracer is global at the time)."""
     global _WATCHING
     if _WATCHING:
         return
@@ -466,3 +550,18 @@ def watch_compiles() -> None:
 
     monitoring.register_event_duration_secs_listener(_on_duration)
     monitoring.register_event_listener(_on_event)
+    gc.callbacks.append(_on_gc)
+
+
+def watch_compiles() -> None:
+    """Start the watch and, the first time, record `startup`: the call
+    is where a process says it is ready to compile. Called by
+    `runtime.enable_compile_cache()`, before a driver's first compile.
+    The first span opened after JAX was imported starts the watch too
+    (`_resolve_notes`), but that is no moment a process chose: it
+    records no `startup`."""
+    global _READY_AT
+    _watch()
+    if _READY_AT is None:
+        _READY_AT = time.perf_counter()
+        _record_startup()

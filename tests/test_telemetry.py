@@ -55,17 +55,20 @@ def test_span_nesting_and_depth():
 def test_span_export_schema_roundtrip(tmp_path):
     tr = Tracer(trace_dir=tmp_path, level="steps")
     with tr.span("step", step=0):
-        tr.event("marker", note="x")
-        tr.counter("hbm_bytes", 123)
+        tr.complete("queued", tr.now(), tr.now(), tid=tr.track("request r"))
     tr.close()
     # streamed JSONL validates line-by-line against the schema
     assert schema.validate_file(tmp_path / "spans.jsonl") == []
     # Chrome trace: every X event has a dur, structure is loadable
     chrome = json.loads((tmp_path / "trace.json").read_text())
     phs = {e["ph"] for e in chrome["traceEvents"]}
-    assert phs == {"X", "i", "C"}
+    assert phs == {"X", "M"}
     for e in chrome["traceEvents"]:
         assert ("dur" in e) == (e["ph"] == "X")
+    # the instants and counter samples of older artifacts still validate
+    for ph in ("i", "C"):
+        assert schema.validate_line({"name": "m", "ph": ph, "ts": 1.0,
+                                     "args": {"value": 1}}) == []
 
 
 def test_schema_rejects_malformed_lines():
@@ -104,9 +107,8 @@ def test_schema_v4_ledger_lines_validate():
 def test_off_level_reaches_the_ring_and_nothing_else(monkeypatch,
                                                      tmp_path):
     """`--telemetry off`: a span is recorded in the ring, and that is
-    all — no instant event or counter sample, no subscriber call, no
-    file, and block_until_ready is never reached (the engines' async
-    dispatch stays async)."""
+    all — no subscriber call, no file, and block_until_ready is never
+    reached (the engines' async dispatch stays async)."""
     def boom(*_a, **_k):  # any fence attempt explodes
         raise AssertionError("off-level telemetry fenced device work")
 
@@ -116,8 +118,6 @@ def test_off_level_reaches_the_ring_and_nothing_else(monkeypatch,
     tr.subscribers.append(seen.append)
     with tr.span("step", step=0) as sp:
         sp.fence(object())
-    tr.event("x")
-    tr.counter("c", 1)
     tr.complete("late", 0.0, 1.0)
     assert tr.track("request r") == 0
     ring = tr.ring()
@@ -467,7 +467,7 @@ def test_tracer_event_windows_survive_buffer_eviction(monkeypatch):
 def test_chrome_trace_sources_full_stream_from_jsonl(tmp_path,
                                                     monkeypatch):
     """trace.json must carry the COMPLETE stream even when the ring
-    evicted early spans, and the instants and lifecycle phases the
+    evicted early spans, and the track names and lifecycle phases the
     ring never holds (spans.jsonl is the source of truth): a request's
     `e` phase is no `e` span."""
     monkeypatch.setattr(trace_mod, "RING_CAP", 2)
@@ -475,13 +475,12 @@ def test_chrome_trace_sources_full_stream_from_jsonl(tmp_path,
     for i in range(5):
         with tr.span("e", i=i):
             pass
-    tr.event("marker")
     tr.complete("e", tr.now(), tr.now(), tid=tr.track("request r"), i=9)
     tr.close()
     assert [e[5]["i"] for e in tr.ring()] == [3, 4]
     assert [e["args"]["i"] for e in tr.spans_named("e")] == [3, 4]
     chrome = json.loads((tmp_path / "trace.json").read_text())
-    assert len(chrome["traceEvents"]) == 8
+    assert len(chrome["traceEvents"]) == 7
 
 
 def test_make_calibration_twin_trains_at_double_n_mu():
@@ -514,6 +513,12 @@ def test_make_calibration_twin_trains_at_double_n_mu():
 
 
 # ---------------------------------------- spans inside the program
+
+
+# entries that something else timed: they lie under whichever span
+# was open on the thread at the time, or under none
+AFTER_THE_FACT = ("startup", "gc", "trace", "lower", "compile",
+                  "cache_miss")
 
 
 @pytest.fixture
@@ -566,6 +571,8 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
     # with the tick's: the spans of its own sample and fetch are gone
     assert not {"prefill.sample", "prefill.fetch", "compile"} & names
     for e in ring:
+        if e[2] in AFTER_THE_FACT:
+            continue        # under whichever span was open, or none
         # a step is one turn of the decode loop; the chunk it holds is
         # dispatched inside that turn
         want = {"engine.step": None, "admit": "engine.step",
@@ -589,7 +596,8 @@ def test_serving_spans_at_off_compile_nothing_and_nest(global_tracer,
     assert decode[5]["released"] == 0
     assert decode[5]["width"] == 4
     covered = sum(e[4] - e[3] for e in ring
-                  if parent(e) == "engine.step")
+                  if parent(e) == "engine.step"
+                  and e[2] not in AFTER_THE_FACT)
     whole = sum(e[4] - e[3] for e in steps)
     assert 0.75 * whole <= covered <= whole
 
@@ -757,6 +765,231 @@ def test_a_compile_is_a_span_under_the_open_span(global_tracer,
     assert under("compile")[-2:] == ["cold", "warm"]
 
 
+def test_a_fresh_jit_leaves_trace_lower_compile_in_that_order(
+        global_tracer):
+    """What `jax.monitoring` reports of a program's way to the device
+    lands under the span open at the time, each entry recorded when its
+    phase ended: `trace` (Python to a jaxpr), `lower` (jaxpr to MLIR),
+    `compile`, the function's name in `fun`. The same shapes again
+    leave nothing."""
+    def newborn(x):
+        return x * 7 - 3
+
+    call = jax.jit(newborn)
+    x = jnp.ones(11)                    # its own programs, out here
+    with global_tracer.span("outer"):
+        call(x).block_until_ready()
+    first = global_tracer.event_count
+    with global_tracer.span("again"):
+        call(x).block_until_ready()
+    ring = global_tracer.ring()
+    outer = next(e for e in ring if e[2] == "outer")
+    mine = [e for e in ring if e[1] == outer[0]
+            and "newborn" in e[5].get("fun", "")]
+    assert [(e[2], e[5]["fun"]) for e in mine] == [
+        ("trace", "newborn"), ("lower", "jit(newborn)"),
+        ("compile", "jit(newborn)")]
+    assert [e[4] for e in mine] == sorted(e[4] for e in mine)
+    for e in mine:
+        assert outer[3] <= e[4] <= outer[4] and e[3] <= e[4] and e[6] == 1
+    later = [e[2] for e in ring[-(global_tracer.event_count - first):]]
+    assert not {"trace", "lower", "compile"} & set(later)
+    assert "again" in later
+
+
+def test_a_function_traced_inside_anothers_trace_lies_inside_it(
+        global_tracer):
+    """A jitted function called while another is traced reports its own
+    tracing, inside its caller's interval: the union of the two `trace`
+    entries is the outer one, their sum would count the inner twice."""
+    @jax.jit
+    def inner_fn(x):
+        return jnp.tanh(x) + 2
+
+    def outer_fn(x):
+        return inner_fn(jnp.sin(x) * 3) - 1
+
+    x = jnp.ones(13)
+    with global_tracer.span("s"):
+        jax.jit(outer_fn)(x).block_until_ready()
+    traces = {e[5]["fun"]: e for e in global_tracer.ring()
+              if e[2] == "trace"}
+    inner, outer = traces["inner_fn"], traces["outer_fn"]
+    assert inner[1] == outer[1] is not None     # both under `s`
+    slack = 1e-4    # JAX times them on time.time, the tracer stamps their end
+    assert outer[3] - slack <= inner[3] and inner[4] <= outer[4]
+    assert 0 < inner[4] - inner[3] < outer[4] - outer[3]
+
+
+def test_startup_is_one_top_level_entry_of_every_global_tracer():
+    """`startup` runs from the process's start, as the kernel has it,
+    to the first `watch_compiles()`; it lies under no span, and a
+    tracer that `configure()` installs later holds it again, as its
+    first entry."""
+    import time
+
+    trace_mod.watch_compiles()
+    try:
+        intervals = []
+        for _ in range(2):
+            tr = trace_mod.configure(level="off")
+            with tr.span("work"):
+                trace_mod.watch_compiles()      # once a process
+            ups = [e for e in tr.ring() if e[2] == "startup"]
+            assert len(ups) == 1 and tr.ring()[0] is ups[0]
+            assert ups[0][:3] == (0, None, "startup") and ups[0][6] == 0
+            intervals.append(ups[0][3:5])
+        assert intervals[0] == intervals[1]
+        t0, t1 = intervals[0]
+        assert t0 == trace_mod.process_start() <= trace_mod._IMPORTED_AT
+        assert t0 < t1 <= time.perf_counter()
+        # this process is younger than an hour and older than its imports
+        assert 0.5 < time.perf_counter() - t0 < 3600
+    finally:
+        trace_mod.configure(level="off")
+
+
+def test_a_long_collection_is_an_entry_and_a_short_one_leaves_nothing(
+        global_tracer):
+    """A collection of `GC_SPAN_MIN_S` or more becomes a `gc` entry
+    under the span that was open, with its generation and what it
+    freed; a young collection of microseconds leaves nothing, so a
+    step's entries in the ring stay what they are."""
+    import gc
+
+    trace_mod.watch_compiles()
+    gc.collect()
+    was_enabled = gc.isenabled()
+    gc.disable()                # only the collections this test asks for
+    try:
+        with global_tracer.span("outer"):
+            junk = [[i] for i in range(200_000)]
+            for j in junk:
+                j.append(j)                     # cycles: only gc frees them
+            del junk, j
+            first = global_tracer.event_count
+            gc.collect()
+            after_full = global_tracer.event_count
+            gc.collect(0)
+            gc.collect(0)
+            assert global_tracer.event_count == after_full
+    finally:
+        if was_enabled:
+            gc.enable()
+    ring = global_tracer.ring()
+    outer = ring[-1]
+    (entry,) = ring[-(global_tracer.event_count - first):-1]
+    assert entry[2] == "gc" and entry[1] == outer[0] and entry[6] == 1
+    assert entry[5]["generation"] == 2 and entry[5]["collected"] >= 200_000
+    assert entry[4] - entry[3] >= trace_mod.GC_SPAN_MIN_S
+    assert outer[3] <= entry[3] <= entry[4] <= outer[4]
+
+
+def test_a_collection_stands_in_a_live_profiler_trace_as_ss_gc(
+        global_tracer, tmp_path):
+    """While a profiler session is live a collection is annotated like
+    any span: `ss:gc` in the `/host:CPU` plane, on the device's clock."""
+    import gc
+
+    trace_mod.watch_compiles()
+    with global_tracer.span("before"):      # resolves the annotations
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with global_tracer.span("outer"):
+            gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    gc.collect()                            # no session: no annotation
+    found = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    host = next(p for p in
+                jax.profiler.ProfileData.from_file(str(found[-1])).planes
+                if p.name == "/host:CPU")
+    spans = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+             for line in host.lines for e in line.events
+             if e.name in ("ss:gc", "ss:outer")}
+    assert set(spans) == {"ss:gc", "ss:outer"}
+    assert spans["ss:outer"][0] <= spans["ss:gc"][0] \
+        <= spans["ss:gc"][1] <= spans["ss:outer"][1]
+    assert trace_mod._GC_NOTE is None
+
+
+def test_a_collection_inside_a_subscriber_re_enters_the_tracer(tmp_path):
+    """A collection can start while a subscriber (the flight recorder)
+    is mid-call under the tracer's lock, on the thread that holds it:
+    its `gc` entry goes through `_close` and `_emit` again, which the
+    re-entrant lock lets through. The entry reaches the ring, the file
+    and the subscribers, inside the line that was being delivered."""
+    import gc
+
+    trace_mod.watch_compiles()
+    tr = trace_mod.configure(trace_dir=tmp_path, level="steps")
+    seen = []
+
+    def collecting(ev):
+        seen.append(ev["name"])
+        if ev["name"] == "outer":
+            junk = [[i] for i in range(200_000)]
+            for j in junk:
+                j.append(j)
+            del junk, j
+            gc.collect()
+            seen.append("outer delivered")
+
+    tr.subscribers.append(collecting)
+    was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        with tr.span("outer"):
+            pass
+    finally:
+        if was_enabled:
+            gc.enable()
+        trace_mod.configure(level="off")
+    assert seen[-3:] == ["outer", "gc", "outer delivered"]
+    assert [e[2] for e in tr.ring()][-2:] == ["outer", "gc"]
+    lines = [json.loads(line)["name"]
+             for line in (tmp_path / "spans.jsonl").read_text().splitlines()]
+    assert lines[-2:] == ["outer", "gc"]
+
+
+def test_the_engines_constructors_are_build_spans(global_tracer):
+    """`build {engine}` is the whole constructor of the two engines the
+    benchmark runs, with the parts that are not a compile under their
+    own names: the pools' allocation in the serving engine; the
+    weights drawn on the host and the placement in the train engine.
+    `backend.init` is `device_stamp()`'s touch of the backend."""
+    from jax.sharding import Mesh
+
+    from shallowspeed_tpu import runtime
+    from shallowspeed_tpu.models import transformer as T
+    from shallowspeed_tpu.optim import SGD
+    from shallowspeed_tpu.parallel.context import ContextParallelEngine
+    from shallowspeed_tpu.serving import ServingEngine
+
+    cfg = T.TransformerConfig(vocab=48, d_model=24, n_heads=2,
+                              n_layers=2, max_seq=96)
+    params = jax.device_put(T.init(cfg, seed=1))
+    runtime.device_stamp()
+    ServingEngine(params, cfg, n_blocks=40, block_size=8, max_slots=2,
+                  prefill_chunk=16)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "sp"))
+    ContextParallelEngine(cfg, SGD(0.1), mesh, seed=0)
+    ring = [e for e in global_tracer.ring() if e[2] not in AFTER_THE_FACT]
+    assert [e[2] for e in ring] == ["backend.init", "build", "build.init",
+                                    "build.place", "build"]
+    stamp, serving, init, place, train = ring
+    assert stamp[1] is None and serving[1] is None and train[1] is None
+    assert serving[5] == {"engine": "ServingEngine"}
+    assert train[5] == {"engine": "ContextParallelEngine"}
+    assert init[1] == place[1] == train[0] and init[4] <= place[3]
+    # what the constructors trace, lower and compile falls under them
+    by_seq = {e[0]: e for e in global_tracer.ring()}
+    under = {by_seq[e[1]][2] for e in global_tracer.ring()
+             if e[2] in ("trace", "lower", "compile") and e[1] is not None}
+    assert under <= {"build", "build.init", "build.place"}
+
+
 def test_gaps_names_the_span_a_device_idled_in(tmp_path, capsys):
     """`--gaps`: busy is the union of a device's operations, and each
     idle gap goes to the innermost `ss:` span open at its middle."""
@@ -776,6 +1009,11 @@ def test_gaps_names_the_span_a_device_idled_in(tmp_path, capsys):
             ["ss:decode.prep", 1.4, 0.6], ["bench:step", 0.9, 3.0],
             ["ss:engine.step", 3.85, 0.1]]},
     }
+    # a collection inside a step takes the gap that lies under it
+    stalled = dict(trace, **{"/host:CPU": {"python": trace["/host:CPU"][
+        "python"] + [["ss:gc", 3.05, 0.4]]}})
+    assert device_gaps(stalled)[0]["idle_by_span"] == {
+        "decode.prep": pytest.approx(0.5), "gc": pytest.approx(0.5)}
     dev0, dev1 = device_gaps(trace)
     assert dev0["traced_s"] == 4.0 and dev0["busy_s"] == pytest.approx(3.0)
     # gap 1.5-2.0 (middle 1.75) sits in decode.prep, innermost of three;
@@ -789,6 +1027,9 @@ def test_gaps_names_the_span_a_device_idled_in(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "/device:TPU:0: traced 4.000 s, busy 3.000 s, idle 1.000 s" in out
     assert "idle in decode.prep" in out
+    path.write_text(json.dumps(stalled))
+    assert main(["--gaps", str(path)]) == 0
+    assert "idle in gc" in capsys.readouterr().out
     path.write_text(json.dumps({"/host:CPU": trace["/host:CPU"]}))
     assert main(["--gaps", str(path)]) == 1
 
